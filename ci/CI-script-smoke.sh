@@ -28,16 +28,6 @@ python -m fedml_tpu.cli lint --ci
 # roofline denominator for the BENCH captures.
 JAX_PLATFORMS=cpu python -m fedml_tpu.cli audit --ci
 
-# Bench-trajectory ratchet gate (fedml_tpu/analysis/perf.py —
-# docs/benchmarks.md): every checked-in BENCH record carries a meta
-# block (device_kind / backend / smoke); the newest record per
-# (phase, device_kind, smoke) group must not regress beyond tolerance
-# against the best prior record of the SAME group — CPU smoke never
-# ratchets against TPU captures. Exit 1 = regression, 2 = a record
-# without a meta block (contract violation). Stdlib-only, no JAX.
-JAX_PLATFORMS=cpu python -m fedml_tpu.cli perf --ratchet \
-  BENCH_r0*.json BENCH_TPU_CAPTURE_r04.json --quiet
-
 python -m pytest tests/ -m "smoke and not slow" -q "$@"
 
 # Round-pipeline smoke (K=2, 6 rounds, CPU): the async executor must run

@@ -11,8 +11,9 @@ sequence axis sharded over the mesh's ``sp`` axis.
   per-chip attention for each head group runs the pallas flash kernel
   (``fedml_tpu/ops/flash_attention.py``), so even the gathered
   sequence never materializes its score matrix. Needs
-  ``num_heads % sp == 0`` — this config ships num_heads: 8 so
-  flipping the strategy alone works.
+  ``num_heads % sp == 0`` and a ``seq_len`` that is a multiple of 128
+  (the kernel's tiling — anything else raises) — this config ships
+  num_heads: 8 and seq_len: 256 so flipping the strategy alone works.
 
 Run:  python main.py --cf fedml_config.yaml
 Try:  sp_strategy: "ulysses"

@@ -97,6 +97,12 @@ def init(args: Optional[Arguments] = None) -> Arguments:
     elif args.training_type == constants.FEDML_TRAINING_PLATFORM_CROSS_DEVICE:
         args.rank = 0
         args.process_id = 0
+    # persistent compilation cache (core/compile_cache.py) — here, after
+    # any process-group join and before the data loader's synthesis
+    # jits, so every compile of the run can be served from it
+    from .core.compile_cache import maybe_enable_compile_cache
+
+    maybe_enable_compile_cache(args)
     return args
 
 

@@ -1,8 +1,9 @@
 """Rule ``host-sync`` — hidden device->host synchronisation on a round
 or serving hot path.
 
-BENCH_r03 measured a 573x gap between device-resident and host-hop
-aggregation; PR 2's DeferredMetrics exists exactly because one stray
+Host-hop aggregation costs orders of magnitude more than
+device-resident aggregation (bench detail.aggregation_exchange), and
+PR 2's DeferredMetrics exists exactly because one stray
 ``float(device_value)`` per round serialises the pipeline. This
 checker flags, **in the hot-path modules only**, the conversions that
 force a device fetch:

@@ -258,8 +258,13 @@ def join_roofline(
     by_name: Dict[str, List[Dict[str, Any]]] = {}
     for row in audit.get("executables", []):
         by_name.setdefault(row["executable"], []).append(row)
-    peak = constants.peak_bf16_flops(device_kind) * max(int(n_chips), 1)
-    bw = constants.hbm_bandwidth_bytes(device_kind) * max(int(n_chips), 1)
+    if constants.normalize_device_kind(device_kind) == "cpu":
+        # a CPU run has seconds and FLOPs but no device peak: rows join
+        # without an MFU or a bound. Any other unknown kind raises.
+        peak = bw = 0.0
+    else:
+        peak = constants.peak_bf16_flops(device_kind) * max(int(n_chips), 1)
+        bw = constants.hbm_bandwidth_bytes(device_kind) * max(int(n_chips), 1)
     ridge = (peak / bw) if (peak > 0 and bw > 0) else None
 
     rows: List[Dict[str, Any]] = []
@@ -413,9 +418,8 @@ def run_ratchet(
             continue
         if not metas:
             violations.append(
-                f"{path}: no meta block on any phase record — run "
-                "scripts/backfill_bench_meta.py (new records get one "
-                "from bench.py automatically)"
+                f"{path}: no meta block on any phase record (bench.py "
+                "stamps one on every record it writes)"
             )
             continue
         for meta in metas:

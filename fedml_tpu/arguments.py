@@ -385,7 +385,9 @@ _DEFAULTS: Dict[str, Any] = {
     # cohort world, mesh sweep, serving restart) skips every compile
     # whose (HLO, flags, platform) key it has seen — hits/misses are
     # counted in compile_cache_hits_total/_misses_total. One directory
-    # per process (process-global jax.config). None disables
+    # per process (process-global jax.config). The environment's
+    # JAX_COMPILATION_CACHE_DIR wins over this knob; None means the
+    # fixed <checkout>/.jax_compile_cache on a TPU and no cache on CPU
     "compile_cache_dir": None,
     # crash recovery / serving feed (core/checkpoint.py): directory for
     # orbax round checkpoints + the round WAL. None disables both —
@@ -433,7 +435,9 @@ _DEFAULTS: Dict[str, Any] = {
     "embed_dim": 128,  # transformer model width
     "max_len": 512,  # positional-embedding capacity
     "hidden_dim": 64,  # MLP hidden width
-    "attention_impl": "full",  # "full" | "segsum" (seg_width panels)
+    # "full" (dense) | "flash" (ops/flash_attention.py; seq_len must be
+    # a multiple of 128 on every platform)
+    "attention_impl": "full",
     "seg_width": 32,  # segsum attention panel width
     "moe_every": 2,  # every Nth transformer block is a Switch MoE layer
     "num_experts": 8,  # Switch MoE expert count

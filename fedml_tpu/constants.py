@@ -221,14 +221,18 @@ DEVICE_WINDOW_REPORT = "report"
 DEVICE_CLOSE_TARGET = "target"
 DEVICE_CLOSE_WINDOW = "window"
 
-# -- performance-attribution plane (analysis/perf.py, bench.py, ------
-# scripts/tpu_watch.py, scripts/analyze_capture.py) -------------------
-# bf16 peak matmul TFLOP/s per chip by device kind (public spec
-# sheets). THE one table every MFU denominator comes from: bench
-# detail.mfu_vs_bf16_peak, `fedml-tpu perf`'s roofline join, the watch
-# loop's live MFU column and the capture analyzer all route through
-# peak_bf16_flops() so no two tools can disagree about a device's
-# peak. Unknown kinds report achieved FLOP/s without an MFU.
+# -- performance-attribution plane (analysis/perf.py, bench.py) -------
+# Per-chip peaks by device kind: bf16 matmul TFLOP/s and HBM TB/s. THE
+# one table every MFU denominator and roofline ridge comes from (bench
+# detail.mfu_vs_bf16_peak, `fedml-tpu perf`'s roofline join), so no two
+# tools can disagree about a device's peak. A kind that is not here is
+# an error (peak_bf16_flops / hbm_bandwidth_bytes raise) — never a
+# silent 0. Keys are ``jax.devices()[0].device_kind`` strings; the chip
+# this repo is checked on reports "TPU v5 lite" (chip_smoke.py, PR 21).
+# Sources: Google Cloud TPU documentation, the system-architecture page
+# of each generation ("TPU v5e": 197 TFLOP/s bf16, 819 GB/s HBM — the
+# one row confirmed against the hardware at hand; v4, v5p and v6e rows
+# are those pages' figures and have not been run here).
 PEAK_BF16_TFLOPS = {
     "TPU v4": 275.0,
     "TPU v5 lite": 197.0,
@@ -238,14 +242,11 @@ PEAK_BF16_TFLOPS = {
     "TPU v6e": 918.0,
 }
 
-# approximate per-chip HBM bandwidth (TB/s, same spec sheets): the
-# roofline ridge point peak_flops/bandwidth decides compute- vs
-# memory-bound verdicts in `fedml-tpu perf`
 HBM_BANDWIDTH_TBPS = {
     "TPU v4": 1.2,
-    "TPU v5 lite": 0.82,
-    "TPU v5e": 0.82,
-    "TPU v5p": 2.77,
+    "TPU v5 lite": 0.819,
+    "TPU v5e": 0.819,
+    "TPU v5p": 2.765,
     "TPU v6 lite": 1.64,
     "TPU v6e": 1.64,
 }
@@ -273,16 +274,23 @@ def normalize_device_kind(kind: str) -> str:
     return best or k
 
 
+def _peak(table: dict, kind: str, what: str) -> float:
+    canon = normalize_device_kind(kind)
+    if canon not in table:
+        raise ValueError(
+            f"no {what} for device_kind {kind!r}: add it (with its "
+            f"source) to fedml_tpu.constants; known: {sorted(table)}"
+        )
+    return table[canon] * 1e12
+
+
 def peak_bf16_flops(kind: str) -> float:
     """Per-chip bf16 peak in FLOP/s for ``kind`` (device_kind string,
-    ordinal suffix OK), or 0.0 when unknown — callers treat 0 as
-    "report achieved FLOP/s without an MFU"."""
-    canon = normalize_device_kind(kind)
-    peak = PEAK_BF16_TFLOPS.get(canon, 0.0)
-    return peak * 1e12
+    ordinal suffix OK). An unknown kind raises: a caller that wants
+    "no MFU on CPU" decides that from the platform before asking."""
+    return _peak(PEAK_BF16_TFLOPS, kind, "bf16 peak")
 
 
 def hbm_bandwidth_bytes(kind: str) -> float:
-    """Per-chip HBM bandwidth in bytes/s, or 0.0 when unknown."""
-    canon = normalize_device_kind(kind)
-    return HBM_BANDWIDTH_TBPS.get(canon, 0.0) * 1e12
+    """Per-chip HBM bandwidth in bytes/s; an unknown kind raises."""
+    return _peak(HBM_BANDWIDTH_TBPS, kind, "HBM bandwidth")

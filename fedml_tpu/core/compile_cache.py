@@ -1,34 +1,49 @@
-"""Persistent XLA compilation cache behind ``args.compile_cache_dir``.
+"""Persistent XLA compilation cache, placed from outside.
 
-A 10k-cohort planet world or a multi-shape mesh sweep spends its
-startup in XLA compiles (ROADMAP item 5's AOT-cache rider: the pow2
-census is exactly the set of executables worth caching). JAX already
-ships a content-addressed persistent cache; this module is the
-validated knob + telemetry seam in front of it:
+Compiling is most of a cold start on the chip (the ResNet cohort round
+alone is tens of seconds), and a chip machine may be thrown away after
+every command — so the cache directory is something the operator
+places, not something the program invents:
 
-- ``maybe_enable_compile_cache(args)`` — idempotent, process-wide.
-  Points ``jax_compilation_cache_dir`` at the knob's directory and
-  drops the min-compile-time/min-entry-size floors to zero so the
-  small per-bucket round executables (milliseconds to compile on CPU,
-  the census that matters on TPU) are cached too. Called from every
-  engine init (``fedavg_api``, the planet loop, the serving engine);
-  the first caller wins, later calls with the same directory are
-  no-ops, a DIFFERENT directory mid-process logs a warning and keeps
-  the first (the cache knob is process-scoped state, like the chaos
-  schedule).
-- hit/miss telemetry: a ``jax.monitoring`` listener counts
-  ``/jax/compilation_cache/cache_hits`` / ``cache_misses`` into
-  ``compile_cache_hits_total`` / ``compile_cache_misses_total``, and
-  ``cache_entries()`` gauges the directory (``compile_cache_entries``)
-  — a warm-started world shows hits == its executable census and a
-  cold one shows the same number as misses.
+1. ``JAX_COMPILATION_CACHE_DIR`` set: JAX itself reads it at import;
+   no code here (or anywhere) calls
+   ``jax.config.update("jax_compilation_cache_dir", ...)``, and a
+   ``compile_cache_dir`` knob naming another directory is ignored with
+   a warning.
+2. otherwise the ``compile_cache_dir`` knob, when given;
+3. otherwise, on a TPU, the fixed ``<checkout>/.jax_compile_cache``
+   (git-ignored). The path is part of the cache key's locality — a
+   directory that moves never hits — so it is never a temp, pid or
+   timestamp path;
+4. otherwise (CPU, nothing asked for) the cache stays off, so a test
+   run leaves the checkout clean.
+
+``maybe_enable_compile_cache(args)`` is idempotent and process-wide
+(``jax.config`` is process-global): the first caller wins, a later knob
+naming a different directory logs one warning and keeps the first.
+``fedml_tpu.init()`` calls it before any data synthesis compiles, and
+every engine init calls it again for callers that skip ``init()``. The
+min-compile-time / min-entry-size floors drop to zero so the small
+per-bucket round executables are cached too.
+
+Hit/miss counts: a ``jax.monitoring`` listener counts
+``/jax/compilation_cache/cache_hits`` / ``cache_misses`` into
+``stats()`` and, when telemetry is on, into
+``compile_cache_hits_total`` / ``compile_cache_misses_total`` with the
+directory gauged as ``compile_cache_entries``.
 """
 
 from __future__ import annotations
 
 import logging
 import os
-from typing import Optional
+from typing import Dict, Optional
+
+_ENV_DIR = "JAX_COMPILATION_CACHE_DIR"
+CHECKOUT_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)))),
+    ".jax_compile_cache",
+)
 
 _EVENT_HITS = "/jax/compilation_cache/cache_hits"
 _EVENT_MISSES = "/jax/compilation_cache/cache_misses"
@@ -38,19 +53,22 @@ _EVENT_MISSES = "/jax/compilation_cache/cache_misses"
 _enabled_dir: Optional[str] = None
 _listener_installed = False
 _warned_conflict = False
+_counts = {"hits": 0, "misses": 0}
 
 
 def _on_event(event: str, **kwargs) -> None:
-    """jax.monitoring listener: fold cache hit/miss events into the
-    telemetry registry (host-side counter bumps only)."""
+    """jax.monitoring listener: count cache hit/miss events here and in
+    the telemetry registry (host-side counter bumps only)."""
     if event not in (_EVENT_HITS, _EVENT_MISSES):
         return
+    hit = event == _EVENT_HITS
+    _counts["hits" if hit else "misses"] += 1
     from .telemetry import Telemetry
 
     tel = Telemetry.get_instance()
     if not tel.enabled:
         return
-    if event == _EVENT_HITS:
+    if hit:
         tel.inc("compile_cache_hits_total")
     else:
         tel.inc("compile_cache_misses_total")
@@ -72,72 +90,71 @@ def enabled_dir() -> Optional[str]:
     return _enabled_dir
 
 
-def maybe_enable_compile_cache(args) -> bool:
-    """Enable the persistent compilation cache when
-    ``args.compile_cache_dir`` is set. Returns True when the cache is
-    active (now or from an earlier identical call)."""
-    global _enabled_dir, _listener_installed, _warned_conflict
-    d = getattr(args, "compile_cache_dir", None)
-    if not d:
-        return _enabled_dir is not None
-    d = os.path.abspath(str(d))
-    if _enabled_dir is not None:
-        if _enabled_dir != d and not _warned_conflict:
-            _warned_conflict = True
-            logging.warning(
-                "compile_cache_dir=%s ignored: the process-wide XLA "
-                "compilation cache is already rooted at %s (jax.config "
-                "is process-global; one directory per process)",
-                d, _enabled_dir,
-            )
-        return True
-    os.makedirs(d, exist_ok=True)
+def stats() -> Dict[str, object]:
+    """Where the cache is and what this process got from it."""
+    return {"dir": _enabled_dir, "entries": cache_entries(), **_counts}
+
+
+def resolve_dir(args) -> Optional[str]:
+    """The directory rules 1-4 of the module docstring pick for
+    ``args`` in this process, or None for "cache off"."""
+    env = os.environ.get(_ENV_DIR)
+    if env:
+        return os.path.abspath(env)
+    knob = getattr(args, "compile_cache_dir", None)
+    if knob:
+        return os.path.abspath(str(knob))
     import jax
 
-    jax.config.update("jax_compilation_cache_dir", d)
-    for knob, val in (
+    return CHECKOUT_CACHE_DIR if jax.default_backend() == "tpu" else None
+
+
+def maybe_enable_compile_cache(args) -> bool:
+    """Enable the persistent compilation cache where ``resolve_dir``
+    says. Returns True when the cache is active (now or from an
+    earlier call)."""
+    global _enabled_dir, _listener_installed, _warned_conflict
+    if _enabled_dir is None:
+        d = resolve_dir(args)
+        if d is None:
+            return False
+        os.makedirs(d, exist_ok=True)
+        import jax
+        from jax import monitoring
+
+        if not os.environ.get(_ENV_DIR):
+            # jax 0.9 builds its cache object lazily at the first
+            # compile that finds a directory configured, so setting it
+            # after earlier compiles needs no reset of jax's internals
+            jax.config.update("jax_compilation_cache_dir", d)
         # cache EVERYTHING: the round/fold/serving executables compile
         # in milliseconds on CPU but in minutes on a TPU pod — the
         # default 1s floor would skip exactly the census we warm-start
-        ("jax_persistent_cache_min_compile_time_secs", 0.0),
-        ("jax_persistent_cache_min_entry_size_bytes", -1),
-    ):
-        try:
-            jax.config.update(knob, val)
-        except Exception:  # pragma: no cover - older jaxlib knob drift
-            logging.debug("compile cache: no config %s on this jax", knob)
-    try:
-        # jax latches its cache singleton DISABLED at the first compile
-        # of the process when no directory was configured yet — and the
-        # data loader's synthesis jits run before any engine init. Drop
-        # the latch so the next compile re-initializes against the
-        # directory just configured.
-        from jax._src import compilation_cache as _jcc
-
-        _jcc.reset_cache()
-    except Exception:  # pragma: no cover - private-API drift
-        logging.warning(
-            "compile cache: could not reset jax's cache latch; if any "
-            "computation compiled before this call, the persistent "
-            "cache may stay disabled for this process"
-        )
-    if not _listener_installed:
-        try:
-            from jax import monitoring
-
+        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+        jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+        if not _listener_installed:
             monitoring.register_event_listener(_on_event)
             _listener_installed = True
-        except Exception:  # pragma: no cover - monitoring API drift
-            logging.warning(
-                "compile cache enabled but jax.monitoring is "
-                "unavailable — hit/miss counters will stay at zero "
-                "(cache_entries() still gauges the directory)"
-            )
-    _enabled_dir = d
-    from .telemetry import Telemetry
+        _enabled_dir = d
+        from .telemetry import Telemetry
 
-    tel = Telemetry.get_instance()
-    if tel.enabled:
-        tel.set_gauge("compile_cache_entries", cache_entries(d))
-    logging.info("persistent compilation cache enabled at %s", d)
+        tel = Telemetry.get_instance()
+        if tel.enabled:
+            tel.set_gauge("compile_cache_entries", cache_entries(d))
+        logging.info("persistent compilation cache enabled at %s", d)
+    knob = getattr(args, "compile_cache_dir", None)
+    if (
+        knob
+        and os.path.abspath(str(knob)) != _enabled_dir
+        and not _warned_conflict
+    ):
+        _warned_conflict = True
+        logging.warning(
+            "compile_cache_dir=%s ignored: the process-wide XLA "
+            "compilation cache is already rooted at %s (%s; jax.config "
+            "is process-global, one directory per process)",
+            knob, _enabled_dir,
+            f"{_ENV_DIR} is set" if os.environ.get(_ENV_DIR)
+            else "first caller wins",
+        )
     return True

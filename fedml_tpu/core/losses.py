@@ -6,7 +6,7 @@ shapes; the reference's torch loaders are ragged, see
 ``data/MNIST/data_loader.py:75-99``). Masked-out examples contribute zero
 loss and zero gradient.
 
-Task taxonomy mirrors the reference's per-task trainers
+The task set mirrors the reference's per-task trainers
 (``simulation/single_process/fedavg/my_model_trainer_classification.py``,
 ``my_model_trainer_nwp.py``, ``my_model_trainer_tag_prediction.py``).
 """

@@ -30,13 +30,11 @@ def _dense_attention(q, k, v):
 
 
 def _flash(q, k, v):
-    from ..ops.flash_attention import flash_attention, pick_block
+    from ..ops.flash_attention import flash_attention
 
-    # explicit attention="flash" engages the kernel at any block size
-    # (minimum=1); shape-adaptive call sites use the default minimum
-    # and fall back to dense instead
-    b = pick_block(q.shape[1], minimum=1)
-    return flash_attention(q, k, v, True, None, b, b)
+    # a seq_len the kernel cannot tile raises (same rule on CPU and
+    # chip) — attention="flash" never quietly becomes another path
+    return flash_attention(q, k, v, True)
 
 
 def resolve_attention(name_or_fn) -> Callable:

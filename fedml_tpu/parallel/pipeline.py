@@ -31,7 +31,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
-from ._compat import pcast, shard_map
 
 
 def stack_stage_params(per_stage: list) -> Any:
@@ -82,7 +81,7 @@ def pipeline_apply(
     x_spec = P(None, batch_axis) if batch_axis else P()
 
     @partial(
-        shard_map,
+        jax.shard_map,
         mesh=mesh,
         in_specs=(P(axis), x_spec),
         out_specs=x_spec,
@@ -92,7 +91,7 @@ def pipeline_apply(
         # x arrives replicated (device-invariant); the scan carry is
         # device-varying (each stage holds different activations), so
         # mark everything feeding it as varying over the pp axis
-        x = pcast(x, axis, to="varying")
+        x = lax.pcast(x, axis, to="varying")
         s = lax.axis_index(axis)
         perm = [(i, i + 1) for i in range(S - 1)]  # non-cyclic: stage s -> s+1
 

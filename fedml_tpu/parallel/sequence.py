@@ -140,20 +140,6 @@ def ring_attention(
     return (o / jnp.maximum(l_t, 1e-30)).astype(q.dtype)
 
 
-def _blockwise_or_full(q, k, v, causal: bool, scale: Optional[float]):
-    """Per-chip attention for the gathered sequence: the pallas flash
-    kernel when the shape tiles (blockwise — the [T, T] score matrix
-    never hits HBM), dense attention otherwise (tiny/odd test shapes;
-    non-causal, which the kernel does not implement). Numerics match
-    full attention up to fp error either way."""
-    from ..ops.flash_attention import flash_attention, pick_block
-
-    b = pick_block(q.shape[1], minimum=8)
-    if b is None or not causal:
-        return full_attention(q, k, v, causal=causal, scale=scale)
-    return flash_attention(q, k, v, causal, scale, b, b)
-
-
 def ulysses_attention(
     q: jax.Array,
     k: jax.Array,
@@ -164,10 +150,12 @@ def ulysses_attention(
 ):
     """DeepSpeed-Ulysses-style all-to-all sequence parallelism under
     ``shard_map``: re-shard sequence->heads, per-chip attention on the
-    full sequence for a head group (the pallas flash kernel when the
-    shape tiles — without it the gathered [T, T] scores are exactly the
-    memory wall sequence parallelism exists to avoid), re-shard back.
-    Requires ``H % n == 0``. Per-shard input [B, T/n, H, D]."""
+    full sequence for a head group through the pallas flash kernel
+    (without it the gathered [T, T] scores are exactly the memory wall
+    sequence parallelism exists to avoid), re-shard back. Requires
+    ``H % n == 0`` and a gathered ``T`` the kernel tiles (a multiple of
+    128 — it raises otherwise, on every platform; ring attention takes
+    any length). Per-shard input [B, T/n, H, D]."""
     n = lax.psum(1, axis_name)
     if q.shape[2] % n:
         raise ValueError(
@@ -185,8 +173,10 @@ def ulysses_attention(
             x, axis_name, split_axis=1, concat_axis=2, tiled=True
         )
 
+    from ..ops.flash_attention import flash_attention
+
     qg, kg, vg = a2a(q, True), a2a(k, True), a2a(v, True)
-    og = _blockwise_or_full(qg, kg, vg, causal=causal, scale=scale)
+    og = flash_attention(qg, kg, vg, causal, scale)
     return a2a(og, False)
 
 
@@ -202,9 +192,7 @@ def make_sequence_sharded_attention(
     over that mesh axis (each dp replica runs its own ring/all-to-all
     over the sp axis; without it, a multi-axis mesh would gather the
     dp-sharded batch at the shard_map boundary)."""
-    from jax.sharding import NamedSharding, PartitionSpec as P
-
-    from ._compat import shard_map
+    from jax.sharding import PartitionSpec as P
 
     strategies = {"ring": ring_attention, "ulysses": ulysses_attention}
     if strategy not in strategies:
@@ -224,7 +212,7 @@ def make_sequence_sharded_attention(
         inner = functools.partial(inner, block_k=ring_block_k)
     spec = P(batch_axis, axis_name, None, None)
 
-    return shard_map(
+    return jax.shard_map(
         inner,
         mesh=mesh,
         in_specs=(spec, spec, spec),

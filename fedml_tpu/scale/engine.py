@@ -201,12 +201,6 @@ class PlanetRoundLoop:
         self.api = api
         args = api.args
         self._validate(api)
-        # persistent compilation cache: the (bucket, nb) census is
-        # exactly the executable set a 10k-cohort world re-compiles on
-        # every cold start — idempotent, shared with the api's own call
-        from ..core.compile_cache import maybe_enable_compile_cache
-
-        maybe_enable_compile_cache(args)
         self.cohort_size = int(
             getattr(args, "cohort_size", 0) or 0
         ) or int(args.client_num_per_round)

@@ -95,7 +95,7 @@ class ServingEngine:
         from ..core.compile_cache import maybe_enable_compile_cache
         from ..core.telemetry import Telemetry
 
-        # persistent compilation cache (args.compile_cache_dir): a
+        # persistent compilation cache (core/compile_cache.py): a
         # serving restart warm-starts its per-bucket forwards from disk
         maybe_enable_compile_cache(args)
         self.telemetry = Telemetry.get_instance(args)

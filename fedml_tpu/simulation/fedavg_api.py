@@ -185,7 +185,7 @@ def build_round_fn(
 
             # the aggregated carry lands fsdp-sharded at rest — the
             # donated (0, 1) chain never leaves the mesh, so zero host
-            # hops at any cohort size (BENCH_r03's 573x prize)
+            # hops at any cohort size
             new_global = constrain_tree(new_global, mesh)
         summed = {k: v.sum() for k, v in train_metrics.items()}
         if keep_stacked:
@@ -379,9 +379,9 @@ class FedAvgAPI:
             self._multi_controller = False
             self._fed_mesh = False
         # persistent XLA compilation cache (core/compile_cache.py):
-        # no-op unless args.compile_cache_dir is set; idempotent
-        # process-wide, so every engine (sync loop, round pipeline,
-        # planet loop, serving) shares one warm-start ledger
+        # idempotent process-wide (fedml_tpu.init() already enabled it
+        # for one-line runs), so every engine (sync loop, round
+        # pipeline, planet loop, serving) shares one warm-start ledger
         from ..core.compile_cache import maybe_enable_compile_cache
 
         maybe_enable_compile_cache(args)

@@ -1,0 +1,394 @@
+"""Bring-up contracts (PR 21), checked on the CPU: what must hold for a
+chip run to be a chip run. The chip itself is reached only through
+``python chip_smoke.py`` (see README); these tests pin the refusals and
+placements around it — no CPU run or interpreted kernel can pass for a
+device run, and the compile cache goes where it is put from outside.
+"""
+
+import json
+import logging
+import os
+import subprocess
+import sys
+import tempfile
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from fedml_tpu import constants
+from fedml_tpu.core import compile_cache
+from fedml_tpu.ops.flash_attention import flash_attention, max_seq_len
+
+pytestmark = pytest.mark.smoke
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+sys.path.insert(0, REPO)
+import bench  # noqa: E402
+
+
+class TestChipSmokeRefusesCpu:
+    def test_exits_nonzero_and_names_the_platform(self):
+        r = subprocess.run(
+            [sys.executable, os.path.join(REPO, "chip_smoke.py")],
+            env=dict(os.environ, JAX_PLATFORMS="cpu"),
+            capture_output=True, text=True, timeout=120, cwd=REPO,
+        )
+        assert r.returncode != 0
+        assert "platform 'cpu'" in r.stderr
+        # no result line: nothing on stdout parses as the ok object
+        for line in r.stdout.splitlines():
+            assert not line.startswith("{"), line
+
+
+class TestChipSmokeConfigs:
+    """The YAMLs chip_smoke.py feeds through --cf must load and say what
+    the stages claim — a typo found here costs no chip minutes."""
+
+    def _load(self, path):
+        import argparse
+
+        from fedml_tpu.arguments import Arguments
+
+        return Arguments(argparse.Namespace(yaml_config_file=path))
+
+    def test_stage_a_is_the_baseline_cohort_at_full_width(self):
+        import chip_smoke
+
+        a = self._load(chip_smoke.STAGE_A_CF)
+        assert (a.federated_optimizer, a.model, a.dataset) == (
+            "FedAvg", "resnet18", "cifar10",
+        )
+        assert (a.client_num_in_total, a.client_num_per_round) == (100, 10)
+        assert (a.batch_size, a.comm_round, a.dtype) == (64, 3, "bfloat16")
+        assert a.synthetic_train_size == 100 * 500
+        assert a.frequency_of_the_test == 1  # a loss for every round
+        assert getattr(a, "mesh_shape", None) is None
+
+    def test_stage_b_reaches_the_flash_kernel_on_one_device(self):
+        import chip_smoke
+
+        a = self._load(chip_smoke.STAGE_B_CF)
+        assert (a.model, a.attention_impl, a.dtype) == (
+            "transformer", "flash", "bfloat16",
+        )
+        assert (a.num_layers, a.num_heads, a.embed_dim, a.seq_len) == (
+            12, 12, 768, 1024,
+        )
+        assert dict(a.mesh_shape) == {"dp": 1}
+        assert a.seq_len % 128 == 0
+
+    def test_stage_c_configs_are_stage_a_plus_a_mesh(self, tmp_path):
+        import yaml
+
+        import chip_smoke
+
+        path = chip_smoke._mesh_cf(
+            chip_smoke.STAGE_A_CF, {"data": 2, "fsdp": 2}, str(tmp_path)
+        )
+        with open(path) as f, open(chip_smoke.STAGE_A_CF) as g:
+            mesh_cfg, base_cfg = yaml.safe_load(f), yaml.safe_load(g)
+        assert mesh_cfg["train_args"].pop("mesh_shape") == {"data": 2, "fsdp": 2}
+        assert mesh_cfg == base_cfg
+        assert self._load(path).mesh_shape == {"data": 2, "fsdp": 2}
+
+
+def _qkv(B, T, H, D, dtype=jnp.bfloat16):
+    s = jax.ShapeDtypeStruct((B, T, H, D), dtype)
+    return s, s, s
+
+
+def _tpu_hlo(fn, *args) -> str:
+    return jax.jit(fn).trace(*args).lower(lowering_platforms=("tpu",)).as_text()
+
+
+class TestFlashCrossLowersForTpu:
+    """The kernel goes through the Pallas TPU lowering from this CPU
+    host (block specs, tiling and the kernel body are checked there);
+    Mosaic itself sees it on the chip, in chip_smoke.py stage B."""
+
+    @pytest.mark.parametrize(
+        "shape",
+        [
+            (4, 4096, 8, 64),   # bench.py longctx
+            (2, 2048, 4, 128),  # D = one full lane tile
+            (8, 1024, 12, 64),  # chip_smoke stage B: B*H = 96
+        ],
+    )
+    def test_forward(self, shape):
+        hlo = _tpu_hlo(lambda q, k, v: flash_attention(q, k, v, True), *_qkv(*shape))
+        assert "tpu_custom_call" in hlo
+
+    def test_backward_at_bench_shape(self):
+        def loss(q, k, v):
+            return flash_attention(q, k, v, True).astype(jnp.float32).sum()
+
+        hlo = _tpu_hlo(jax.grad(loss, argnums=(0, 1, 2)), *_qkv(4, 4096, 8, 64))
+        assert "tpu_custom_call" in hlo
+
+    def test_lowers_under_the_default_matmul_precision(self):
+        # fedml_tpu.init() sets matmul_precision="highest" by default;
+        # the bf16 kernel must not inherit an fp32 contract precision
+        with jax.default_matmul_precision("highest"):
+            hlo = _tpu_hlo(
+                lambda q, k, v: flash_attention(q, k, v, True), *_qkv(1, 256, 2, 64)
+            )
+        assert "tpu_custom_call" in hlo
+
+    def test_cpu_lowering_interprets_and_other_platforms_raise(self):
+        args = _qkv(1, 256, 2, 64)
+        f = lambda q, k, v: flash_attention(q, k, v, True)
+        cpu = jax.jit(f).trace(*args).lower(lowering_platforms=("cpu",)).as_text()
+        assert "tpu_custom_call" not in cpu
+        with pytest.raises(NotImplementedError, match="cuda"):
+            jax.jit(f).trace(*args).lower(lowering_platforms=("cuda",))
+
+    def test_untileable_shapes_raise_the_clear_error(self):
+        q = jnp.zeros((1, 1000, 2, 64), jnp.bfloat16)
+        with pytest.raises(ValueError, match="TPU tiling cannot take"):
+            flash_attention(q, q, q, True)
+        q = jnp.zeros((1, 256, 2, 64), jnp.bfloat16)
+        with pytest.raises(ValueError, match="multiple of 128"):
+            flash_attention(q, q, q, True, None, 64, 64)
+
+    def test_largest_sequence_is_stated_and_enforced(self):
+        t_max = max_seq_len(64, jnp.bfloat16)
+        assert t_max % 128 == 0
+        # f32 rows are twice as wide; D=256 takes two lane tiles
+        assert max_seq_len(64, jnp.float32) == t_max // 2
+        assert max_seq_len(256, jnp.bfloat16) == t_max // 2
+        ok = jax.ShapeDtypeStruct((1, t_max, 1, 64), jnp.bfloat16)
+        assert "tpu_custom_call" in _tpu_hlo(
+            lambda q, k, v: flash_attention(q, k, v, True), ok, ok, ok
+        )
+        big = jax.ShapeDtypeStruct((1, t_max + 128, 1, 64), jnp.bfloat16)
+        with pytest.raises(ValueError, match=f"exceeds {t_max}"):
+            jax.eval_shape(lambda q, k, v: flash_attention(q, k, v, True), big, big, big)
+
+
+class _Args:
+    def __init__(self, compile_cache_dir=None):
+        self.compile_cache_dir = compile_cache_dir
+
+
+class TestCompileCachePlacement:
+    def test_environment_wins_and_no_code_sets_the_dir(
+        self, monkeypatch, tmp_path, caplog, compile_cache_reset
+    ):
+        env_dir = str(tmp_path / "from_env")
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", env_dir)
+        updates = []
+        real_update = jax.config.update
+
+        def recording_update(name, value):
+            updates.append(name)
+            return real_update(name, value)
+
+        monkeypatch.setattr(jax.config, "update", recording_update)
+        with caplog.at_level(logging.WARNING):
+            assert compile_cache.maybe_enable_compile_cache(
+                _Args(str(tmp_path / "from_knob"))
+            )
+        assert compile_cache.enabled_dir() == env_dir
+        assert compile_cache.stats()["dir"] == env_dir
+        assert "jax_compilation_cache_dir" not in updates
+        assert any("ignored" in r.message for r in caplog.records)
+
+    def test_unset_on_tpu_is_the_fixed_checkout_path(self, monkeypatch):
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        d = compile_cache.resolve_dir(_Args())
+        assert d == os.path.join(REPO, ".jax_compile_cache")
+        assert d == compile_cache.CHECKOUT_CACHE_DIR
+        # a fixed path: nothing of this process or of a temp dir in it
+        assert str(os.getpid()) not in d
+        assert not d.startswith(tempfile.gettempdir())
+
+    def test_unset_on_cpu_stays_off(self, monkeypatch):
+        # the tier-1 run must not fill the checkout
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        assert jax.default_backend() == "cpu"
+        assert compile_cache.resolve_dir(_Args()) is None
+        assert not compile_cache.maybe_enable_compile_cache(_Args())
+        assert not os.path.exists(os.path.join(REPO, ".jax_compile_cache"))
+
+    def test_knob_honoured_when_environment_is_unset(
+        self, monkeypatch, tmp_path, compile_cache_reset
+    ):
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        knob = str(tmp_path / "from_knob")
+        assert compile_cache.resolve_dir(_Args(knob)) == knob
+        assert compile_cache.maybe_enable_compile_cache(_Args(knob))
+        assert jax.config.jax_compilation_cache_dir == knob
+
+    def test_init_enables_it_before_any_data_is_loaded(
+        self, monkeypatch, tmp_path, compile_cache_reset
+    ):
+        import fedml_tpu
+        from fedml_tpu.arguments import Arguments
+
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        args = Arguments()
+        args.compile_cache_dir = str(tmp_path / "from_init")
+        args._validate()
+        fedml_tpu.init(args)
+        assert compile_cache.enabled_dir() == str(tmp_path / "from_init")
+
+    def test_enabling_after_a_compile_still_caches(
+        self, monkeypatch, tmp_path, compile_cache_reset
+    ):
+        """jax 0.9 builds its cache lazily, so no reach into jax._src is
+        needed when the directory arrives after the first compile."""
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        jax.jit(lambda x: x * 2.0 + 1.0)(jnp.ones(3)).block_until_ready()
+        knob = str(tmp_path / "late")
+        assert compile_cache.maybe_enable_compile_cache(_Args(knob))
+        jax.jit(lambda x: x * 3.0 - 7.0)(jnp.ones(5)).block_until_ready()
+        assert compile_cache.cache_entries() > 0
+        assert compile_cache.stats()["misses"] > 0
+
+
+class TestPeakTable:
+    def test_unknown_device_kind_raises(self):
+        with pytest.raises(ValueError, match="TPU v9"):
+            constants.peak_bf16_flops("TPU v9")
+        with pytest.raises(ValueError, match="TPU v9"):
+            constants.hbm_bandwidth_bytes("TPU v9")
+        with pytest.raises(ValueError):
+            constants.peak_bf16_flops("cpu")
+
+    def test_the_chip_at_hand(self):
+        # what jax.devices()[0].device_kind reads on the v5e (PR 21)
+        assert constants.peak_bf16_flops("TPU v5 lite") == 197e12
+        assert constants.hbm_bandwidth_bytes("TPU v5 lite") == 819e9
+        assert constants.peak_bf16_flops("TPU v5 lite0") == 197e12
+
+
+class TestNoSilentMfu:
+    def test_roofline_join_raises_on_an_unknown_accelerator(self):
+        from fedml_tpu.analysis import perf
+
+        measured = {("x", ""): {"count": 1.0, "sum": 1.0, "min": 1.0, "max": 1.0}}
+        with pytest.raises(ValueError, match="TPU v9"):
+            perf.join_roofline({"executables": []}, measured, "TPU v9")
+        # the CPU is asked for by platform and simply has no peak
+        assert perf.join_roofline(
+            {"executables": []}, measured, "cpu"
+        )["peak_bf16_flops"] is None
+
+    def test_bench_mfu_detail_on_cpu_has_flops_but_no_mfu(self):
+        out = bench._mfu_detail(1e9, 2.0)
+        assert out["model_flops_per_sec"] == 2e9
+        assert "mfu_vs_bf16_peak" not in out
+
+
+class TestMultichipShapes:
+    def test_four_chip_host_exercises_fsdp(self):
+        shapes = dict(bench._multichip_shapes(4))
+        assert shapes == {
+            "1x1": {"data": 1, "fsdp": 1},
+            "4x1": {"data": 4, "fsdp": 1},
+            "2x2": {"data": 2, "fsdp": 2},
+        }
+
+    def test_other_hosts(self):
+        assert [k for k, _ in bench._multichip_shapes(1)] == ["1x1"]
+        assert [k for k, _ in bench._multichip_shapes(2)] == ["1x1", "2x1"]
+        assert [k for k, _ in bench._multichip_shapes(8)] == [
+            "1x1", "8x1", "4x2", "2x4",
+        ]
+        # every shape fits the devices there are
+        for n in (1, 2, 4, 8):
+            for _, shape in bench._multichip_shapes(n):
+                assert shape["data"] * shape["fsdp"] <= n
+
+
+def _fake_children(failing=()):
+    """A stand-in for bench._run_phase_subprocess: every phase child
+    'succeeds' with a minimal record except the ones named."""
+    calls = []
+
+    def run(phase_args, timeout_s):
+        phase = phase_args[1]
+        calls.append(phase_args)
+        if phase in failing:
+            return None, "rc=1: boom"
+        if phase == "headline":
+            return {"metric": "fedavg_rounds_per_sec", "value": 2.0,
+                    "unit": "rounds/s", "vs_baseline": 3.0, "detail": {}}, "ok"
+        if phase == "sweep":
+            c = int(phase_args[3])
+            return {"clients": c, "rounds_per_sec": 1.0,
+                    "samples_per_sec": 100.0 * c}, "ok"
+        return {"rounds_per_sec": 1.0}, "ok"
+
+    return run, calls
+
+
+def _keys(node):
+    if isinstance(node, dict):
+        for k, v in node.items():
+            yield k
+            yield from _keys(v)
+    elif isinstance(node, list):
+        for v in node:
+            yield from _keys(v)
+
+
+class TestBenchHasNoFallback:
+    def test_parent_returns_zero_when_every_child_passes(self, monkeypatch, capsys):
+        run, calls = _fake_children()
+        monkeypatch.setattr(bench, "_run_phase_subprocess", run)
+        assert bench.main() == 0
+        out = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
+        assert "failed_phases" not in out
+        assert set(out["detail"]) >= set(bench._DETAIL_PHASES) | {
+            "scaling", "bf16", "longctx", "mesh",
+        }
+        # the children are placed by the environment, never by the parent
+        assert not any("--cpu" in c for c in calls)
+
+    def test_parent_returns_nonzero_when_a_child_fails(self, monkeypatch, capsys):
+        run, _ = _fake_children(failing={"dense", "longctx"})
+        monkeypatch.setattr(bench, "_run_phase_subprocess", run)
+        assert bench.main() != 0
+        out = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
+        assert [f["phase"] for f in out["failed_phases"]] == ["dense", "longctx"]
+        assert "dense" not in out["detail"] and "longctx" not in out["detail"]
+        bad = [k for k in _keys(out)
+               if k.endswith("_cpu_fallback") or k.endswith("_skipped")]
+        assert not bad, bad
+
+    def test_no_headline_no_rate(self, monkeypatch, capsys):
+        run, calls = _fake_children(failing={"headline"})
+        monkeypatch.setattr(bench, "_run_phase_subprocess", run)
+        assert bench.main() != 0
+        assert capsys.readouterr().out.strip() == ""
+        assert len(calls) == 1  # nothing else runs without a headline
+
+    def test_child_without_cpu_flag_refuses_a_cpu_platform(self, tmp_path):
+        with pytest.raises(RuntimeError, match="without --cpu"):
+            bench._phase_main(
+                ["--phase", "headline", "--out", str(tmp_path / "o.json")]
+            )
+        assert not (tmp_path / "o.json").exists()
+
+    def test_parent_module_does_not_import_jax(self):
+        # one process per chip: the parent must stay off JAX
+        r = subprocess.run(
+            [sys.executable, "-c",
+             "import sys; sys.path.insert(0, %r); import bench; "
+             "sys.exit(1 if 'jax' in sys.modules else 0)" % REPO],
+            capture_output=True, text=True, timeout=60,
+        )
+        assert r.returncode == 0, r.stderr
+
+
+class TestSiloLauncherOnAChipHost:
+    def test_refuses_processes_that_would_share_the_chips(self, monkeypatch):
+        from fedml_tpu.cross_silo.hierarchical.launcher import launch_silo_processes
+
+        monkeypatch.delenv("JAX_PLATFORMS", raising=False)
+        with pytest.raises(RuntimeError, match="belongs to one"):
+            launch_silo_processes("entry.py", 2, 1234, 5678)

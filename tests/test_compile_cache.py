@@ -17,26 +17,7 @@ from fedml_tpu.data import load
 from fedml_tpu.simulation import FedAvgAPI
 
 
-@pytest.fixture(autouse=True)
-def _reset_cache_module():
-    """The module is process-scoped on purpose; tests reset its
-    bookkeeping (jax.config's cache dir is cleared too so later tests
-    never write into a deleted tmpdir)."""
-    yield
-    if compile_cache._enabled_dir is not None:
-        jax.config.update("jax_compilation_cache_dir", None)
-        try:
-            # jax pins its persistent-cache singleton to the first
-            # directory it initialized with; drop it so the next test's
-            # enable takes a fresh tmpdir (production never switches —
-            # one directory per process by design)
-            from jax._src import compilation_cache as _jcc
-
-            _jcc.reset_cache()
-        except Exception:  # lint: except-ok — private-API drift just skips the latch reset (next enable warns)
-            pass
-    compile_cache._enabled_dir = None
-    compile_cache._warned_conflict = False
+pytestmark = pytest.mark.usefixtures("compile_cache_reset")
 
 
 def _args(**kw):

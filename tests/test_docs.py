@@ -67,7 +67,7 @@ def test_docs_mention_only_real_knobs():
         "fed_cifar100", "fed_emnist", "fed_shakespeare",
         "stackoverflow_nwp", "stackoverflow_lr", "fashion_mnist",
         "data_batch", "fedml_tpu", "mnist", "vs_baseline",
-        "value_cpu_fallback", "mfu_vs_bf16_peak", "tag_count",
+        "mfu_vs_bf16_peak", "tag_count",
         "word_count", "materialize_real_digits", "jax", "shard_map",
         "ppermute", "vmap",
     }

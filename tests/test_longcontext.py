@@ -126,9 +126,10 @@ class TestRingAttention:
 class TestUlysses:
     @pytest.mark.parametrize("causal", [True, False])
     def test_matches_full_attention(self, sp_mesh, causal):
-        # Ulysses re-shards heads over the axis: H must divide n
+        # Ulysses re-shards heads over the axis: H must divide n; the
+        # gathered sequence runs the flash kernel, which tiles T=128
         rng = np.random.default_rng(3)
-        mk = lambda: jnp.asarray(rng.normal(size=(B, T, 8, D)).astype(np.float32))
+        mk = lambda: jnp.asarray(rng.normal(size=(B, 128, 8, D)).astype(np.float32))
         q, k, v = mk(), mk(), mk()
         want = full_attention(q, k, v, causal=causal)
         attn = make_sequence_sharded_attention(
@@ -137,6 +138,15 @@ class TestUlysses:
         got = jax.jit(attn)(q, k, v)
         np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-5)
 
+    def test_untileable_sequence_raises_not_dense(self, sp_mesh):
+        """A gathered T the flash kernel cannot tile is an error on
+        every platform — never a quiet switch to dense attention."""
+        rng = np.random.default_rng(3)
+        mk = lambda: jnp.asarray(rng.normal(size=(B, T, 8, D)).astype(np.float32))
+        attn = make_sequence_sharded_attention(sp_mesh, strategy="ulysses")
+        with pytest.raises(ValueError, match="TPU tiling cannot take"):
+            jax.jit(attn)(mk(), mk(), mk())
+
     def test_rejects_indivisible_heads(self, sp_mesh):
         q, k, v = _qkv(3)  # H=4 over 8 devices
         attn = make_sequence_sharded_attention(sp_mesh, strategy="ulysses")
@@ -144,19 +154,28 @@ class TestUlysses:
             jax.jit(attn)(q, k, v)
 
 
+FT = 256  # two 128-blocks each way: the smallest multi-block flash shape
+
+
+def _flash_qkv(seed):
+    rng = np.random.default_rng(seed)
+    mk = lambda: jnp.asarray(rng.normal(size=(1, FT, 2, D)).astype(np.float32))
+    return mk(), mk(), mk()
+
+
 class TestFlashAttention:
     @pytest.mark.parametrize("causal", [True, False])
     def test_matches_full(self, causal):
-        q, k, v = _qkv(4)
+        q, k, v = _flash_qkv(4)
         want = full_attention(q, k, v, causal=causal)
-        got = flash_attention(q, k, v, causal, None, 16, 16)
+        got = flash_attention(q, k, v, causal)
         np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-5)
 
     def test_gradients(self):
-        q, k, v = _qkv(5)
+        q, k, v = _flash_qkv(5)
 
         def loss_flash(q, k, v):
-            return (flash_attention(q, k, v, True, None, 16, 16) ** 2).sum()
+            return (flash_attention(q, k, v, True) ** 2).sum()
 
         def loss_full(q, k, v):
             return (full_attention(q, k, v, causal=True) ** 2).sum()
@@ -166,10 +185,17 @@ class TestFlashAttention:
         for a, b in zip(gf, gd):
             np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=5e-4)
 
-    def test_rejects_indivisible_blocks(self):
-        q, k, v = _qkv(6)
-        with pytest.raises(ValueError, match="divide"):
+    def test_rejects_untileable_shapes(self):
+        """One rule on every platform: 128-multiple blocks that divide
+        T. What the TPU tiling cannot take raises here on CPU too."""
+        q, k, v = _flash_qkv(6)
+        with pytest.raises(ValueError, match="multiple of 128"):
             flash_attention(q, k, v, True, None, 48, 48)
+        with pytest.raises(ValueError, match="TPU tiling cannot take"):
+            flash_attention(q[:, :192], k[:, :192], v[:, :192], True)
+        q64, k64, v64 = _qkv(6)  # T=64: smaller than one block
+        with pytest.raises(ValueError, match="TPU tiling cannot take"):
+            flash_attention(q64, k64, v64, True)
 
     def test_backward_is_blockwise(self):
         """The custom backward's jaxpr never materializes a [T, T]
@@ -185,16 +211,16 @@ class TestFlashAttention:
                     if hasattr(inner, "eqns"):
                         yield from all_shapes(inner)
 
-        q, k, v = _qkv(7)
-        bk = 16
+        q, k, v = _flash_qkv(7)
+        bk = 128
 
         def loss(q, k, v):
-            return (flash_attention(q, k, v, True, None, bk, bk) ** 2).sum()
+            return (flash_attention(q, k, v, True) ** 2).sum()
 
         jaxpr = jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2)))(q, k, v)
         shapes = list(all_shapes(jaxpr.jaxpr))
-        assert not any(s[-2:] == (T, T) for s in shapes if len(s) >= 2)
-        assert any(s[-2:] == (T, bk) for s in shapes if len(s) >= 2)
+        assert not any(s[-2:] == (FT, FT) for s in shapes if len(s) >= 2)
+        assert any(s[-2:] == (FT, bk) for s in shapes if len(s) >= 2)
 
 
 class TestBf16Ring:
@@ -251,12 +277,12 @@ class TestTransformerFL:
 
         common = dict(
             dataset="shakespeare", model="transformer", vocab_size=50,
-            seq_len=16, num_layers=1, num_heads=2, embed_dim=32,
+            seq_len=128, num_layers=1, num_heads=2, embed_dim=32,
         )
         m_full = models.create(args_factory(**common, attention_impl="full"), 50)
         m_flash = models.create(args_factory(**common, attention_impl="flash"), 50)
         params = m_full.init(jax.random.PRNGKey(0))
-        x = jnp.asarray(np.random.default_rng(0).integers(0, 50, (4, 16)))
+        x = jnp.asarray(np.random.default_rng(0).integers(0, 50, (2, 128)))
         np.testing.assert_allclose(
             np.asarray(m_full.apply(params, x)),
             np.asarray(m_flash.apply(params, x)),
