@@ -30,7 +30,9 @@ Hit/miss counts: a ``jax.monitoring`` listener counts
 ``/jax/compilation_cache/cache_hits`` / ``cache_misses`` into
 ``stats()`` and, when telemetry is on, into
 ``compile_cache_hits_total`` / ``compile_cache_misses_total`` with the
-directory gauged as ``compile_cache_entries``.
+directory gauged as ``compile_cache_entries``. Beside it a second
+listener writes one ``compile`` instant into the flight recorder for
+each executable the backend builds, cache or no cache.
 """
 
 from __future__ import annotations
@@ -47,6 +49,7 @@ CHECKOUT_CACHE_DIR = os.path.join(
 
 _EVENT_HITS = "/jax/compilation_cache/cache_hits"
 _EVENT_MISSES = "/jax/compilation_cache/cache_misses"
+_EVENT_BACKEND_COMPILE = "/jax/core/compile/backend_compile_duration"
 
 # process-scoped: the directory the cache was enabled with (None =
 # never enabled). jax.config is process-global, so this module is too.
@@ -75,6 +78,33 @@ def _on_event(event: str, **kwargs) -> None:
         # a miss just wrote an entry — keep the directory gauge live
         # (one listdir per compile, which already cost far more)
         tel.set_gauge("compile_cache_entries", cache_entries())
+
+
+def _on_duration(event: str, duration: float, **kwargs) -> None:
+    """jax.monitoring listener: one ``compile`` instant in the flight
+    recorder for each executable the backend builds (compiled or read
+    from the persistent cache), its seconds as an arg -- so a round
+    that stalled on one says so in the run's own timeline."""
+    if event != _EVENT_BACKEND_COMPILE:
+        return
+    from .telemetry import Telemetry
+
+    Telemetry.get_instance().recorder.instant(
+        "compile", cat="compile", seconds=round(float(duration), 6)
+    )
+
+
+def install_listeners() -> None:
+    """Register the two listeners above, once a process
+    (``jax.monitoring`` has no way to take one back)."""
+    global _listener_installed
+    if _listener_installed:
+        return
+    from jax import monitoring
+
+    monitoring.register_event_listener(_on_event)
+    monitoring.register_event_duration_secs_listener(_on_duration)
+    _listener_installed = True
 
 
 def cache_entries(directory: Optional[str] = None) -> int:
@@ -113,14 +143,14 @@ def maybe_enable_compile_cache(args) -> bool:
     """Enable the persistent compilation cache where ``resolve_dir``
     says. Returns True when the cache is active (now or from an
     earlier call)."""
-    global _enabled_dir, _listener_installed, _warned_conflict
+    global _enabled_dir, _warned_conflict
+    install_listeners()
     if _enabled_dir is None:
         d = resolve_dir(args)
         if d is None:
             return False
         os.makedirs(d, exist_ok=True)
         import jax
-        from jax import monitoring
 
         if not os.environ.get(_ENV_DIR):
             # jax 0.9 builds its cache object lazily at the first
@@ -132,9 +162,21 @@ def maybe_enable_compile_cache(args) -> bool:
         # default 1s floor would skip exactly the census we warm-start
         jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
         jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-        if not _listener_installed:
-            monitoring.register_event_listener(_on_event)
-            _listener_installed = True
+        # op metadata is part of the key: the round executable's scopes
+        # (fedavg_api.build_round_fn) are read from device traces, and
+        # with JAX's default an entry written by a tree that had other
+        # scopes, or none, is served with *its* metadata ("executables
+        # loaded from the cache may have stale metadata, which may show
+        # up in, e.g., profiles" -- the flag's own help)
+        jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
+        # ... of which each op's name stack and its own source line,
+        # not the ten frames of traceback JAX adds by default: with
+        # those the key would also name whoever called train(), and
+        # every entry script would compile the round executable for
+        # itself. (jax_include_full_tracebacks_in_locations=False would
+        # do the same and more: it drops the name stack of everything
+        # inside a loop body, scopes included -- seen on the chip.)
+        jax.config.update("jax_traceback_in_locations_limit", 1)
         _enabled_dir = d
         from .telemetry import Telemetry
 

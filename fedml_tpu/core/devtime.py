@@ -1,10 +1,11 @@
 """Per-auditable-executable device-time accounting.
 
 Every ``auditable(...)`` call site wraps its dispatch in
-:func:`measure`, which brackets the call three ways at once:
+:func:`measure`, which brackets the call two ways at once (a
+``jax.named_scope`` round the *call* of an already-jitted function
+reaches no HLO and no trace, so there is none here; the scopes that do
+are inside the traced functions, ``simulation/fedavg_api.build_round_fn``):
 
-* a ``jax.named_scope("exec.<name>")`` so XLA profiler captures carry
-  the executable's registry name on-device;
 * a flight-recorder B/E span (``cat="exec"``) so the offline trace
   stitcher sees exactly where each executable sat on the round's
   critical path;
@@ -102,12 +103,7 @@ def measure(executable: str, bucket: Optional[str] = None) -> Iterator[None]:
         tel.recorder.begin(name, cat="exec", **tags)
     t0 = time.perf_counter()
     try:
-        scope = _named_scope(name)
-        if scope is not None:
-            with scope:
-                yield
-        else:
-            yield
+        yield
     finally:
         dt = time.perf_counter() - t0
         if enabled:
@@ -122,18 +118,6 @@ def measure(executable: str, bucket: Optional[str] = None) -> Iterator[None]:
                     "t_rel": t0 - _T0,
                 }
             )
-
-
-def _named_scope(name: str):
-    """``jax.named_scope`` when jax is importable (it always is inside
-    the training stack; guarded so the module stays importable from
-    analysis-side tooling on a bare interpreter)."""
-    try:
-        import jax
-
-        return jax.named_scope(name)
-    except Exception:  # pragma: no cover - jax-less interpreter
-        return None
 
 
 def measured_executables() -> List[str]:

@@ -135,19 +135,26 @@ def make_local_train_fn(
         def train_step(carry, batch):
             p, s = carry
             x, y, m = batch
-            (loss, metrics), grads = jax.value_and_grad(batch_loss, has_aux=True)(
-                p, global_params, x, y, m
-            )
-            updates, s_new = optimizer.update(grads, s, p)
-            if lr_mult is not None:
-                # round-indexed LR: every _CLIENT_OPTS optimizer ends in
-                # scale_by_learning_rate, so scaling the final updates
-                # == running it with lr * lr_mult this round
-                updates = jax.tree.map(lambda u: u * lr_mult, updates)
-            p_new = optax.apply_updates(p, updates)
-            nonempty = m.sum() > 0
-            p = jax.tree.map(lambda a, b2: jnp.where(nonempty, a, b2), p_new, p)
-            s = jax.tree.map(lambda a, b2: jnp.where(nonempty, a, b2), s_new, s)
+            # HLO op metadata only (see build_round_fn's scopes)
+            with jax.named_scope("fwd_bwd"):
+                (loss, metrics), grads = jax.value_and_grad(
+                    batch_loss, has_aux=True
+                )(p, global_params, x, y, m)
+            with jax.named_scope("opt"):
+                updates, s_new = optimizer.update(grads, s, p)
+                if lr_mult is not None:
+                    # round-indexed LR: every _CLIENT_OPTS optimizer ends
+                    # in scale_by_learning_rate, so scaling the final
+                    # updates == running it with lr * lr_mult this round
+                    updates = jax.tree.map(lambda u: u * lr_mult, updates)
+                p_new = optax.apply_updates(p, updates)
+                nonempty = m.sum() > 0
+                p = jax.tree.map(
+                    lambda a, b2: jnp.where(nonempty, a, b2), p_new, p
+                )
+                s = jax.tree.map(
+                    lambda a, b2: jnp.where(nonempty, a, b2), s_new, s
+                )
             return (p, s), metrics
 
         def epoch(carry, ep_rng):

@@ -146,22 +146,29 @@ class RoundPipeline:
 
         api = self.api
         args = api.args
-        n_per_round = int(args.client_num_per_round)
-        # compile buckets must tile the mesh's cohort axis ('clients'
-        # legacy / 'data' on the fed (data, fsdp) mesh) so every padded
-        # cohort shards evenly across the lanes
-        from ..parallel.layout import cohort_axis_size
+        # host phase spans (docs/observability.md has the table): every
+        # `span` below is a TraceAnnotation on the device trace's clock
+        # and a B/E pair in the flight recorder; `train.plan`, the
+        # `round`s and `train.drain` tile this call, and a round's
+        # children tile the round
+        span = api.profiler.span
+        with span("train.plan"):
+            n_per_round = int(args.client_num_per_round)
+            # compile buckets must tile the mesh's cohort axis ('clients'
+            # legacy / 'data' on the fed (data, fsdp) mesh) so every padded
+            # cohort shards evenly across the lanes
+            from ..parallel.layout import cohort_axis_size
 
-        shard_multiple = cohort_axis_size(api.mesh)
-        bucket = bucket_cohort(
-            n_per_round,
-            self.bucket_policy,
-            max_size=int(api.dataset.client_num),  # lint: host-sync-ok — host metadata
-            shard_multiple=shard_multiple,
-        )
-        idx_plan, lr_plan, key_plan, head_plan = self._precompute(
-            start_round, comm_rounds
-        )
+            shard_multiple = cohort_axis_size(api.mesh)
+            bucket = bucket_cohort(
+                n_per_round,
+                self.bucket_policy,
+                max_size=int(api.dataset.client_num),  # lint: host-sync-ok — host metadata
+                shard_multiple=shard_multiple,
+            )
+            idx_plan, lr_plan, key_plan, head_plan = self._precompute(
+                start_round, comm_rounds
+            )
 
         # telemetry (core/telemetry.py): every instrument below is a
         # host-side counter bump / ring append — the hot loop gains no
@@ -186,121 +193,135 @@ class RoundPipeline:
 
         def flush(upto: Optional[int]) -> None:
             nonlocal final_stats
-            flushed = self.deferred.flush(upto)
-            if rec is not None and flushed:
+            if not len(self.deferred):
+                return
+            with span("flush.fetch"):
+                flushed = self.deferred.flush(upto)
+            if not flushed:
+                return
+            if rec is not None:
                 rec.instant(
                     "pipeline.flush" if upto is not None else "pipeline.drain",
                     cat="pipeline",
                     records=len(flushed),
                     upto=upto,
                 )
-            for r, host in flushed:
-                t0r = t_dispatch.pop(r, None)
-                dt = durations.pop(r, None)
-                if dt is None and t0r is not None:
-                    # only possible for the just-dispatched round (K=1's
-                    # same-iteration flush): legacy semantics, round
-                    # start to now
-                    dt = time.perf_counter() - t0r
-                stats = self._stats_from_host(r, host, dt)
-                api.history.append(stats)
-                final_stats = stats
-                api.metrics_reporter.report_server_training_metric(stats)
+            with span("flush.report"):
+                for r, host in flushed:
+                    t0r = t_dispatch.pop(r, None)
+                    dt = durations.pop(r, None)
+                    if dt is None and t0r is not None:
+                        # only possible for the just-dispatched round
+                        # (K=1's same-iteration flush): legacy
+                        # semantics, round start to now
+                        dt = time.perf_counter() - t0r
+                    stats = self._stats_from_host(r, host, dt)
+                    api.history.append(stats)
+                    final_stats = stats
+                    api.metrics_reporter.report_server_training_metric(stats)
 
         # on-demand device profiling (core/tracing.py): with K rounds in
         # flight the capture window is dispatch-to-dispatch of the listed
         # round, which brackets its device work under back-pressure
         profiler = getattr(api, "_round_profiler", None)
 
+        # the body stays in this frame: as a nested function of its own
+        # it made every trace taken from inside it a quarter slower on
+        # the v5e's host (+2.9 s of set-up; PERF.md §6, PR 26)
         for i, round_idx in enumerate(range(start_round, comm_rounds)):
-            if profiler is not None:
-                profiler.tick(round_idx)
-            t0 = time.perf_counter()
-            if prev_round is not None and prev_round in t_dispatch:
-                durations[prev_round] = t0 - t_dispatch[prev_round]
-            prev_round = None
-            pidx, valid = pad_cohort_idx(idx_plan[i], bucket)
-            if api._multi_controller:
-                idx_dev, valid_dev = pidx, valid
-            else:
-                idx_dev, valid_dev = jnp.asarray(pidx), jnp.asarray(valid)
-            lr_mult = lr_plan[i]
-            extra = () if lr_mult is None else (lr_mult,)
-            with api.profiler.span("round"):
-                with _devtime(api._round_exec_name(), bucket=f"b{bucket}"):
-                    out = api._round_fn(
-                        api.global_params,
-                        api.server_state,
-                        packed,
-                        nsamples,
-                        idx_dev,
-                        key_plan[i],
-                        *extra,
-                        valid=valid_dev,
-                    )
-            api.global_params, api.server_state, summed = out[:3]
-            api.rng = head_plan[i]
-            # back-pressure: bound in-flight rounds at K with a wait
-            # (block_until_ready), never a transfer — after the wait at
-            # most K-1 unconfirmed rounds remain, so the next dispatch
-            # brings the queue back to exactly K (depth=1: wait on the
-            # round just dispatched, i.e. fully synchronous)
-            inflight.append(summed["count"])
-            while len(inflight) >= self.depth:
-                jax.block_until_ready(inflight.popleft())  # lint: host-sync-ok — THE back-pressure sync (depth bound)
-            if tel is not None:
-                tel.inc("pipeline_rounds_dispatched_total")
-                tel.heartbeat("pipeline.round", round_idx)
-                rec.instant("pipeline.dispatch", cat="pipeline", round=round_idx)
+            with api.profiler.iteration_span("round", round=round_idx):
+                if profiler is not None:
+                    profiler.tick(round_idx)
+                with span("round.prep"):
+                    t0 = time.perf_counter()
+                    if prev_round is not None and prev_round in t_dispatch:
+                        durations[prev_round] = t0 - t_dispatch[prev_round]
+                    prev_round = None
+                    pidx, valid = pad_cohort_idx(idx_plan[i], bucket)
+                    if api._multi_controller:
+                        idx_dev, valid_dev = pidx, valid
+                    else:
+                        idx_dev, valid_dev = jnp.asarray(pidx), jnp.asarray(valid)
+                    lr_mult = lr_plan[i]
+                    extra = () if lr_mult is None else (lr_mult,)
+                with span("round.dispatch"):
+                    with _devtime(api._round_exec_name(), bucket=f"b{bucket}"):
+                        out = api._round_fn(
+                            api.global_params,
+                            api.server_state,
+                            packed,
+                            nsamples,
+                            idx_dev,
+                            key_plan[i],
+                            *extra,
+                            valid=valid_dev,
+                        )
+                api.global_params, api.server_state, summed = out[:3]
+                api.rng = head_plan[i]
+                # back-pressure: bound in-flight rounds at K with a wait
+                # (block_until_ready), never a transfer — after the wait at
+                # most K-1 unconfirmed rounds remain, so the next dispatch
+                # brings the queue back to exactly K (depth=1: wait on the
+                # round just dispatched, i.e. fully synchronous)
+                inflight.append(summed["count"])
+                with span("round.wait"):
+                    while len(inflight) >= self.depth:
+                        jax.block_until_ready(inflight.popleft())  # lint: host-sync-ok — THE back-pressure sync (depth bound)
+                if tel is not None:
+                    tel.inc("pipeline_rounds_dispatched_total")
+                    tel.heartbeat("pipeline.round", round_idx)
+                    rec.instant("pipeline.dispatch", cat="pipeline", round=round_idx)
 
-            if round_idx % freq == 0 or round_idx == comm_rounds - 1:
-                with api.profiler.span("eval"):
-                    train_sums = api._eval_all(
-                        api.global_params, api.dataset.packed_train
-                    )
-                    test_sums = api._eval_all(
-                        api.global_params, api.dataset.packed_test
-                    )
-                t_dispatch[round_idx] = t0
-                prev_round = round_idx
-                self.deferred.push(
-                    round_idx,
-                    {"summed": summed, "train": train_sums, "test": test_sums},
-                )
-                # flush every eval round, but only records at least
-                # K-1 rounds old — the fetch never waits on in-flight
-                # compute (K=1: flush this round's record immediately,
-                # i.e. exactly the synchronous loop's behavior)
-                flush(round_idx - (self.depth - 1))
+                if round_idx % freq == 0 or round_idx == comm_rounds - 1:
+                    with span("eval"):
+                        train_sums = api._eval_all(
+                            api.global_params, api.dataset.packed_train
+                        )
+                        test_sums = api._eval_all(
+                            api.global_params, api.dataset.packed_test
+                        )
+                        t_dispatch[round_idx] = t0
+                        prev_round = round_idx
+                        self.deferred.push(
+                            round_idx,
+                            {"summed": summed, "train": train_sums, "test": test_sums},
+                        )
+                    # flush every eval round, but only records at least
+                    # K-1 rounds old — the fetch never waits on in-flight
+                    # compute (K=1: flush this round's record immediately,
+                    # i.e. exactly the synchronous loop's behavior)
+                    flush(round_idx - (self.depth - 1))
 
-            saved = False
-            if ckpt is not None and (
-                (round_idx + 1) % ckpt_freq == 0 or round_idx == comm_rounds - 1
-            ):
-                # drain before save: all pending metrics out, then the
-                # checkpoint fetches params (inherently a host sync)
-                flush(None)
-                api._save_checkpoint(ckpt, round_idx)
-                self._extra_syncs += 1
-                saved = True
-            signal = getattr(api, "_preempt_signal", None)
-            if signal is not None:
-                notice = signal.poll(round_idx)
-                if notice is not None:
-                    # drain the depth-K window DETERMINISTICALLY before
-                    # the forced snapshot: every in-flight round's
-                    # confirmation waited on (same barrier as the depth
-                    # bound), deferred metrics out — the checkpoint then
-                    # holds exactly the rounds the WAL says it does
-                    while inflight:
-                        jax.block_until_ready(inflight.popleft())  # lint: host-sync-ok — preempt drain (same barrier as the depth bound)
-                    flush(None)
+                saved = False
+                if ckpt is not None and (
+                    (round_idx + 1) % ckpt_freq == 0 or round_idx == comm_rounds - 1
+                ):
+                    with span("round.ckpt"):
+                        # drain before save: all pending metrics out, then the
+                        # checkpoint fetches params (inherently a host sync)
+                        flush(None)
+                        api._save_checkpoint(ckpt, round_idx)
                     self._extra_syncs += 1
-                    from ..parallel.elastic import preempt_now
+                    saved = True
+                signal = getattr(api, "_preempt_signal", None)
+                if signal is not None:
+                    notice = signal.poll(round_idx)
+                    if notice is not None:
+                        # drain the depth-K window DETERMINISTICALLY before
+                        # the forced snapshot: every in-flight round's
+                        # confirmation waited on (same barrier as the depth
+                        # bound), deferred metrics out — the checkpoint then
+                        # holds exactly the rounds the WAL says it does
+                        while inflight:
+                            jax.block_until_ready(inflight.popleft())  # lint: host-sync-ok — preempt drain (same barrier as the depth bound)
+                        flush(None)
+                        self._extra_syncs += 1
+                        from ..parallel.elastic import preempt_now
 
-                    preempt_now(api, ckpt, round_idx, notice, saved=saved)
+                        preempt_now(api, ckpt, round_idx, notice, saved=saved)
 
-        flush(None)  # drain
+        with span("train.drain"):
+            flush(None)
         n_rounds = max(1, comm_rounds - start_round)
         self.stats = {
             "depth": self.depth,

@@ -40,6 +40,20 @@ def current_rss_bytes() -> int:
         return 0
 
 
+def cpu_steal_ticks() -> Optional[int]:
+    """The ``steal`` column of ``/proc/stat``'s ``cpu`` line: clock
+    ticks, summed over this machine's CPUs since boot, that the
+    hypervisor gave to someone else. The round loops record its
+    difference from round to round (``ProfilerEvent.iteration_span``) so a
+    stalled round can be told from a starved one. None off Linux."""
+    try:
+        with open("/proc/stat", "rb") as f:
+            fields = f.readline().split()
+        return int(fields[8])
+    except (OSError, ValueError, IndexError):
+        return None
+
+
 def peak_rss_bytes() -> int:
     """Lifetime peak resident set size of this process (ru_maxrss).
     Exported by the ``detail.planet`` bench as the

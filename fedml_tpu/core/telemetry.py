@@ -168,6 +168,19 @@ class FlightRecorder:
     def end(self, name: str, cat: str = "span", **args: Any) -> None:
         self._emit("E", name, cat, args or None)
 
+    def complete(
+        self, name: str, t0: float, t1: float, cat: str = "span", **args: Any
+    ) -> None:
+        """A span that has already ended, from its two
+        ``time.perf_counter`` readings: a B/E pair at those times, for
+        a caller that could not take the ring's lock while the span ran
+        (a garbage-collection callback)."""
+        for ph, t in (("B", t0), ("E", t1)):
+            self._emit(
+                ph, name, cat, args or None,
+                extra={"ts": round((t - self._t0) * 1e6, 1)},
+            )
+
     def instant(self, name: str, cat: str = "event", **args: Any) -> None:
         self._emit("i", name, cat, args or None)
 
@@ -346,9 +359,11 @@ class Telemetry:
             self._gauges[self._key(name, tags)] = float(value)
 
     def observe(
-        self, name: str, value: float, buckets=None, **tags: Any
+        self, name: str, value: float, /, buckets=None, **tags: Any
     ) -> None:
-        """Histogram-style observation (count / sum / min / max).
+        """Histogram-style observation (count / sum / min / max); the
+        series' name and the value are positional only, so ``name`` is
+        free as a tag (``span_seconds{name}``).
 
         With ``buckets`` (a sequence of upper bounds, fixed by the
         series' first observation), the series also keeps cumulative
@@ -416,8 +431,10 @@ class Telemetry:
 
     def attach_profiler(self, profiler) -> None:
         """Forward a ``ProfilerEvent``'s spans into the flight recorder
-        and expose its open spans to the debug bundle."""
+        and the ``span_seconds{name}`` histogram, and expose its open
+        spans to the debug bundle."""
         profiler.recorder = self.recorder
+        profiler.telemetry = self
         with self._lock:
             if profiler not in self._profilers:
                 self._profilers.append(profiler)

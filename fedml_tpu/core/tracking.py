@@ -25,11 +25,13 @@ and TPU.
 
 from __future__ import annotations
 
+import gc
 import json
 import logging
 import os
 import time
 from collections import defaultdict
+from contextlib import contextmanager
 from typing import Any, Callable, Dict, List, Optional
 
 EVENT_TYPE_STARTED = 0  # mlops_profiler_event.py:12
@@ -38,7 +40,12 @@ EVENT_TYPE_ENDED = 1  # mlops_profiler_event.py:13
 
 class ProfilerEvent:
     """Span recorder. ``log_event_started(name)`` /
-    ``log_event_ended(name)`` mirror the reference API."""
+    ``log_event_ended(name)`` mirror the reference API; ``span(name)``
+    is the one primitive the round loops are timed with: it opens a
+    ``jax.profiler.TraceAnnotation`` (the device trace's own clock),
+    mirrors B/E into the flight recorder and, at its end, observes the
+    ``span_seconds{name}`` histogram (docs/observability.md lists the
+    names)."""
 
     _instance: Optional["ProfilerEvent"] = None
 
@@ -46,12 +53,15 @@ class ProfilerEvent:
         self.args = args
         self.run_id = getattr(args, "run_id", "0") if args else "0"
         self._open: Dict[str, float] = {}
-        self.spans: List[Dict[str, Any]] = []
         self.totals: Dict[str, float] = defaultdict(float)
         self.counts: Dict[str, int] = defaultdict(int)
         # set by Telemetry.attach_profiler: spans are mirrored into the
-        # flight recorder's trace.json timeline (core/telemetry.py)
+        # flight recorder's trace.json timeline and observed into the
+        # registry (core/telemetry.py)
         self.recorder = None
+        self.telemetry = None
+        self._steal_ticks: Optional[int] = None
+        self._gc_done: List[Any] = []  # (t0, t1, generation, collected)
 
     @classmethod
     def get_instance(cls, args=None) -> "ProfilerEvent":
@@ -83,21 +93,94 @@ class ProfilerEvent:
         if t0 is None:
             logging.warning("span %r ended without start", event_name)
             return
+        dt = time.perf_counter() - t0
+        self._flush_gc()  # collections that ran inside this span, first
         if self.recorder is not None:
             self.recorder.end(event_name, cat="profiler", **trace_args)
-        dt = time.perf_counter() - t0
-        self.spans.append(
-            {"name": event_name, "duration_s": dt, "ended_at": time.time()}
-        )
-        self.totals[event_name] += dt
-        self.counts[event_name] += 1
+        self._tally(event_name, dt)
 
     def span(self, name: str, **trace_args: Any):
         """Context-manager sugar the reference lacks. ``trace_args``
-        land on the mirrored flight-recorder span (round / rank tags
-        the critical-path analyzer reads); the span record itself is
-        unchanged."""
+        land on the mirrored flight-recorder B event and on the trace
+        annotation (round / rank tags the critical-path analyzer
+        reads); what the body puts into the span's ``end_args`` lands
+        on its E event."""
         return _Span(self, name, **trace_args)
+
+    @contextmanager
+    def iteration_span(self, name: str, **trace_args: Any):
+        """The span of one iteration of a round or epoch loop
+        (``round``, ``epoch``). Its E event carries ``steal_ticks``:
+        what the hypervisor took from this machine's CPUs
+        (``/proc/stat``) since the previous iteration ended, or since
+        ``watch_stalls`` began."""
+        from .sys_stats import cpu_steal_ticks
+
+        with self.span(name, **trace_args) as sp:
+            try:
+                yield sp
+            finally:
+                ticks = cpu_steal_ticks()
+                if ticks is not None:
+                    if self._steal_ticks is not None:
+                        sp.end_args["steal_ticks"] = ticks - self._steal_ticks
+                    self._steal_ticks = ticks
+
+    @contextmanager
+    def watch_stalls(self):
+        """While inside, every garbage collection is a ``gc`` span
+        (``generation`` and ``collected`` as args) and the steal count
+        starts here: with JAX's compile events
+        (``core/compile_cache.py``) the three things that stall a round
+        loop from outside it.
+
+        A collection can begin at any allocation, also inside the
+        flight recorder's or the registry's lock, so the callback takes
+        no lock: it opens the trace annotation, keeps two clock
+        readings, and the next span to end (or this block's end) writes
+        the pair into the recorder at those times."""
+        from jax.profiler import TraceAnnotation
+
+        from .sys_stats import cpu_steal_ticks
+
+        running: List[Any] = []
+
+        def on_gc(phase: str, info: Dict[str, int]) -> None:
+            if phase == "start":
+                ann = TraceAnnotation("gc", generation=info["generation"])
+                ann.__enter__()
+                running[:] = [time.perf_counter(), ann]
+            elif running:
+                t0, ann = running
+                del running[:]
+                ann.__exit__(None, None, None)
+                self._gc_done.append(
+                    (t0, time.perf_counter(), info["generation"], info["collected"])
+                )
+
+        self._steal_ticks = cpu_steal_ticks()
+        gc.callbacks.append(on_gc)
+        try:
+            yield
+        finally:
+            gc.callbacks.remove(on_gc)
+            self._flush_gc()
+
+    def _flush_gc(self) -> None:
+        while self._gc_done:
+            t0, t1, generation, collected = self._gc_done.pop(0)
+            if self.recorder is not None:
+                self.recorder.complete(
+                    "gc", t0, t1, cat="profiler",
+                    generation=generation, collected=collected,
+                )
+            self._tally("gc", t1 - t0)
+
+    def _tally(self, name: str, dt: float) -> None:
+        self.totals[name] += dt
+        self.counts[name] += 1
+        if self.telemetry is not None:
+            self.telemetry.observe("span_seconds", dt, name=name)
 
     def summary(self) -> Dict[str, Dict[str, float]]:
         return {
@@ -110,6 +193,7 @@ class _Span:
     def __init__(self, ev: ProfilerEvent, name: str, **trace_args: Any) -> None:
         self.ev, self.name = ev, name
         self.trace_args = trace_args
+        self.end_args: Dict[str, Any] = {}
         self._annotation = None
 
     def __enter__(self):
@@ -117,7 +201,7 @@ class _Span:
         # named region in any active XLA device trace (no-op otherwise)
         import jax.profiler
 
-        self._annotation = jax.profiler.TraceAnnotation(self.name)
+        self._annotation = jax.profiler.TraceAnnotation(self.name, **self.trace_args)
         self._annotation.__enter__()
         return self
 
@@ -125,7 +209,7 @@ class _Span:
         if self._annotation is not None:
             self._annotation.__exit__(*exc)
             self._annotation = None
-        self.ev.log_event_ended(self.name)
+        self.ev.log_event_ended(self.name, **self.end_args)
         return False
 
 

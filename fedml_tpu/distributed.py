@@ -113,9 +113,14 @@ class DistributedTrainer:
         )
         self.compute_dtype = compute_dtype_from_args(args)
         self.optimizer = create_client_optimizer(args)
-        from .core.tracking import MetricsReporter
+        from .core.telemetry import Telemetry
+        from .core.tracking import MetricsReporter, ProfilerEvent
 
         self.metrics_reporter = MetricsReporter(args)
+        # the epoch loop's phase spans (run()) land in the process-wide
+        # flight recorder, as the round loops' do
+        self.profiler = ProfilerEvent(args)
+        Telemetry.get_instance(args).attach_profiler(self.profiler)
         init_rng = jax.random.PRNGKey(int(getattr(args, "random_seed", 0)))
         # distinct stream for the per-epoch shuffle permutations
         self._shuffle_key = jax.random.fold_in(init_rng, 0x51)
@@ -545,51 +550,64 @@ class DistributedTrainer:
                     {"kind": "distributed_train", **stats}
                 )
                 return stats
-            with device_trace(args), self.mesh:
+            # phase spans (docs/observability.md): `epoch` and its
+            # children tile the loop, so a stalled epoch says which
+            # phase stalled, with gc, compile and steal beside it
+            span = self.profiler.span
+            with device_trace(args), self.mesh, self.profiler.watch_stalls():
                 for ep in range(self._start_epoch, epochs):
-                    t0 = time.perf_counter()
-                    # epoch-INDEXED stream (fold_in, not sequential
-                    # split): a resumed run replays exactly the
-                    # permutations the interrupted run would have used;
-                    # every process derives the same host value, so the
-                    # shuffle is multi-controller consistent
-                    ep_rng = np.asarray(
-                        jax.random.fold_in(self._shuffle_key, ep)
-                    )
-                    self.params, self.opt_state, sums = self._epoch(
-                        self.params, self.opt_state, train, ep_rng
-                    )
-                    jax.block_until_ready(jax.tree.leaves(self.params)[0])
-                    dt = time.perf_counter() - t0
-                    train_m = self.model.metrics_from_sums(
-                        jax.tree.map(np.asarray, sums)
-                    )
-                    stats = {
-                        "epoch": ep,
-                        "train_loss": train_m["loss"],
-                        "train_acc": train_m["acc"],
-                        "epoch_time_s": dt,
-                        "tokens_per_sec": train_m["count"] / max(dt, 1e-9),
-                    }
-                    if (ep + 1) % eval_every == 0 or ep == epochs - 1:
-                        stats.update(self._evaluate(test))
-                    self.metrics_reporter.report(
-                        {"kind": "distributed_train", **stats}
-                    )
-                    logging.info("distributed epoch %d: %s", ep, stats)
-                    if self._ckpt and (
-                        (ep + 1) % self._ckpt_freq == 0 or ep == epochs - 1
-                    ):
-                        from flax.serialization import to_state_dict
+                    with self.profiler.iteration_span("epoch", epoch=ep):
+                        with span("epoch.place"):
+                            t0 = time.perf_counter()
+                            # epoch-INDEXED stream (fold_in, not
+                            # sequential split): a resumed run replays
+                            # exactly the permutations the interrupted
+                            # run would have used; every process derives
+                            # the same host value, so the shuffle is
+                            # multi-controller consistent
+                            ep_rng = np.asarray(
+                                jax.random.fold_in(self._shuffle_key, ep)
+                            )
+                        with span("epoch.dispatch"):
+                            self.params, self.opt_state, sums = self._epoch(
+                                self.params, self.opt_state, train, ep_rng
+                            )
+                        with span("epoch.wait"):
+                            jax.block_until_ready(jax.tree.leaves(self.params)[0])
+                            dt = time.perf_counter() - t0
+                        with span("epoch.fetch"):
+                            train_m = self.model.metrics_from_sums(
+                                jax.tree.map(np.asarray, sums)
+                            )
+                        stats = {
+                            "epoch": ep,
+                            "train_loss": train_m["loss"],
+                            "train_acc": train_m["acc"],
+                            "epoch_time_s": dt,
+                            "tokens_per_sec": train_m["count"] / max(dt, 1e-9),
+                        }
+                        if (ep + 1) % eval_every == 0 or ep == epochs - 1:
+                            with span("epoch.eval"):
+                                stats.update(self._evaluate(test))
+                        with span("epoch.report"):
+                            self.metrics_reporter.report(
+                                {"kind": "distributed_train", **stats}
+                            )
+                            logging.info("distributed epoch %d: %s", ep, stats)
+                        if self._ckpt and (
+                            (ep + 1) % self._ckpt_freq == 0 or ep == epochs - 1
+                        ):
+                            with span("epoch.ckpt"):
+                                from flax.serialization import to_state_dict
 
-                        self._ckpt.save(
-                            ep,
-                            {
-                                "params": self.params,
-                                "opt_state": to_state_dict(self.opt_state),
-                                "epoch": ep,
-                            },
-                        )
+                                self._ckpt.save(
+                                    ep,
+                                    {
+                                        "params": self.params,
+                                        "opt_state": to_state_dict(self.opt_state),
+                                        "epoch": ep,
+                                    },
+                                )
         finally:
             if self._ckpt is not None:
                 self._ckpt.close()

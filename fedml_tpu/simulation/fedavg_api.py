@@ -109,22 +109,27 @@ def build_round_fn(
     ):
         if on_trace is not None:
             on_trace(idx)
-        cohort = _take(packed, idx)
-        ns = jnp.take(nsamples, idx)
-        if valid is not None:
-            # shape-bucketed cohorts (core/round_pipeline.py): the
-            # padded slots repeat a real client index; zeroing their
-            # batch mask makes every batch fully-masked (local
-            # training reverts params exactly, metrics count 0) and
-            # normalize_weights(..., valid) gives them aggregation
-            # weight 0 — the same invisibility contract as
-            # parallel/mesh.py's pad_federation
-            vm = valid.reshape((-1,) + (1,) * (cohort.mask.ndim - 1))
-            cohort = Batches(
-                x=cohort.x,
-                y=cohort.y,
-                mask=cohort.mask * vm.astype(cohort.mask.dtype),
-            )
+        # the three scopes below are names in the HLO's op metadata and
+        # nothing else: the device trace's readers
+        # (benchmark/layer_metrics/_scopes.py) find the round's parts
+        # by them whatever the compiler numbers its instructions
+        with jax.named_scope("fed.gather"):
+            cohort = _take(packed, idx)
+            ns = jnp.take(nsamples, idx)
+            if valid is not None:
+                # shape-bucketed cohorts (core/round_pipeline.py): the
+                # padded slots repeat a real client index; zeroing their
+                # batch mask makes every batch fully-masked (local
+                # training reverts params exactly, metrics count 0) and
+                # normalize_weights(..., valid) gives them aggregation
+                # weight 0 — the same invisibility contract as
+                # parallel/mesh.py's pad_federation
+                vm = valid.reshape((-1,) + (1,) * (cohort.mask.ndim - 1))
+                cohort = Batches(
+                    x=cohort.x,
+                    y=cohort.y,
+                    mask=cohort.mask * vm.astype(cohort.mask.dtype),
+                )
         train_params = global_params
         if fed:
             from ..parallel.layout import fed_compute_constraints
@@ -161,33 +166,35 @@ def build_round_fn(
         if preprocess is not None:
             cohort, server_state = preprocess(cohort, server_state)
         rngs = jax.random.split(rng, idx.shape[0])
-        if use_round_lr:
-            # round-indexed LR: one multiplier for the whole cohort
-            new_stacked, train_metrics = jax.vmap(
-                local_train, in_axes=(None, 0, 0, None)
-            )(train_params, cohort, rngs, lr_mult)
-        else:
-            new_stacked, train_metrics = jax.vmap(
-                local_train, in_axes=(None, 0, 0)
-            )(train_params, cohort, rngs)
+        with jax.named_scope("fed.local_train"):
+            if use_round_lr:
+                # round-indexed LR: one multiplier for the whole cohort
+                new_stacked, train_metrics = jax.vmap(
+                    local_train, in_axes=(None, 0, 0, None)
+                )(train_params, cohort, rngs, lr_mult)
+            else:
+                new_stacked, train_metrics = jax.vmap(
+                    local_train, in_axes=(None, 0, 0)
+                )(train_params, cohort, rngs)
         if fed:
             from ..parallel.layout import pin_cohort_outputs
 
             # per-client compute stays whole; only the at-rest carry
             # is fsdp-sharded (see pin_cohort_outputs)
             new_stacked = pin_cohort_outputs(mesh, new_stacked)
-        weights = normalize_weights(ns, valid)
-        new_global, new_state = aggregate(
-            global_params, server_state, new_stacked, weights, cohort, rng
-        )
-        if fed:
-            from ..parallel.layout import constrain_tree
+        with jax.named_scope("fed.aggregate"):
+            weights = normalize_weights(ns, valid)
+            new_global, new_state = aggregate(
+                global_params, server_state, new_stacked, weights, cohort, rng
+            )
+            if fed:
+                from ..parallel.layout import constrain_tree
 
-            # the aggregated carry lands fsdp-sharded at rest — the
-            # donated (0, 1) chain never leaves the mesh, so zero host
-            # hops at any cohort size
-            new_global = constrain_tree(new_global, mesh)
-        summed = {k: v.sum() for k, v in train_metrics.items()}
+                # the aggregated carry lands fsdp-sharded at rest — the
+                # donated (0, 1) chain never leaves the mesh, so zero host
+                # hops at any cohort size
+                new_global = constrain_tree(new_global, mesh)
+            summed = {k: v.sum() for k, v in train_metrics.items()}
         if keep_stacked:
             return new_global, new_state, summed, new_stacked
         return new_global, new_state, summed
@@ -645,9 +652,12 @@ class FedAvgAPI:
 
         self._round_profiler = RoundProfiler(args)
         try:
-            return self._train_rounds(
-                packed, nsamples, comm_rounds, freq, ckpt, start_round
-            )
+            # gc spans and the steal count, beside the loops' own phase
+            # spans: what stalls a round from outside it
+            with self.profiler.watch_stalls():
+                return self._train_rounds(
+                    packed, nsamples, comm_rounds, freq, ckpt, start_round
+                )
         finally:
             if ckpt is not None:
                 ckpt.close()
@@ -712,61 +722,69 @@ class FedAvgAPI:
         cohort params on host every round."""
         args = self.args
         final_stats: Dict[str, float] = {}
+        # the round pipeline's span names (docs/observability.md), as
+        # far as this loop has the phase: it fetches inside `eval`, so
+        # there is no `round.wait` and no `flush.fetch` here
+        span = self.profiler.span
         for round_idx in range(start_round, comm_rounds):
             if getattr(self, "_round_profiler", None) is not None:
                 self._round_profiler.tick(round_idx)
-            t0 = time.perf_counter()
-            idx = self._client_sampling(
-                round_idx, self.dataset.client_num, int(args.client_num_per_round)
-            )
-            self.rng, round_rng = jax.random.split(self.rng)
-            if self._multi_controller:
-                round_rng = np.asarray(round_rng)  # lint: host-sync-ok — process-consistent host value (multi-controller rule)
-            lr_mult = self._lr_mult(round_idx)
-            with self.profiler.span("round"):
-                if self.mode == "sequential":
-                    new_global, summed = self._sequential_round(
-                        idx, round_rng, lr_mult, nsamples=nsamples
+            with self.profiler.iteration_span("round", round=round_idx):
+                with span("round.prep"):
+                    t0 = time.perf_counter()
+                    idx = self._client_sampling(
+                        round_idx, self.dataset.client_num, int(args.client_num_per_round)
                     )
-                    self.global_params = new_global
-                else:
-                    extra = () if lr_mult is None else (lr_mult,)
-                    with _devtime(
-                        self._round_exec_name(), bucket=f"b{len(idx)}"
-                    ):
-                        out = self._round_fn(
-                            self.global_params,
-                            self.server_state,
-                            packed,
-                            nsamples,
-                            np.asarray(idx) if self._multi_controller else jnp.asarray(idx),  # lint: host-sync-ok — idx is host numpy (sampling)
-                            round_rng,
-                            *extra,
+                    self.rng, round_rng = jax.random.split(self.rng)
+                    if self._multi_controller:
+                        round_rng = np.asarray(round_rng)  # lint: host-sync-ok — process-consistent host value (multi-controller rule)
+                    lr_mult = self._lr_mult(round_idx)
+                with span("round.dispatch"):
+                    if self.mode == "sequential":
+                        new_global, summed = self._sequential_round(
+                            idx, round_rng, lr_mult, nsamples=nsamples
                         )
-                    self.global_params, self.server_state, summed = out[:3]
-                    if self._keep_stacked:
-                        self._post_round_stacked(out[3], idx, round_rng)
-            if round_idx % freq == 0 or round_idx == comm_rounds - 1:
-                with self.profiler.span("eval"):
-                    stats = self._local_test_on_all_clients(round_idx)
-                stats["round"] = round_idx
-                stats["round_time_s"] = time.perf_counter() - t0
-                # eval-round metric fetch: the sync loop fetches at its
-                # eval cadence by design (the pipelined loop defers)
-                stats["train_loss_cohort"] = float(summed["loss_sum"]) / max(  # lint: host-sync-ok
-                    float(summed["count"]), 1.0  # lint: host-sync-ok — same eval-round fetch
-                )
-                self.history.append(stats)
-                final_stats = stats
-                self.metrics_reporter.report_server_training_metric(stats)
-            saved = False
-            if ckpt is not None and (
-                (round_idx + 1) % self._ckpt_freq == 0
-                or round_idx == comm_rounds - 1
-            ):
-                self._save_checkpoint(ckpt, round_idx)
-                saved = True
-            self._maybe_preempt(ckpt, round_idx, saved=saved)
+                        self.global_params = new_global
+                    else:
+                        extra = () if lr_mult is None else (lr_mult,)
+                        with _devtime(
+                            self._round_exec_name(), bucket=f"b{len(idx)}"
+                        ):
+                            out = self._round_fn(
+                                self.global_params,
+                                self.server_state,
+                                packed,
+                                nsamples,
+                                np.asarray(idx) if self._multi_controller else jnp.asarray(idx),  # lint: host-sync-ok — idx is host numpy (sampling)
+                                round_rng,
+                                *extra,
+                            )
+                        self.global_params, self.server_state, summed = out[:3]
+                        if self._keep_stacked:
+                            self._post_round_stacked(out[3], idx, round_rng)
+                if round_idx % freq == 0 or round_idx == comm_rounds - 1:
+                    with span("eval"):
+                        stats = self._local_test_on_all_clients(round_idx)
+                    with span("flush.report"):
+                        stats["round"] = round_idx
+                        stats["round_time_s"] = time.perf_counter() - t0
+                        # eval-round metric fetch: the sync loop fetches at its
+                        # eval cadence by design (the pipelined loop defers)
+                        stats["train_loss_cohort"] = float(summed["loss_sum"]) / max(  # lint: host-sync-ok
+                            float(summed["count"]), 1.0  # lint: host-sync-ok — same eval-round fetch
+                        )
+                        self.history.append(stats)
+                        final_stats = stats
+                        self.metrics_reporter.report_server_training_metric(stats)
+                saved = False
+                if ckpt is not None and (
+                    (round_idx + 1) % self._ckpt_freq == 0
+                    or round_idx == comm_rounds - 1
+                ):
+                    with span("round.ckpt"):
+                        self._save_checkpoint(ckpt, round_idx)
+                    saved = True
+                self._maybe_preempt(ckpt, round_idx, saved=saved)
         return final_stats
 
     # -- elastic preemption seam (parallel/elastic.py) ----------------

@@ -68,6 +68,9 @@ def compile_cache_reset():
         jcc.reset_cache()
     compile_cache._enabled_dir = None
     compile_cache._warned_conflict = False
+    # what enabling sets beside the directory goes back to JAX's own
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", False)
+    jax.config.update("jax_traceback_in_locations_limit", 10)
     compile_cache._counts.update(hits=0, misses=0)
 
 
