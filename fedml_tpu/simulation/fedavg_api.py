@@ -48,6 +48,7 @@ from ..core.optimizers import (
     create_server_optimizer,
     resolve_round_lr_schedule,
 )
+from ..core import sample_store
 from ..core.types import Batches
 from ..data.loader import FederatedDataset
 from ..models.spec import FedModel
@@ -72,6 +73,7 @@ def build_round_fn(
     use_round_lr: bool = False,
     keep_stacked: bool = False,
     on_trace=None,
+    sample_shape: Optional[Tuple[int, ...]] = None,
 ):
     """THE round engine, as a pure function of its collaborators.
 
@@ -98,6 +100,14 @@ def build_round_fn(
     mesh round bitwise identical to the single-chip vmap path), and
     pins the aggregated output back onto the fsdp layout so the
     chained/donated carry never leaves the mesh.
+
+    ``sample_shape``: the static per-sample shape of a federation whose
+    ``packed`` argument is the flat sample store
+    (``core/sample_store.py``: ``x`` as ``[N, nb, B, F]``, a client one
+    contiguous block); the gathered cohort is reshaped back to it, so
+    everything after ``fed.gather`` sees the cohort it always saw. None
+    where a sample is one-dimensional and ``packed`` is the dataset's
+    own arrays.
     """
     from ..parallel.layout import is_fed_mesh
 
@@ -115,6 +125,10 @@ def build_round_fn(
         # by them whatever the compiler numbers its instructions
         with jax.named_scope("fed.gather"):
             cohort = _take(packed, idx)
+            if sample_shape is not None:
+                cohort = cohort.replace(
+                    x=cohort.x.reshape(cohort.mask.shape + sample_shape)
+                )
             ns = jnp.take(nsamples, idx)
             if valid is not None:
                 # shape-bucketed cohorts (core/round_pipeline.py): the
@@ -410,8 +424,13 @@ class FedAvgAPI:
             )
         self.history: List[Dict[str, float]] = []
         # populated by core/round_pipeline.py after train(): depth,
-        # bucket, flushes, host_syncs_per_round
+        # bucket, flushes, host_syncs_per_round; train() adds
+        # store_stagings
         self.pipeline_stats: Dict[str, Any] = {}
+        # (dataset.packed_train.x it was made from, the store): on the
+        # object, so that dropping the API frees the store's copy too
+        self._store: Optional[Tuple[jax.Array, Batches]] = None
+        self._store_stagings = 0
 
         self.rng = jax.random.PRNGKey(int(getattr(args, "random_seed", 0)))
         self.rng, init_rng = jax.random.split(self.rng)
@@ -572,6 +591,7 @@ class FedAvgAPI:
             use_round_lr=self._round_lr is not None,
             keep_stacked=self._keep_stacked,
             on_trace=on_trace,
+            sample_shape=sample_store.sample_shape(self.dataset.packed_train),
         )
         self._round_fn = jax.jit(round_fn, donate_argnums=(0, 1))
         # donation deliberately NOT safe here: the sequential loop
@@ -597,6 +617,22 @@ class FedAvgAPI:
         """Host-side hook fed the per-client cohort params when
         ``_keep_stacked`` is set (overridden by S-FedAvg / TurboAggregate)."""
 
+    def _sample_store(self) -> Batches:
+        """What ``_round_fn`` gathers the cohort from: the dataset's
+        packed training split with every sample flattened
+        (``core/sample_store.py``), made once per ``packed_train.x`` and
+        before a loop's first round; the dataset's own ``Batches`` where
+        its samples are one-dimensional already."""
+        packed = self.dataset.packed_train
+        if self._store is None or self._store[0] is not packed.x:
+            self._store = None  # the old copy goes before the new one comes
+            store, facts = sample_store.stage(packed)
+            self._store = (packed.x, store)
+            self._store_stagings += 1
+            if self.telemetry.enabled:
+                self.telemetry.recorder.instant("store.staged", cat="data", **facts)
+        return self._store[1]
+
     # -- reference-parity sampling ------------------------------------
     def _client_sampling(
         self, round_idx: int, client_num_in_total: int, client_num_per_round: int
@@ -619,7 +655,13 @@ class FedAvgAPI:
             # jit inputs under multi-controller must be global arrays or
             # process-consistent host values — never locally-committed
             # device arrays (every process holds the same host copy)
-            packed = self.dataset.packed_train
+            # (the sequential loop indexes the dataset's arrays by hand
+            # and would hold a store for nothing)
+            packed = (
+                self.dataset.packed_train
+                if self.mode == "sequential"
+                else self._sample_store()
+            )
             nsamples = (
                 # one pre-loop conversion to a process-consistent host
                 # value (multi-controller jit-input rule, comment above)
@@ -655,9 +697,11 @@ class FedAvgAPI:
             # gc spans and the steal count, beside the loops' own phase
             # spans: what stalls a round from outside it
             with self.profiler.watch_stalls():
-                return self._train_rounds(
+                stats = self._train_rounds(
                     packed, nsamples, comm_rounds, freq, ckpt, start_round
                 )
+            self.pipeline_stats["store_stagings"] = self._store_stagings
+            return stats
         finally:
             if ckpt is not None:
                 ckpt.close()

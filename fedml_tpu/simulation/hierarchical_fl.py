@@ -47,7 +47,7 @@ class HierarchicalFLAPI(FedAvgAPI):
 
     def train(self) -> Dict[str, float]:
         args = self.args
-        packed = self.dataset.packed_train
+        packed = self._sample_store()
         nsamples = jnp.asarray(self.dataset.packed_num_samples)
         groups = self._groups()
         group_rounds = int(getattr(args, "group_comm_round", 1))

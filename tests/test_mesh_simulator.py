@@ -152,6 +152,14 @@ class TestFedMesh:
             )
             # compile census: one trace per world
             assert sim.fl_trainer._round_trace_count == 1
+            # the round gathered from the flat sample store, placed as
+            # the federation is: client axis along 'data'
+            api = sim.fl_trainer
+            store = api._sample_store()
+            assert api.pipeline_stats["store_stagings"] == 1
+            assert store.x.ndim == 4 and store.x.sharding.is_equivalent_to(
+                api.dataset.packed_train.x.sharding, 4
+            )
 
     def test_params_fsdp_sharded_at_rest(self, eight_devices, args_factory):
         """The carried global params live fsdp-sharded on the mesh —

@@ -136,16 +136,17 @@ class TestEngine:
             api_c.global_params,
         )
 
-        packed = dataset.packed_train
+        # what train() hands the round executable: the API's sample store
         ns = jnp.asarray(dataset.packed_num_samples)
         idx = jnp.arange(4, dtype=jnp.int32)
         rng = jax.random.PRNGKey(42)
         p_s, _, _ = api_s._round_fn(
-            api_s.global_params, api_s.server_state, packed, ns, idx, rng,
-            api_s._lr_mult(r_probe),
+            api_s.global_params, api_s.server_state, api_s._sample_store(),
+            ns, idx, rng, api_s._lr_mult(r_probe),
         )
         p_c, _, _ = api_c._round_fn(
-            api_c.global_params, api_c.server_state, packed, ns, idx, rng
+            api_c.global_params, api_c.server_state, api_c._sample_store(),
+            ns, idx, rng,
         )
         jax.tree.map(
             lambda a, b: np.testing.assert_allclose(

@@ -15,7 +15,7 @@ import pytest
 
 import fedml_tpu
 from fedml_tpu import models
-from fedml_tpu.core import sys_stats
+from fedml_tpu.core import sample_store, sys_stats
 from fedml_tpu.core.round_pipeline import RoundPipeline
 from fedml_tpu.core.telemetry import Telemetry
 from fedml_tpu.core.tracking import ProfilerEvent
@@ -69,7 +69,11 @@ def _round_case():
         return weighted_average(stacked, weights), server_state
 
     def make():
-        return jax.jit(build_round_fn(ctx.local_train_fn(), aggregate))
+        # as _build_jitted builds it: the audit's samples are flat, so
+        # there is no shape to restore after the gather
+        return jax.jit(build_round_fn(
+            ctx.local_train_fn(), aggregate,
+            sample_shape=sample_store.sample_shape(ctx.abstract_batches(8))))
 
     return ctx, make
 
