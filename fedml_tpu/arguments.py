@@ -442,6 +442,31 @@ _DEFAULTS: Dict[str, Any] = {
     "moe_every": 2,  # every Nth transformer block is a Switch MoE layer
     "num_experts": 8,  # Switch MoE expert count
     "capacity_factor": 1.25,  # MoE per-expert token capacity slack
+    # model: moe_decoder (models/decoder.py): RMSNorm, grouped-KV rotary
+    # attention, window and full layers, routed gated-SiLU experts
+    "hidden_size": 256,  # moe_decoder model width
+    "num_kv_heads": 2,  # moe_decoder KV heads (num_heads a multiple)
+    "head_dim": 64,  # moe_decoder head width (not tied to hidden_size / num_heads)
+    # moe_decoder layer pattern, one entry per layer: "sliding_attention"
+    # | "full_attention" (None = num_layers full layers)
+    "layer_types": None,
+    "sliding_window": 1024,  # moe_decoder: keys a sliding layer sees, itself included
+    # moe_decoder rotary parameters per layer type, as a published
+    # config.json has them: {layer type: {rope_type: default | yarn,
+    # rope_theta, factor, original_max_position_embeddings, beta_fast,
+    # beta_slow, attention_factor}} (None = rope_type default at
+    # rope_theta 10000 on both layer types)
+    "rope_parameters": None,
+    "experts_per_token": 2,  # moe_decoder: experts a token is routed to (top-k)
+    "expert_dim": 128,  # moe_decoder: width of one expert's gated MLP
+    "norm_topk_prob": True,  # moe_decoder: renormalise the top-k routing weights
+    "rms_norm_eps": 1e-6,  # moe_decoder RMSNorm epsilon
+    # moe_decoder: the chips that share each expert layer, and which of
+    # them this is: the layer holds num_experts / expert_parallel
+    # experts from expert_rank's first, routes over all num_experts and
+    # computes its own experts' part (parallel/expert.py experts_held)
+    "expert_parallel": 1,
+    "expert_rank": 0,
     "nas_width": 16,  # FedNAS stem channels
     "nas_cells": 2,  # FedNAS cells per client model
     "nas_steps": 2,  # FedNAS nodes per cell

@@ -99,6 +99,10 @@ def make_local_train_fn(
 
     ``metrics`` carries the last epoch's summed ``loss_sum`` /
     ``correct`` / ``count`` so callers can weight by true sample count.
+    ``apply_fn`` may return ``(logits, counters)`` (``FedModel.
+    apply_counted``: a dict of float32 scalars, e.g. an expert layer's
+    token counts); they are summed over the epoch's steps and ride in
+    ``metrics`` under their own names.
 
     Donation contract: the function is pure in its arguments — it never
     aliases ``params`` into its outputs' buffers itself, so the round
@@ -112,12 +116,12 @@ def make_local_train_fn(
 
     def batch_loss(params, global_params, x, y, mask):
         if compute_dtype is not None:
-            logits = apply_fn(
-                _cast_floats(params, compute_dtype), _cast_floats(x, compute_dtype)
-            ).astype(jnp.float32)
+            out = apply_fn(_cast_floats(params, compute_dtype), _cast_floats(x, compute_dtype))
         else:
-            logits = apply_fn(params, x)
-        loss, metrics = loss_fn(logits, y, mask)
+            out = apply_fn(params, x)
+        logits, counters = out if isinstance(out, tuple) else (out, {})
+        loss, metrics = loss_fn(logits.astype(jnp.float32), y, mask)
+        metrics = {**metrics, "counters": counters}
         if prox_mu > 0.0:
             sq = sum(
                 jnp.vdot(p - g, p - g)
@@ -167,6 +171,7 @@ def make_local_train_fn(
                 .astype(jnp.float32),
                 "correct": metrics["correct"].sum().astype(jnp.float32),
                 "count": metrics["count"].sum().astype(jnp.float32),
+                **{k: v.sum().astype(jnp.float32) for k, v in metrics["counters"].items()},
             }
             return (p, s), summed
 
@@ -176,6 +181,17 @@ def make_local_train_fn(
         return params, last
 
     return local_train
+
+
+def model_counters(summed) -> Dict[str, float]:
+    """What a counting model (``FedModel.apply_counted``) added to a round's
+    summed training metrics, as host floats: the round's record carries
+    them beside the loss. ``summed`` is already on the host or is being
+    fetched with the loss at an evaluation round."""
+    return {
+        k: float(v)  # lint: host-sync-ok — the eval-round fetch, with the loss
+        for k, v in summed.items() if k not in ("loss_sum", "correct", "count")
+    }
 
 
 def make_eval_fn(

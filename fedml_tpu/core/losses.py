@@ -56,11 +56,14 @@ def token_cross_entropy(
     """
     if mask.ndim == labels.ndim - 1:
         mask = jnp.broadcast_to(mask[..., None], labels.shape)
-    logp = jax.nn.log_softmax(logits, axis=-1)
-    ll = jnp.take_along_axis(logp, labels[..., None], axis=-1)[..., 0]
-    loss = _mean_over_mask(-ll, mask)
-    correct = (jnp.argmax(logits, axis=-1) == labels).astype(jnp.float32)
-    acc = _mean_over_mask(correct, mask)
+    # HLO op metadata only: with the LM's output head this is the scope
+    # the device trace's head_loss_device_ms reads
+    with jax.named_scope("lm.head_loss"):
+        logp = jax.nn.log_softmax(logits, axis=-1)
+        ll = jnp.take_along_axis(logp, labels[..., None], axis=-1)[..., 0]
+        loss = _mean_over_mask(-ll, mask)
+        correct = (jnp.argmax(logits, axis=-1) == labels).astype(jnp.float32)
+        acc = _mean_over_mask(correct, mask)
     return loss, {
         "loss": loss,
         "correct": (correct * mask).sum(),

@@ -54,6 +54,7 @@ import numpy as np
 # part of this module's public surface before the factor-out
 from .bucketing import bucket_cohort, pad_cohort_idx  # noqa: F401
 from .devtime import measure as _devtime
+from .local_trainer import model_counters
 from .tracking import DeferredMetrics
 
 __all__ = ["RoundPipeline", "bucket_cohort", "pad_cohort_idx"]
@@ -104,8 +105,12 @@ class RoundPipeline:
         self._extra_syncs = 0  # non-metric fetches (drains count wall time only)
 
     # -- horizon precompute -------------------------------------------
-    def _precompute(self, start_round: int, comm_rounds: int):
+    def precompute(self, start_round: int, comm_rounds: int):
         """Indices / RNG chain / LR multipliers for [start, comm_rounds).
+        Reads the API and changes nothing in it, so a caller may plan a
+        horizon ahead of ``train()`` to have its host-side programs (the
+        chain's scan and the slices of its keys, shaped by the round
+        count) built before the first call of that length.
 
         The RNG chain reproduces the synchronous loop's per-round
         ``self.rng, k = split(self.rng)`` sequence exactly — generated
@@ -166,7 +171,7 @@ class RoundPipeline:
                 max_size=int(api.dataset.client_num),  # lint: host-sync-ok — host metadata
                 shard_multiple=shard_multiple,
             )
-            idx_plan, lr_plan, key_plan, head_plan = self._precompute(
+            idx_plan, lr_plan, key_plan, head_plan = self.precompute(
                 start_round, comm_rounds
             )
 
@@ -365,4 +370,5 @@ class RoundPipeline:
             "train_loss_cohort": float(summed["loss_sum"])  # lint: host-sync-ok
             / max(float(summed["count"]), 1.0),  # lint: host-sync-ok
         }
+        stats.update(model_counters(summed))
         return stats

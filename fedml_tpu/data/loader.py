@@ -62,6 +62,11 @@ _DATASET_META = {
     "shakespeare": ((80,), 90, 16000, 2000, "nwp"),
     "fed_shakespeare": ((80,), 90, 16000, 2000, "nwp"),
     "stackoverflow_nwp": ((20,), 10004, 40000, 8000, "nwp"),
+    # a token corpus with no fixed geometry (an LM fine-tune's packed
+    # sequences): args.seq_len and args.vocab_size state the sequence
+    # length and the vocabulary; stand-in only (a Markov chain over the
+    # stated vocabulary, data/synthetic.py), no real copy is looked for
+    "token_stream": ((1024,), 32000, 2048, 256, "nwp"),
     # multi-label tag prediction (reference data/stackoverflow_lr/:
     # 10k bag-of-words -> 500 tags); the synthetic stand-in shrinks the
     # feature dim so the offline path stays in memory
@@ -227,6 +232,8 @@ def _standin_shape_and_sizes(args, name: str):
         # without this the long-context path would silently train at
         # the dataset's canonical length (shakespeare: 80)
         shape = (int(args.seq_len),)
+    if name == "token_stream" and int(getattr(args, "vocab_size", 0) or 0) > 0:
+        class_num = int(args.vocab_size)
     train_n = int(getattr(args, "synthetic_train_size", min(train_n, 20000)))
     test_n = int(getattr(args, "synthetic_test_size", min(test_n, 4000)))
     return shape, class_num, train_n, test_n, task

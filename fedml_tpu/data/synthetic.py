@@ -257,6 +257,12 @@ def synthetic_segmentation(
     return x, y
 
 
+# above this vocabulary the chain's rows are sparse: a dense
+# [vocab, vocab] transition matrix is 800 MB at 10,004 and 1.2 GB at 12,288
+_DENSE_CHAIN_VOCAB = 2048
+_CHAIN_FANOUT = 16
+
+
 def synthetic_sequences(
     n_samples: int,
     seq_len: int,
@@ -265,17 +271,31 @@ def synthetic_sequences(
 ) -> Tuple[np.ndarray, np.ndarray]:
     """Markov-chain token streams for NWP models: x = tokens[:-1],
     y = tokens[1:]. The chain's structure makes next-token prediction
-    learnable above chance."""
+    learnable above chance. Up to ``_DENSE_CHAIN_VOCAB`` tokens a row of
+    the chain is a Dirichlet(0.05) draw over the whole vocabulary; above
+    it (a real vocabulary: 10,004, 12,288, 98,304) each token has
+    ``_CHAIN_FANOUT`` successors drawn at random with Dirichlet(0.5)
+    weights, so memory and time grow with ``n_samples * seq_len`` and
+    not with ``vocab_size ** 2``."""
     rng = np.random.RandomState(seed)
-    # sparse row-stochastic transition matrix
-    trans = rng.dirichlet(np.full(vocab_size, 0.05), size=vocab_size)
     toks = np.zeros((n_samples, seq_len + 1), np.int64)
     toks[:, 0] = rng.randint(0, vocab_size, n_samples)
+    if vocab_size <= _DENSE_CHAIN_VOCAB:
+        # sparse row-stochastic transition matrix
+        trans = rng.dirichlet(np.full(vocab_size, 0.05), size=vocab_size)
+        for t in range(seq_len):
+            p = trans[toks[:, t]]
+            cum = p.cumsum(axis=1)
+            u = rng.rand(n_samples, 1)
+            toks[:, t + 1] = (u > cum).sum(axis=1)
+        return toks[:, :-1], toks[:, 1:]
+    succ = rng.randint(0, vocab_size, (vocab_size, _CHAIN_FANOUT))
+    cum = rng.dirichlet(np.full(_CHAIN_FANOUT, 0.5), size=vocab_size).cumsum(axis=1)
+    u = rng.rand(n_samples, seq_len)
     for t in range(seq_len):
-        p = trans[toks[:, t]]
-        cum = p.cumsum(axis=1)
-        u = rng.rand(n_samples, 1)
-        toks[:, t + 1] = (u > cum).sum(axis=1)
+        cur = toks[:, t]
+        pick = np.minimum((u[:, t, None] > cum[cur]).sum(axis=1), _CHAIN_FANOUT - 1)
+        toks[:, t + 1] = succ[cur, pick]
     return toks[:, :-1], toks[:, 1:]
 
 

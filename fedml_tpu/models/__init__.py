@@ -47,6 +47,14 @@ def _example_shape(args, default=(28, 28, 1)):
     return _IMAGE_SHAPES.get(ds, default)
 
 
+def _lm_geometry(args, output_dim: int):
+    """``(vocab, seq_len)`` of a transformer LM. The dataset's class_num
+    is the vocabulary's floor (see the rnn branch's note on
+    out-of-range embedding look-ups)."""
+    vocab = max(int(getattr(args, "vocab_size", 0) or 0), output_dim)
+    return vocab, int(getattr(args, "seq_len", 64))
+
+
 def create(args, output_dim: int) -> FedModel:
     """Factory (model_hub.py:13-53 semantics)."""
     name = getattr(args, "model", "lr").lower()
@@ -179,9 +187,7 @@ def create(args, output_dim: int) -> FedModel:
     if name == "transformer":
         from .transformer import TransformerLM
 
-        # class_num is the floor (see the rnn branch note on OOB lookups)
-        vocab = max(int(getattr(args, "vocab_size", 0) or 0), output_dim)
-        seq_len = int(getattr(args, "seq_len", 64))
+        vocab, seq_len = _lm_geometry(args, output_dim)
         return FedModel(
             name="transformer_lm",
             module=TransformerLM(
@@ -200,8 +206,7 @@ def create(args, output_dim: int) -> FedModel:
     if name == "moe_transformer":
         from .moe import MoETransformerLM
 
-        vocab = max(int(getattr(args, "vocab_size", 0) or 0), output_dim)
-        seq_len = int(getattr(args, "seq_len", 64))
+        vocab, seq_len = _lm_geometry(args, output_dim)
         return FedModel(
             name="moe_transformer_lm",
             module=MoETransformerLM(
@@ -213,6 +218,42 @@ def create(args, output_dim: int) -> FedModel:
                 num_experts=int(getattr(args, "num_experts", 8)),
                 capacity_factor=float(getattr(args, "capacity_factor", 1.25)),
                 moe_every=int(getattr(args, "moe_every", 2)),
+                attention=getattr(args, "attention_impl", "full"),
+                remat=bool(getattr(args, "remat", False)),
+            ),
+            task="nwp",
+            example_shape=(seq_len,),
+            example_dtype=jnp.int32,
+        )
+    if name == "moe_decoder":
+        from ..parallel.expert import experts_held
+        from .decoder import FULL, MoEDecoderLM, rope_parameters_from_args
+
+        vocab, seq_len = _lm_geometry(args, output_dim)
+        num_experts = int(getattr(args, "num_experts", 8))
+        layer_types = getattr(args, "layer_types", None) or (
+            [FULL] * int(getattr(args, "num_layers", 2)))
+        return FedModel(
+            name="moe_decoder_lm",
+            module=MoEDecoderLM(
+                vocab_size=vocab,
+                hidden_size=int(getattr(args, "hidden_size", 256)),
+                layer_types=tuple(layer_types),
+                num_heads=int(getattr(args, "num_heads", 4)),
+                num_kv_heads=int(getattr(args, "num_kv_heads", 2)),
+                head_dim=int(getattr(args, "head_dim", 64)),
+                sliding_window=int(getattr(args, "sliding_window", 1024)),
+                rope_parameters=rope_parameters_from_args(args),
+                num_experts=num_experts,
+                experts_per_token=int(getattr(args, "experts_per_token", 2)),
+                expert_dim=int(getattr(args, "expert_dim", 128)),
+                experts_held=tuple(experts_held(
+                    num_experts,
+                    int(getattr(args, "expert_parallel", 1) or 1),
+                    int(getattr(args, "expert_rank", 0) or 0),
+                )),
+                norm_topk_prob=bool(getattr(args, "norm_topk_prob", True)),
+                rms_norm_eps=float(getattr(args, "rms_norm_eps", 1e-6)),
                 attention=getattr(args, "attention_impl", "full"),
                 remat=bool(getattr(args, "remat", False)),
             ),

@@ -1,0 +1,451 @@
+"""A config-driven decoder LM: the block today's open models share.
+
+``models/transformer.py`` is a GPT-2 block (LayerNorm, learned
+positions, fused qkv, GELU) and cannot express this one: RMSNorm,
+separate q/k/v/o projections whose ``head_dim`` is not
+``hidden / heads``, grouped KV heads, q and k RMS-normalised per head,
+a rotary embedding whose parameters depend on the layer's *type*
+(``sliding_attention`` layers attend a causal window with the default
+rotary table, ``full_attention`` layers attend everything before them
+and may carry YaRN), and a routed gated-SiLU expert layer in place of
+every MLP. All sizes are arguments; nothing is a model's name.
+
+**The expert layer holds a share.** ``experts_held = (first, count)``
+(``parallel/expert.py``) says which of the ``num_experts`` experts live
+on this chip. The router keeps its published width: it scores all of
+them in float32, takes the top ``experts_per_token`` and renormalises;
+the layer then keeps the token-choices that landed on held experts,
+sorts them by expert, runs one grouped gated-SiLU product over the held
+stacks (``jax.lax.ragged_dot``: each row meets only its own expert's
+matrices) and adds each row, times its routing weight, back onto its
+token. What the absent experts would add is left out and the partial
+sum goes on — on one chip the layer runs without its exchange, and no
+code stands in for absent chips. Nothing is dropped at any imbalance:
+the sorted list has room for every choice a token can place here, and
+is worked through in chunks of an even load's rows and a quarter, as
+many as the choices that did land fill: one at a load near even (the
+chunks it never reaches cost nothing), more at an imbalance.
+
+The round engine vmaps local training over the cohort's lanes, and the
+chip's ragged product takes no batch dimension, so the grouped part is
+a ``custom_vjp`` whose forward and backward each run one lane after
+another (``jax.custom_batching.sequential_vmap``): every lane brings its
+own expert weights, as a vmapped cohort must, and one lane's rows at a
+time is what the chip has room for (batched over two lanes the round
+executable needs 15.8 GB, lane after lane 13.5; compiled for a described
+v5e, PR 28). Inside that lane loop JAX's name stack starts anew: its
+operations carry ``moe.route`` / ``moe.experts`` / ``moe.combine`` and
+not the scopes around the model (``fed.local_train``).
+
+Scopes (HLO op metadata; ``benchmark/layer_metrics`` reads them from
+device traces): ``lm.embed``, ``blk.attn.window``, ``blk.attn.full``,
+``moe.route``, ``moe.experts``, ``moe.combine``, ``lm.head_loss`` (the
+head here, the loss in ``core/losses.py``). Counters (collection
+``counters``, summed over layers; ``FedModel.apply_counted``):
+``moe_local_hits``, ``moe_expert_tokens_max``,
+``moe_expert_tokens_mean``, ``moe_dropped``.
+"""
+
+from __future__ import annotations
+
+import functools
+import math
+from typing import Any, Dict, Optional, Sequence, Tuple
+
+import flax.linen as nn
+import jax
+import jax.numpy as jnp
+import numpy as np
+from flax.core import freeze
+from jax.custom_batching import sequential_vmap
+
+SLIDING, FULL = "sliding_attention", "full_attention"
+_NEG_INF = -1e30
+
+
+# -- rotary embedding ---------------------------------------------------
+
+def rope_inv_freq(head_dim: int, rope: Dict[str, Any]) -> Tuple[np.ndarray, float]:
+    """``(inv_freq [head_dim / 2], scale)`` of one layer type's rotary
+    parameters: ``rope_type`` ``default`` (``theta ** (-2i / d)``, scale
+    1) or ``yarn`` (Peng et al. 2023, arXiv:2309.00071: the
+    interpolated and the extrapolated frequencies blended by a linear
+    ramp between the dimensions that turn ``beta_fast`` and ``beta_slow``
+    times over the original context; cos and sin scaled by
+    ``attention_factor``, by default ``0.1 ln(factor) + 1``)."""
+    theta = float(rope["rope_theta"])
+    half = np.arange(0, head_dim, 2, dtype=np.float64) / head_dim
+    pos_freqs = theta ** half
+    kind = rope.get("rope_type", "default")
+    if kind == "default":
+        return (1.0 / pos_freqs).astype(np.float32), 1.0
+    if kind != "yarn":
+        raise ValueError(f"rope_type {kind!r}: only 'default' and 'yarn' are built")
+    factor = float(rope["factor"])
+    original = float(rope["original_max_position_embeddings"])
+
+    def correction_dim(rotations: float) -> float:
+        return head_dim * math.log(original / (rotations * 2 * math.pi)) / (2 * math.log(theta))
+
+    low = max(math.floor(correction_dim(float(rope.get("beta_fast", 32)))), 0)
+    high = min(math.ceil(correction_dim(float(rope.get("beta_slow", 1)))), head_dim - 1)
+    if low == high:
+        high += 0.001  # the ramp's width may not be 0
+    ramp = np.clip((np.arange(head_dim // 2, dtype=np.float64) - low) / (high - low), 0.0, 1.0)
+    inv_freq = (1.0 / (factor * pos_freqs)) * ramp + (1.0 / pos_freqs) * (1.0 - ramp)
+    scale = rope.get("attention_factor")
+    if scale is None:
+        scale = 0.1 * math.log(factor) + 1.0 if factor > 1 else 1.0
+    return inv_freq.astype(np.float32), float(scale)
+
+
+def rope_tables(seq_len: int, head_dim: int, rope: Dict[str, Any]):
+    """``(cos, sin)`` [T, head_dim] in float32, the two halves alike
+    (the rotate-half convention)."""
+    inv_freq, scale = rope_inv_freq(head_dim, rope)
+    angles = jnp.arange(seq_len, dtype=jnp.float32)[:, None] * jnp.asarray(inv_freq)[None]
+    angles = jnp.concatenate([angles, angles], axis=-1)
+    return jnp.cos(angles) * scale, jnp.sin(angles) * scale
+
+
+def apply_rope(x, cos, sin):
+    """``x`` [B, T, heads, D]; rotated in float32, returned in its dtype."""
+    x32 = x.astype(jnp.float32)
+    half = x.shape[-1] // 2
+    rotated = jnp.concatenate([-x32[..., half:], x32[..., :half]], axis=-1)
+    return (x32 * cos[None, :, None] + rotated * sin[None, :, None]).astype(x.dtype)
+
+
+# -- attention ------------------------------------------------------------
+
+def dense_attention(q, k, v, window: Optional[int]):
+    """Dense masked grouped-KV attention, [T, T] scores in float32: the
+    small-size path (``attention="full"``)."""
+    B, T, H, D = q.shape
+    KV = k.shape[2]
+    qg = q.reshape(B, T, KV, H // KV, D)
+    s = jnp.einsum("bqhgd,bkhd->bhgqk", qg, k, preferred_element_type=jnp.float32) * D**-0.5
+    i, j = jnp.arange(T)[:, None], jnp.arange(T)[None]
+    keep = i >= j
+    if window is not None:
+        keep = keep & (i - j < window)
+    p = jax.nn.softmax(jnp.where(keep, s, _NEG_INF), axis=-1).astype(v.dtype)
+    return jnp.einsum("bhgqk,bkhd->bqhgd", p, v).reshape(B, T, H, D)
+
+
+def _flash_block(seq_len: int) -> int:
+    """The forward kernel's tile: the largest of 512 / 256 / 128 that
+    divides the sequence (a wider tile feeds the MXU longer products;
+    a sequence the kernel cannot tile raises there)."""
+    return next((b for b in (512, 256) if seq_len % b == 0), 128)
+
+
+def attend(q, k, v, window: Optional[int], impl: str):
+    if impl == "full":
+        return dense_attention(q, k, v, window)
+    if impl != "flash":
+        raise ValueError(f"attention {impl!r}: the decoder block builds 'full' and 'flash'")
+    from ..ops.flash_attention import flash_attention
+
+    b = _flash_block(q.shape[1])
+    # a shape the kernel cannot tile raises (same rule on CPU and chip):
+    # attention="flash" never quietly becomes another path
+    return flash_attention(q, k, v, True, None, b, b, window)
+
+
+class RMSNorm(nn.Module):
+    eps: float = 1e-6
+
+    @nn.compact
+    def __call__(self, x):
+        scale = self.param("scale", nn.initializers.ones, (x.shape[-1],))
+        x32 = x.astype(jnp.float32)
+        y = x32 * jax.lax.rsqrt(jnp.mean(x32 * x32, axis=-1, keepdims=True) + self.eps)
+        return (y * scale.astype(jnp.float32)).astype(x.dtype)
+
+
+def _proj(features: int, name: str) -> nn.Dense:
+    return nn.Dense(features, use_bias=False, name=name)
+
+
+class Attention(nn.Module):
+    num_heads: int
+    num_kv_heads: int
+    head_dim: int
+    window: Optional[int]  # None: a full (causal) layer
+    impl: str
+    eps: float
+
+    @nn.compact
+    def __call__(self, x, cos, sin):
+        B, T, _ = x.shape
+        H, KV, D = self.num_heads, self.num_kv_heads, self.head_dim
+        q = _proj(H * D, "q_proj")(x).reshape(B, T, H, D)
+        k = _proj(KV * D, "k_proj")(x).reshape(B, T, KV, D)
+        v = _proj(KV * D, "v_proj")(x).reshape(B, T, KV, D)
+        # q and k are RMS-normalised per head before the rotation
+        q = apply_rope(RMSNorm(self.eps, name="q_norm")(q), cos, sin)
+        k = apply_rope(RMSNorm(self.eps, name="k_norm")(k), cos, sin)
+        o = attend(q, k, v, self.window, self.impl)
+        return _proj(x.shape[-1], "o_proj")(o.reshape(B, T, H * D))
+
+
+# -- the expert layer -----------------------------------------------------
+
+def _gated_silu(xs, wg, wu, wd, sizes):
+    """Rows sorted by expert through their own expert's gated-SiLU MLP:
+    the grouped product. Rows past ``sizes.sum()`` belong to no expert;
+    what they read is masked by the caller."""
+    dot = functools.partial(jax.lax.ragged_dot, group_sizes=sizes, preferred_element_type=jnp.float32)
+    gate, up = dot(xs, wg), dot(xs, wu)
+    h = (jax.nn.silu(gate) * up).astype(xs.dtype)
+    return dot(h, wd).astype(xs.dtype)
+
+
+def _chunks(tok, weight, sizes, rows: int):
+    """How the sorted list of token-choices is worked through: ``rows``
+    at a time. Returns ``(count, slice_of)``; ``slice_of(c)`` gives
+    chunk ``c``'s token ids, routing weights (0 past the list's end) and
+    each expert's rows inside the chunk. The list is padded to whole
+    chunks (a slice that ran over its end would be moved back)."""
+    pad = -tok.shape[0] % rows
+    tok, weight = jnp.pad(tok, (0, pad)), jnp.pad(weight, (0, pad))
+    ends = jnp.cumsum(sizes)
+    starts = ends - sizes
+    count = (ends[-1] + rows - 1) // rows
+
+    def slice_of(c):
+        lo = c * rows
+        t = jax.lax.dynamic_slice_in_dim(tok, lo, rows)
+        w = jax.lax.dynamic_slice_in_dim(weight, lo, rows)
+        inside = jnp.clip(ends, lo, lo + rows) - jnp.clip(starts, lo, lo + rows)
+        valid = (lo + jnp.arange(rows)) < ends[-1]
+        return t, jnp.where(valid, w, 0.0), inside.astype(jnp.int32), valid
+
+    return count, slice_of
+
+
+def _experts_fwd_one(rows, x, tok, weight, sizes, wg, wu, wd):
+    """One lane: ``y[t] = sum over the held choices (t, e) of
+    weight * MLP_e(x[t])``, float32 [N, C], and the rows the grouped
+    product was given over the chunks that ran (what ``moe_dropped``
+    is counted from)."""
+    count, slice_of = _chunks(tok, weight, sizes, rows)
+
+    def body(c, acc):
+        y, done = acc
+        t, w, inside, valid = slice_of(c)
+        with jax.named_scope("moe.route"):
+            xs = jnp.take(x, t, axis=0)
+        with jax.named_scope("moe.experts"):
+            out = _gated_silu(xs, wg, wu, wd, inside)
+        with jax.named_scope("moe.combine"):
+            add = jnp.where(valid[:, None], out.astype(jnp.float32) * w[:, None], 0.0)
+            return y.at[t].add(add), done + jnp.sum(inside).astype(jnp.float32)
+
+    return jax.lax.fori_loop(0, count, body, (jnp.zeros(x.shape, jnp.float32), jnp.float32(0)))
+
+
+def _experts_bwd_one(rows, x, tok, weight, sizes, wg, wu, wd, dy):
+    """One lane's cotangents of ``x``, ``weight`` and the three stacks,
+    each chunk's forward recomputed (nothing of a chunk outlives it)."""
+    count, slice_of = _chunks(tok, weight, sizes, rows)
+
+    def body(c, acc):
+        dx, dweight, dwg, dwu, dwd = acc
+        t, w, inside, valid = slice_of(c)
+        with jax.named_scope("moe.route"):
+            xs = jnp.take(x, t, axis=0)
+        with jax.named_scope("moe.combine"):
+            dys = jnp.where(valid[:, None], jnp.take(dy, t, axis=0), 0.0)
+        with jax.named_scope("moe.experts"):
+            out, vjp = jax.vjp(lambda xs, a, b, c_: _gated_silu(xs, a, b, c_, inside), xs, wg, wu, wd)
+            dxs, g_wg, g_wu, g_wd = vjp((dys * w[:, None]).astype(out.dtype))
+        with jax.named_scope("moe.combine"):
+            dw = jnp.where(valid, jnp.sum(dys * out.astype(jnp.float32), axis=-1), 0.0)
+            dweight = jax.lax.dynamic_update_slice_in_dim(dweight, dw, c * rows, axis=0)
+        with jax.named_scope("moe.route"):
+            dx = dx.at[t].add(jnp.where(valid[:, None], dxs.astype(jnp.float32), 0.0))
+        return dx, dweight, dwg + g_wg, dwu + g_wu, dwd + g_wd
+
+    zeros32 = lambda a: jnp.zeros(a.shape, jnp.float32)
+    whole_chunks = jnp.zeros(-(-weight.shape[0] // rows) * rows, jnp.float32)
+    dx, dweight, dwg, dwu, dwd = jax.lax.fori_loop(
+        0, count, body, (zeros32(x), whole_chunks, zeros32(wg), zeros32(wu), zeros32(wd)))
+    return (dx.astype(x.dtype), dweight[:weight.shape[0]], dwg.astype(wg.dtype), dwu.astype(wu.dtype),
+            dwd.astype(wd.dtype))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def held_experts(rows, x, tok, weight, sizes, wg, wu, wd):
+    """``x`` [N, C] tokens; ``tok`` / ``weight`` [M] the token id and
+    routing weight of each choice that landed on a held expert, sorted
+    by expert, ``sizes`` [held] the rows of each; ``wg`` / ``wu``
+    [held, C, I], ``wd`` [held, I, C]; ``rows`` the chunk the list is
+    worked through in. Returns the held experts' part
+    of the layer's output, float32 [N, C], and the number of rows the
+    grouped product ran on (float32; no gradient). A ``custom_vjp`` because the
+    chunk loop's length is a value (reverse mode cannot unroll it): the
+    backward walks the same chunks, each chunk's forward recomputed.
+    Under ``vmap`` the lanes run one after another, forward and
+    backward (the chip's ragged product has no batch dimension, and one
+    lane's rows at a time is all the chip has room for)."""
+    return sequential_vmap(functools.partial(_experts_fwd_one, rows))(x, tok, weight, sizes, wg, wu, wd)
+
+
+def _held_experts_fwd(rows, x, tok, weight, sizes, wg, wu, wd):
+    return held_experts(rows, x, tok, weight, sizes, wg, wu, wd), (x, tok, weight, sizes, wg, wu, wd)
+
+
+def _held_experts_bwd(rows, res, cotangents):
+    dy, _ = cotangents  # the row count carries no gradient
+    dx, dweight, dwg, dwu, dwd = sequential_vmap(functools.partial(_experts_bwd_one, rows))(*res, dy)
+    return dx, None, dweight, None, dwg, dwu, dwd
+
+
+held_experts.defvjp(_held_experts_fwd, _held_experts_bwd)
+
+
+class HeldExperts(nn.Module):
+    """Routed gated-SiLU experts, [B, T, C] -> [B, T, C]: the router's
+    full width, this chip's share of the stacks (module docstring)."""
+
+    num_experts: int
+    experts_per_token: int
+    expert_dim: int
+    experts_held: Tuple[int, int]  # (first, count)
+    norm_topk_prob: bool = True
+
+    @nn.compact
+    def __call__(self, x):
+        B, T, C = x.shape
+        N, E, K = B * T, self.num_experts, self.experts_per_token
+        first, held = self.experts_held
+        if not (0 <= first and held > 0 and first + held <= E):
+            raise ValueError(f"experts_held {self.experts_held} is no share of {E} experts")
+        init = nn.initializers.lecun_normal(batch_axis=(0,))
+        wg = self.param("gate_proj", init, (held, C, self.expert_dim))
+        wu = self.param("up_proj", init, (held, C, self.expert_dim))
+        wd = self.param("down_proj", init, (held, self.expert_dim, C))
+        xf = x.reshape(N, C)
+        with jax.named_scope("moe.route"):
+            # float32 whatever the compute type (the router's parameters
+            # are cast back up; the scores decide a discrete choice)
+            logits = nn.Dense(E, use_bias=False, name="router", dtype=jnp.float32)(
+                xf.astype(jnp.float32))
+            probs = jax.nn.softmax(logits, axis=-1)
+            weight, expert = jax.lax.top_k(probs, K)  # [N, K]
+            if self.norm_topk_prob:
+                weight = weight / jnp.sum(weight, axis=-1, keepdims=True)
+            here = (expert >= first) & (expert < first + held)
+            # absent experts sort last, as one group past the held ones
+            local = jnp.where(here, expert - first, held).reshape(N * K)
+            order = jnp.argsort(local, stable=True)
+            # the most a token can place here is min(K, held) choices
+            room = N * min(K, held)
+            order = order[:room]
+            tok = (order // K).astype(jnp.int32)
+            sorted_weight = jnp.take(weight.reshape(N * K), order)
+            sizes = jnp.bincount(local, length=held + 1)[:held].astype(jnp.int32)
+            hits = jnp.sum(here)
+        # a chunk is an even load's rows and a quarter: a share a little
+        # fuller than even still takes one chunk, so a step's time does
+        # not jump with the seed's routing (at a chunk of exactly the
+        # even load, layers a per cent over it ran a second, near-empty
+        # chunk: a call of 10 rounds read 62.5 to 63.9 s by seed on the v5e)
+        rows = min(room, -(-5 * N * K * held // (4 * E)))
+        y, done = held_experts(rows, xf, tok, sorted_weight, sizes, wg, wu, wd)
+        f32 = lambda v: jnp.asarray(v, jnp.float32)
+        for name, value in (
+            ("moe_local_hits", hits), ("moe_expert_tokens_max", jnp.max(sizes)),
+            ("moe_expert_tokens_mean", f32(hits) / held),
+            # choices on held experts less the rows the chunk loop gave
+            # the grouped product: 0 while the loop walks the whole list
+            ("moe_dropped", f32(hits) - jax.lax.stop_gradient(done)),
+        ):
+            self.sow("counters", name, f32(value), reduce_fn=jnp.add, init_fn=lambda: f32(0))
+        return y.astype(x.dtype).reshape(B, T, C)
+
+
+# -- block and model ------------------------------------------------------
+
+class DecoderBlock(nn.Module):
+    num_heads: int
+    num_kv_heads: int
+    head_dim: int
+    window: Optional[int]
+    attention: str
+    eps: float
+    num_experts: int
+    experts_per_token: int
+    expert_dim: int
+    experts_held: Tuple[int, int]
+    norm_topk_prob: bool
+
+    @nn.compact
+    def __call__(self, x, cos, sin):
+        with jax.named_scope("blk.attn.window" if self.window is not None else "blk.attn.full"):
+            x = x + Attention(
+                self.num_heads, self.num_kv_heads, self.head_dim, self.window,
+                self.attention, self.eps, name="attn",
+            )(RMSNorm(self.eps, name="attn_norm")(x), cos, sin)
+        return x + HeldExperts(
+            self.num_experts, self.experts_per_token, self.expert_dim,
+            self.experts_held, self.norm_topk_prob, name="moe",
+        )(RMSNorm(self.eps, name="ffn_norm")(x))
+
+
+class MoEDecoderLM(nn.Module):
+    """Causal LM over ``vocab_size`` rows (a slice of a larger
+    vocabulary is a smaller vocabulary: ids, logits and loss are over
+    it): tokens [B, T] -> float32 logits [B, T, vocab_size]."""
+
+    vocab_size: int
+    hidden_size: int
+    layer_types: Sequence[str]
+    num_heads: int
+    num_kv_heads: int
+    head_dim: int
+    sliding_window: int
+    rope_parameters: Any  # {layer type: {"rope_type", "rope_theta", ...}}
+    num_experts: int
+    experts_per_token: int
+    expert_dim: int
+    experts_held: Tuple[int, int]
+    norm_topk_prob: bool = True
+    rms_norm_eps: float = 1e-6
+    attention: str = "full"
+    remat: bool = False
+
+    @nn.compact
+    def __call__(self, tokens, train: bool = False):
+        T = tokens.shape[1]
+        with jax.named_scope("lm.embed"):
+            x = nn.Embed(self.vocab_size, self.hidden_size, name="embed")(tokens.astype(jnp.int32))
+        # one table per layer type, made once
+        tables = {
+            kind: rope_tables(T, self.head_dim, dict(self.rope_parameters[kind]))
+            for kind in dict.fromkeys(self.layer_types)
+        }
+        block = nn.remat(DecoderBlock) if self.remat else DecoderBlock
+        for i, kind in enumerate(self.layer_types):
+            if kind not in (SLIDING, FULL):
+                raise ValueError(f"layer type {kind!r}: {SLIDING!r} or {FULL!r}")
+            x = block(
+                self.num_heads, self.num_kv_heads, self.head_dim,
+                self.sliding_window if kind == SLIDING else None, self.attention,
+                self.rms_norm_eps, self.num_experts, self.experts_per_token, self.expert_dim,
+                tuple(self.experts_held), self.norm_topk_prob, name=f"layer_{i}",
+            )(x, *tables[kind])
+        with jax.named_scope("lm.head_loss"):
+            x = RMSNorm(self.rms_norm_eps, name="final_norm")(x)
+            return _proj(self.vocab_size, "lm_head")(x).astype(jnp.float32)
+
+
+def rope_parameters_from_args(args):
+    """``args.rope_parameters`` ({layer type: {...}}, as a published
+    ``config.json`` has it) or, without one, the default table at a
+    base of 10,000 for both layer types."""
+    given = getattr(args, "rope_parameters", None) or {
+        kind: {"rope_type": "default", "rope_theta": 10000.0} for kind in (SLIDING, FULL)}
+    return freeze({k: dict(v) for k, v in dict(given).items()})

@@ -54,6 +54,21 @@ class FedModel:
     def apply(self, params: Params, x: jax.Array) -> jax.Array:
         return self.module.apply({"params": params}, x)
 
+    def apply_counted(self, params: Params, x: jax.Array):
+        """``(logits, counters)``: what the module sowed into its
+        ``counters`` collection during this call, as float32 scalars
+        summed over its layers (an expert layer's token counts); ``{}``
+        from a module that counts nothing, whose lowered program is
+        then ``apply``'s. The round engine trains through this one; the
+        local trainer sums the counters over a round's steps beside the
+        loss."""
+        logits, state = self.module.apply({"params": params}, x, mutable=["counters"])
+        flat = {}
+        for path, value in jax.tree_util.tree_leaves_with_path(state.get("counters", {})):
+            name = path[-1].key
+            flat[name] = flat.get(name, 0.0) + value
+        return logits, flat
+
     @property
     def loss_fn(self) -> Callable:
         return LOSSES[self.task]

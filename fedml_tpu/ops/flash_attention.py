@@ -10,10 +10,26 @@ sequence length (``max_seq_len``).
 
 Backward: ``jax.custom_vjp`` with the standard flash residuals
 (output + per-row logsumexp) and a BLOCKWISE recompute — a ``lax.scan``
-over k-blocks that rebuilds one [T, bk] score panel at a time, so the
-backward peak is O(T·bk) like the forward, never the dense [T, T]
-matrix. Pair with ``parallel.sequence.ring_attention`` across chips:
+over panels of 128 keys (the lane tile, whatever blocks the forward
+tiles with: ``block_q`` / ``block_k`` are the forward kernel's alone)
+that rebuilds one [T, 128] score panel at a time, so the backward peak
+is O(T·128) like the forward, never the dense [T, T] matrix. Its
+products take their operands — the recomputed probabilities and score
+gradients among them — in the input dtype and accumulate in float32,
+as the forward kernel's do: float32 inputs are worked in float32
+throughout; bfloat16 inputs cost the gradients about one bfloat16
+rounding more than products on operands cast up to float32 would
+(relative error 2.7e-3 against 1.4e-3 on a 12 x 64 head, 1,024 token
+causal block, ``tests/test_moe_decoder.py``). Pair with ``parallel.sequence.ring_attention`` across chips:
 ring for the sequence axis, this kernel for the per-chip block.
+
+Grouped KV (``k``/``v`` with fewer heads than ``q``): a KV head is read
+by its ``H // KV`` query heads through the block index map, never
+repeated in HBM; the backward sums each KV head's gradient over its
+group. ``window=w`` (causal only) keeps the keys in ``[q - w + 1, q]``:
+the forward kernel's k-loop starts at the first block that holds one,
+and the backward's scan slices out the ``w + 128`` query rows a panel
+of keys can reach, so neither pays for the blocks outside the band.
 
 Platforms: compiled by Mosaic on ``tpu``; on ``cpu`` the SAME kernel
 body runs in the Pallas interpreter (what the tests exercise). The
@@ -90,7 +106,7 @@ def _check_shape(T: int, D: int, dtype, block_q: int, block_k: int) -> None:
         )
 
 
-def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale, causal, bq, bk, seq_len):
+def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale, causal, window, bq, bk, seq_len):
     qi = pl.program_id(1)
     q = q_ref[0]  # [bq, D], input dtype (bf16 feeds the MXU natively)
     d = q.shape[-1]
@@ -114,7 +130,10 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale, causal, bq, bk,
         if causal:
             q_pos = qi * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
             k_pos = j * bk + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
-            s = jnp.where(q_pos >= k_pos, s, _NEG_INF)
+            keep = q_pos >= k_pos
+            if window is not None:
+                keep = keep & (q_pos - k_pos < window)
+            s = jnp.where(keep, s, _NEG_INF)
         m_new = jnp.maximum(m, s.max(axis=-1, keepdims=True))
         p = jnp.exp(s - m_new)
         corr = jnp.exp(m - m_new)
@@ -129,7 +148,9 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale, causal, bq, bk,
     l0 = jnp.zeros((bq, 1), jnp.float32)
     acc0 = jnp.zeros((bq, d), jnp.float32)
     upper = n_kb if not causal else jnp.minimum(((qi + 1) * bq + bk - 1) // bk, n_kb)
-    m, l, acc = jax.lax.fori_loop(0, upper, body, (m0, l0, acc0))
+    # the first k-block that holds a key of this q-block's band
+    lower = 0 if window is None else jnp.maximum(qi * bq - (window - 1), 0) // bk
+    m, l, acc = jax.lax.fori_loop(lower, upper, body, (m0, l0, acc0))
     l = jnp.maximum(l, 1e-30)
     o_ref[0] = (acc / l).astype(o_ref.dtype)
     # per-row logsumexp: the backward residual (flash convention),
@@ -137,10 +158,11 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale, causal, bq, bk,
     lse_ref[0] = jnp.transpose(m + jnp.log(l))
 
 
-def _flash_call(qf, kf, vf, *, scale, causal, bq, bk, interpret):
+def _flash_call(qf, kf, vf, *, scale, causal, window, bq, bk, interpret):
     BH, T, D = qf.shape
+    group = BH // kf.shape[0]  # query heads per KV head (1: plain MHA)
     kernel = functools.partial(
-        _flash_kernel, scale=scale, causal=causal, bq=bq, bk=bk, seq_len=T
+        _flash_kernel, scale=scale, causal=causal, window=window, bq=bq, bk=bk, seq_len=T
     )
     need = _kv_resident_bytes(T, D, qf.dtype.itemsize) + _VMEM_WORKSPACE_BYTES
     return pl.pallas_call(
@@ -148,8 +170,10 @@ def _flash_call(qf, kf, vf, *, scale, causal, bq, bk, interpret):
         grid=(BH, T // bq),
         in_specs=[
             pl.BlockSpec((1, bq, D), lambda i, j: (i, j, 0)),
-            pl.BlockSpec((1, T, D), lambda i, j: (i, 0, 0)),
-            pl.BlockSpec((1, T, D), lambda i, j: (i, 0, 0)),
+            # row b*H + h of q reads row b*KV + h // group of k and v;
+            # consecutive steps on one KV head re-fetch nothing
+            pl.BlockSpec((1, T, D), lambda i, j: (i // group, 0, 0)),
+            pl.BlockSpec((1, T, D), lambda i, j: (i // group, 0, 0)),
         ],
         out_specs=[
             pl.BlockSpec((1, bq, D), lambda i, j: (i, j, 0)),
@@ -164,20 +188,44 @@ def _flash_call(qf, kf, vf, *, scale, causal, bq, bk, interpret):
             vmem_limit_bytes=need,
         ),
         interpret=interpret,
-        name="flash_attention_fwd",
+        # the device trace's readers tell the two kinds of layer apart
+        # by this name (benchmark/layer_metrics/flash_*_fwd_roofline.py)
+        name="flash_attention_fwd" if window is None else "flash_attention_window_fwd",
     )(qf, kf, vf)
 
 
-def _flash_forward(q, k, v, causal, scale, block_q, block_k):
+def _check_heads(q, k, v, causal, window) -> int:
+    """Query heads per KV head; the rules every platform shares."""
+    H, KV = q.shape[2], k.shape[2]
+    if k.shape != v.shape or k.shape[:2] != q.shape[:2] or k.shape[3] != q.shape[3]:
+        raise ValueError(
+            f"flash_attention: q {q.shape}, k {k.shape}, v {v.shape} — k and v "
+            "must match and share q's batch, length and head_dim"
+        )
+    if KV <= 0 or H % KV:
+        raise ValueError(
+            f"flash_attention: {H} query heads cannot share {KV} KV heads "
+            "(grouped KV needs a whole number of query heads per KV head)"
+        )
+    if window is not None and (not causal or window <= 0):
+        raise ValueError(
+            f"flash_attention: window={window} needs causal=True and a "
+            "positive width (the band is [q - window + 1, q])"
+        )
+    return H // KV
+
+
+def _flash_forward(q, k, v, causal, scale, block_q, block_k, window):
     B, T, H, D = q.shape
+    _check_heads(q, k, v, causal, window)
     _check_shape(T, D, q.dtype, block_q, block_k)
     scale = scale or (D**-0.5)
 
     def reshaped(x):
-        return x.transpose(0, 2, 1, 3).reshape(B * H, T, D)
+        return x.transpose(0, 2, 1, 3).reshape(B * x.shape[2], T, D)
 
     call = functools.partial(
-        _flash_call, scale=scale, causal=causal, bq=block_q, bk=block_k
+        _flash_call, scale=scale, causal=causal, window=window, bq=block_q, bk=block_k
     )
     # compiled on the chip, interpreted on the CPU (tests); no default
     # branch, so lowering for any other platform raises
@@ -192,7 +240,7 @@ def _flash_forward(q, k, v, causal, scale, block_q, block_k):
     )
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
 def flash_attention(
     q: jax.Array,
     k: jax.Array,
@@ -201,54 +249,96 @@ def flash_attention(
     scale: Optional[float] = None,
     block_q: int = 128,
     block_k: int = 128,
+    window: Optional[int] = None,
 ):
-    """Flash attention, [B, T, H, D] layout. Differentiable. ``T`` must
-    be a multiple of the (128-multiple) block sizes and at most
-    ``max_seq_len(D, dtype)``; anything else raises ``ValueError``."""
-    out, _ = _flash_forward(q, k, v, causal, scale, block_q, block_k)
+    """Flash attention, ``q`` [B, T, H, D], ``k``/``v`` [B, T, KV, D] with
+    ``H`` a multiple of ``KV``. Differentiable. ``T`` must be a multiple
+    of the (128-multiple) block sizes, which tile the forward kernel
+    only, and at most ``max_seq_len(D, dtype)``; ``window`` needs
+    ``causal``; anything else raises ``ValueError``."""
+    out, _ = _flash_forward(q, k, v, causal, scale, block_q, block_k, window)
     return out
 
 
-def _fwd(q, k, v, causal, scale, block_q, block_k):
-    out, lse = _flash_forward(q, k, v, causal, scale, block_q, block_k)
+def _fwd(q, k, v, causal, scale, block_q, block_k, window):
+    out, lse = _flash_forward(q, k, v, causal, scale, block_q, block_k, window)
     return out, (q, k, v, out, lse)
 
 
-def _bwd(causal, scale, block_q, block_k, res, g):
+# the backward's k-panel: [B, H, rows, 128] float32 score panels, whatever
+# block the forward kernel tiles with
+_BWD_BLOCK_K = _LANES
+
+
+def _bwd(causal, scale, _block_q, _block_k, window, res, g):
     """Blockwise backward (FlashAttention-2 recompute): scan over
-    k-blocks rebuilding [T, bk] score panels from the saved logsumexp —
-    peak memory O(B·H·T·bk), never the dense [T, T] matrix."""
+    panels of ``bk`` = 128 keys rebuilding [rows, bk] score panels from
+    the saved logsumexp — peak memory O(B·H·rows·bk), never the dense
+    [T, T] matrix. The forward's block sizes play no part. ``rows`` is
+    T, or with a window the ``window + bk`` query rows (rounded up to a
+    panel) a panel of keys can reach. Operands (``p`` and ``ds`` too)
+    stay in the input dtype and every product accumulates in float32,
+    as the forward kernel's do (module docstring). Everything is laid out head-major
+    ([B, KV, G, T, D]: the forward kernel's own order) once, outside the
+    scan, so that each step's five products are plain batched matrix
+    products and the dq accumulator is updated a contiguous [rows, D]
+    block a head (with T minor the v5e compiler copied the whole
+    accumulator every step; PERF.md, PR 28)."""
     q, k, v, o, lse = res
     B, T, H, Dh = q.shape
+    KV = k.shape[2]
+    G = H // KV
     sc = scale or (Dh**-0.5)
-    bk = block_k
-    f32 = lambda x: x.astype(jnp.float32)
-    qf, kf, vf, of, gf = f32(q), f32(k), f32(v), f32(o), f32(g)
-    # D_i = do_i · o_i  [B,H,T]
-    d_sum = (gf * of).sum(-1).transpose(0, 2, 1)
-    q_pos = jnp.arange(T)
+    bk = _BWD_BLOCK_K
+    dt = q.dtype
+    f32 = jnp.float32
+    dot = functools.partial(jnp.einsum, preferred_element_type=f32)
+    heads = lambda x: x.reshape(B, T, KV, G, -1).transpose(0, 2, 3, 1, 4)  # [B,KV,G,T,D]
+    qh, gh = heads(q), heads(g)
+    kh, vh = k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3)  # [B,KV,T,D]
+    # D_i = do_i · o_i and the saved logsumexp, [B,KV,G,T,1]
+    d_sum = heads((g.astype(f32) * o.astype(f32)).sum(-1))
+    lse_h = lse.reshape(B, KV, G, T, 1)
+    # the query rows one k-block can reach: all of them, or the band
+    rows = T if window is None else min(T, -(-(window + bk - 1) // bk) * bk)
 
     def body(dq_acc, j):
-        ks = jax.lax.dynamic_slice_in_dim(kf, j * bk, bk, axis=1)  # [B,bk,H,D]
-        vs = jax.lax.dynamic_slice_in_dim(vf, j * bk, bk, axis=1)
-        s = jnp.einsum("bqhd,bkhd->bhqk", qf, ks) * sc  # [B,H,T,bk]
+        k0 = j * bk
+        ks = jax.lax.dynamic_slice_in_dim(kh, k0, bk, axis=2)  # [B,KV,bk,D]
+        vs = jax.lax.dynamic_slice_in_dim(vh, k0, bk, axis=2)
+        # keys [k0, k0+bk) are seen by queries [k0, k0+bk+window-1)
+        q0 = jnp.minimum(k0, T - rows)
+        cut = lambda x: jax.lax.dynamic_slice_in_dim(x, q0, rows, axis=3)
+        qs, gs = cut(qh), cut(gh)
+        s = dot("bhgqd,bhkd->bhgqk", qs, ks) * sc  # [B,KV,G,rows,bk]
         if causal:
-            k_pos = j * bk + jnp.arange(bk)
-            s = jnp.where((q_pos[:, None] >= k_pos[None, :])[None, None], s, _NEG_INF)
-        p = jnp.exp(s - lse[..., None])  # [B,H,T,bk]
-        dp = jnp.einsum("bqhd,bkhd->bhqk", gf, vs)
-        ds = p * (dp - d_sum[..., None]) * sc
-        dq_acc = dq_acc + jnp.einsum("bhqk,bkhd->bqhd", ds, ks)
-        dk_j = jnp.einsum("bhqk,bqhd->bkhd", ds, qf)
-        dv_j = jnp.einsum("bhqk,bqhd->bkhd", p, gf)
+            q_pos = q0 + jnp.arange(rows)[:, None]
+            k_pos = k0 + jnp.arange(bk)[None, :]
+            keep = q_pos >= k_pos
+            if window is not None:
+                keep = keep & (q_pos - k_pos < window)
+            s = jnp.where(keep, s, _NEG_INF)
+        p = jnp.exp(s - cut(lse_h))
+        dp = dot("bhgqd,bhkd->bhgqk", gs, vs)
+        ds = (p * (dp - cut(d_sum)) * sc).astype(dt)
+        dq_j = dot("bhgqk,bhkd->bhgqd", ds, ks)
+        dq_acc = jax.lax.dynamic_update_slice_in_dim(
+            dq_acc, jax.lax.dynamic_slice_in_dim(dq_acc, q0, rows, axis=3) + dq_j, q0, axis=3
+        )
+        dk_j = dot("bhgqk,bhgqd->bhkd", ds, qs)
+        dv_j = dot("bhgqk,bhgqd->bhkd", p.astype(dt), gs)
         return dq_acc, (dk_j, dv_j)
 
     dq, (dks, dvs) = jax.lax.scan(
-        body, jnp.zeros_like(qf), jnp.arange(T // bk)
+        body, jnp.zeros((B, KV, G, T, Dh), f32), jnp.arange(T // bk)
     )
-    # [nkb, B, bk, H, D] -> [B, T, H, D]
-    merge = lambda blocks: jnp.moveaxis(blocks, 0, 1).reshape(B, T, H, Dh)
-    return dq.astype(q.dtype), merge(dks).astype(k.dtype), merge(dvs).astype(v.dtype)
+    # [nkb, B, KV, bk, D] -> [B, T, KV, D]
+    merge = lambda blocks: blocks.transpose(1, 0, 3, 2, 4).reshape(B, T, KV, Dh)
+    return (
+        dq.transpose(0, 3, 1, 2, 4).reshape(B, T, H, Dh).astype(q.dtype),
+        merge(dks).astype(k.dtype),
+        merge(dvs).astype(v.dtype),
+    )
 
 
 flash_attention.defvjp(_fwd, _bwd)

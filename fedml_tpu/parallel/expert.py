@@ -12,22 +12,56 @@ as the replicated one (asserted in tests).
 Composes with the Megatron tp rules: apply ``tensor.tp_specs`` to the
 dense blocks and these rules to the expert stacks on a
 ``{dp, tp/ep}``-axis mesh.
+
+**A held share.** Where the chips that share a layer are not all in
+this process — the benchmark's one-chip cut of an expert-parallel
+deployment — the expert layer (``models.decoder.HeldExperts``) is told
+which experts it holds: ``experts_held(num_experts, ep, rank)``. It
+routes over all of them and computes its own experts' part; the layer
+runs without its exchange, and the parts of all ``ep`` shares add up
+to the whole layer (``tests/test_moe_decoder.py``).
 """
 
 from __future__ import annotations
 
-from typing import Any
+from typing import Any, NamedTuple
 
 import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-_EXPERT_LEAVES = {"wi", "bi", "wo", "bo"}
+# stacked expert leaves (leading dim E) by the module that owns them:
+# models.moe.SwitchFFN (auto-named SwitchFFN_<i>) and
+# models.decoder.HeldExperts (named "moe" inside each decoder layer)
+_EXPERT_LEAVES = {
+    "SwitchFFN": {"wi", "bi", "wo", "bo"},
+    "moe": {"gate_proj", "up_proj", "down_proj"},
+}
+
+
+class ExpertsHeld(NamedTuple):
+    """The experts one chip holds of a layer: ``count`` from ``first``."""
+
+    first: int
+    count: int
+
+
+def experts_held(num_experts: int, ep: int = 1, rank: int = 0) -> ExpertsHeld:
+    """The share of chip ``rank`` among the ``ep`` chips that share each
+    expert layer: a contiguous ``num_experts / ep`` of the experts."""
+    if ep <= 0 or num_experts % ep or not 0 <= rank < ep:
+        raise ValueError(
+            f"{num_experts} experts over {ep} chips, rank {rank}: the chips that share "
+            "a layer hold equal whole shares"
+        )
+    count = num_experts // ep
+    return ExpertsHeld(rank * count, count)
 
 
 def _spec_for(path, leaf, axis: str) -> P:
     names = [p.key if hasattr(p, "key") else str(p) for p in path]
-    if names[-1] in _EXPERT_LEAVES and any("SwitchFFN" in n for n in names):
-        return P(axis, *([None] * (leaf.ndim - 1)))
+    for owner, leaves in _EXPERT_LEAVES.items():
+        if names[-1] in leaves and any(n == owner or n.startswith(owner + "_") for n in names[:-1]):
+            return P(axis, *([None] * (leaf.ndim - 1)))
     return P()
 
 

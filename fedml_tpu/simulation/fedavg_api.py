@@ -42,6 +42,7 @@ from ..core.local_trainer import (
     compute_dtype_from_args,
     make_eval_fn,
     make_local_train_fn,
+    model_counters,
 )
 from ..core.optimizers import (
     create_client_optimizer,
@@ -461,7 +462,11 @@ class FedAvgAPI:
                 else 0.0
             )
             self._local_train = make_local_train_fn(
-                model.apply,
+                # what a model counts (an expert layer's token loads;
+                # most count nothing and lower as through apply) rides
+                # in the round's metric outputs, fetched with the
+                # deferred ones
+                model.apply_counted,
                 model.loss_fn,
                 create_client_optimizer(
                     args,
@@ -817,6 +822,7 @@ class FedAvgAPI:
                         stats["train_loss_cohort"] = float(summed["loss_sum"]) / max(  # lint: host-sync-ok
                             float(summed["count"]), 1.0  # lint: host-sync-ok — same eval-round fetch
                         )
+                        stats.update(model_counters(summed))
                         self.history.append(stats)
                         final_stats = stats
                         self.metrics_reporter.report_server_training_metric(stats)

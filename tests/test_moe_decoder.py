@@ -1,0 +1,511 @@
+"""The config-driven MoE decoder (``models/decoder.py``), the windowed
+grouped-KV flash kernel and the held-share expert layer, each against
+the plain reference the benchmark compares with
+(``benchmark/reference/fedavg_mellum2.py``, loaded by path: float32,
+dense masked attention, a loop over experts, no kernel, no vmap).
+
+Small sizes on the CPU: hidden 64, 4 query / 2 KV heads of 16, 8 experts
+top-2 of width 32, window 8, T 32, vocabulary 64, layers S S S F. What a
+scope or a kernel *costs* is the chip's to say.
+"""
+
+import argparse
+import importlib.util
+import math
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+import fedml_tpu
+from fedml_tpu import data, models
+from fedml_tpu.arguments import Arguments
+from fedml_tpu.models.decoder import (
+    FULL, SLIDING, HeldExperts, dense_attention, rope_inv_freq,
+)
+from fedml_tpu.ops.flash_attention import flash_attention
+from fedml_tpu.parallel.expert import ep_specs, experts_held
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+def _load(path, name):
+    spec = importlib.util.spec_from_file_location(name, os.path.join(REPO, path))
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+ref = _load("benchmark/reference/fedavg_mellum2.py", "ref_fedavg_mellum2")
+
+ROPE = {
+    FULL: {"rope_type": "yarn", "rope_theta": 500000, "factor": 16,
+           "original_max_position_embeddings": 16, "beta_fast": 32, "beta_slow": 1,
+           "attention_factor": 1.2772588722239782},
+    SLIDING: {"rope_type": "default", "rope_theta": 500000},
+}
+MODEL = {  # the reference's keys are the published config's
+    "vocab_size": 64, "hidden_size": 64, "head_dim": 16, "num_attention_heads": 4,
+    "num_key_value_heads": 2, "layer_types": [SLIDING, SLIDING, SLIDING, FULL],
+    "sliding_window": 8, "rope_parameters": ROPE, "num_experts": 8, "num_experts_per_tok": 2,
+    "moe_intermediate_size": 32, "norm_topk_prob": True, "rms_norm_eps": 1e-6,
+    "experts_held": [4, 4],
+}
+T = 32
+
+
+def _args(**over):
+    flat = dict(
+        model="moe_decoder", dataset="token_stream", vocab_size=64, seq_len=T, hidden_size=64,
+        num_heads=4, num_kv_heads=2, head_dim=16, num_experts=8, experts_per_token=2,
+        expert_dim=32, sliding_window=8, layer_types=list(MODEL["layer_types"]),
+        rope_parameters=ROPE, expert_parallel=2, expert_rank=1, attention_impl="full",
+    )
+    flat.update(over)
+    return argparse.Namespace(**flat)
+
+
+@pytest.fixture(scope="module")
+def weights():
+    return ref.init_params(7, MODEL)
+
+
+@pytest.fixture(scope="module")
+def tokens():
+    return jax.random.randint(jax.random.PRNGKey(1), (3, T + 1), 0, 64)
+
+
+def _close(a, b, tol):
+    a, b = np.asarray(a, np.float64), np.asarray(b, np.float64)
+    return np.max(np.abs(a - b)) <= tol * max(np.max(np.abs(b)), 1e-12)
+
+
+# -- the model against the reference -----------------------------------
+def test_parameter_tree_is_the_references(weights):
+    m = models.create(_args(), 64)
+    have = jax.tree.map(lambda a: (a.shape, str(a.dtype)), m.init(jax.random.PRNGKey(0)))
+    want = jax.tree.map(lambda a: (a.shape, str(a.dtype)), weights)
+    assert have == want
+
+
+@pytest.mark.parametrize("remat", [False, True])
+def test_forward_matches_reference(weights, tokens, remat):
+    m = models.create(_args(remat=remat), 64)
+    with jax.default_matmul_precision("highest"):
+        got = m.apply(weights, tokens[:, :-1])
+        want = jnp.stack([ref.forward(weights, t[:-1], MODEL) for t in tokens])
+    assert got.dtype == jnp.float32 and got.shape == (3, T, 64)
+    assert _close(got, want, 2e-5)
+
+
+@pytest.mark.parametrize("remat", [False, True])
+def test_gradients_match_reference(weights, tokens, remat):
+    m = models.create(_args(remat=remat), 64)
+    x, y = tokens[:, :-1], tokens[:, 1:]
+
+    def prog(p):
+        logp = jax.nn.log_softmax(m.apply(p, x), axis=-1)
+        return -jnp.take_along_axis(logp, y[..., None], axis=-1).sum()
+
+    def plain(p):
+        return sum(ref._sequence_loss_sum(p, a, b, MODEL, None, None) for a, b in zip(x, y))
+
+    with jax.default_matmul_precision("highest"):
+        (lp, gp), (lr, gr) = jax.value_and_grad(prog)(weights), jax.value_and_grad(plain)(weights)
+    assert abs(float(lp) - float(lr)) <= 1e-5 * abs(float(lr))
+    bad = [jax.tree_util.keystr(k) for (k, a), b in zip(
+        jax.tree_util.tree_leaves_with_path(gp), jax.tree.leaves(gr)) if not _close(a, b, 2e-4)]
+    assert not bad, bad
+
+
+@pytest.mark.parametrize("fault", ["no_window", "no_renorm", "no_yarn"])
+def test_each_planted_fault_moves_the_reference(weights, tokens, fault):
+    """What the benchmark's limits have to catch is not a no-op at this
+    size: the reference with a fault planted disagrees with itself."""
+    with jax.default_matmul_precision("highest"):
+        good = ref.forward(weights, tokens[0, :-1], MODEL)
+        bad = ref.forward(weights, tokens[0, :-1], MODEL, fault=fault)
+    assert not _close(bad, good, 1e-3)
+
+
+def test_flash_path_matches_dense_path(weights):
+    """attention_impl flash (the interpreter here) and full agree on a
+    sequence the kernel can tile; a window wider than T is every key."""
+    toks = jax.random.randint(jax.random.PRNGKey(3), (2, 128), 0, 64)
+    over = dict(seq_len=128, sliding_window=40)
+    with jax.default_matmul_precision("highest"):
+        dense = models.create(_args(**over), 64).apply(weights, toks)
+        flash = models.create(_args(attention_impl="flash", **over), 64).apply(weights, toks)
+    assert _close(flash, dense, 2e-5)
+
+
+def test_flash_refuses_a_sequence_it_cannot_tile(weights, tokens):
+    m = models.create(_args(attention_impl="flash"), 64)
+    with pytest.raises(ValueError, match="multiple"):
+        m.apply(weights, tokens[:, :-1])  # T = 32: never a quiet dense fallback
+
+
+# -- the kernel against dense masked attention -------------------------
+FLASH_CASES = [  # H, KV, window, T, block
+    (4, 4, None, 256, 128), (4, 2, None, 256, 128), (8, 2, 100, 384, 128),
+    (4, 1, 128, 256, 128), (4, 2, 300, 512, 256), (4, 2, 1, 256, 128),
+]
+
+
+def _qkv(h, kv, t, seed=0, b=2, d=16):
+    ks = jax.random.split(jax.random.PRNGKey(seed), 4)
+    return (jax.random.normal(ks[0], (b, t, h, d)), jax.random.normal(ks[1], (b, t, kv, d)),
+            jax.random.normal(ks[2], (b, t, kv, d)), jax.random.normal(ks[3], (b, t, h, d)))
+
+
+@pytest.mark.parametrize("h,kv,window,t,block", FLASH_CASES)
+def test_flash_window_gqa_forward(h, kv, window, t, block):
+    q, k, v, _ = _qkv(h, kv, t)
+    got = flash_attention(q, k, v, True, None, block, block, window)
+    assert _close(got, dense_attention(q, k, v, window), 1e-5)
+
+
+# (window 1 sees only itself: every gradient of q and k is exactly 0)
+@pytest.mark.parametrize("h,kv,window,t,block", FLASH_CASES[:-1])
+def test_flash_window_gqa_backward(h, kv, window, t, block):
+    q, k, v, w = _qkv(h, kv, t, seed=1)
+    f = lambda q, k, v: (flash_attention(q, k, v, True, None, block, block, window) * w).sum()
+    d = lambda q, k, v: (dense_attention(q, k, v, window) * w).sum()
+    got, want = jax.grad(f, (0, 1, 2))(q, k, v), jax.grad(d, (0, 1, 2))(q, k, v)
+    assert got[1].shape == k.shape  # a KV head's gradient, summed over its group
+    assert all(_close(a, b, 2e-5) for a, b in zip(got, want))
+
+
+def test_flash_backward_bfloat16_operands_cost_one_rounding():
+    """bfloat16 inputs: the backward's products take ``p`` and ``ds`` in
+    bfloat16 (float32 accumulation), where the GPT-2 block's backward
+    before PR 28 cast every operand up to float32. Against dense float32
+    attention on the same rounded inputs, 12 heads of 64 (the GPT-2
+    block), the gradients' relative error stays near one bfloat16
+    rounding, 2**-8: at 1,024 tokens this backward reads 2.8e-3 / 2.7e-3
+    / 2.5e-3 (dq / dk / dv) where the float32-operand one read 1.4e-3 /
+    1.5e-3 / 3e-5 -- on the CPU, whose float32 products are exact; the
+    chip's default precision rounds float32 operands to bfloat16 too."""
+    q, k, v, w = (a.astype(jnp.bfloat16) for a in _qkv(12, 12, 512, seed=3, b=1, d=64))
+    f32 = lambda a: a.astype(jnp.float32)
+    f = lambda q, k, v: (f32(flash_attention(q, k, v, True, None, 128, 128)) * f32(w)).sum()
+    d = lambda q, k, v: (dense_attention(f32(q), f32(k), f32(v), None) * f32(w)).sum()
+    got = jax.grad(f, (0, 1, 2))(q, k, v)
+    with jax.default_matmul_precision("highest"):
+        want = jax.grad(d, (0, 1, 2))(q, k, v)
+    errors = [float(jnp.linalg.norm(f32(a) - f32(b)) / jnp.linalg.norm(f32(b))) for a, b in zip(got, want)]
+    assert all(a.dtype == jnp.bfloat16 for a in got)
+    assert all(e < 2.0 ** -8 for e in errors), errors
+
+
+def test_flash_vmapped_lanes():
+    q, k, v, _ = _qkv(4, 2, 256, seed=2, b=3)
+    lanes = lambda a: a[:, None]  # three lanes of batch 1
+    f = lambda q, k, v: (flash_attention(q, k, v, True, None, 128, 128, 100) ** 2).sum()
+    d = lambda q, k, v: (dense_attention(q, k, v, 100) ** 2).sum()
+    got = jax.vmap(jax.grad(f, (0, 1, 2)))(lanes(q), lanes(k), lanes(v))
+    want = jax.vmap(jax.grad(d, (0, 1, 2)))(lanes(q), lanes(k), lanes(v))
+    assert all(_close(a, b, 2e-5) for a, b in zip(got, want))
+
+
+@pytest.mark.parametrize("bad", ["heads", "window_noncausal", "window_zero", "kv_shape"])
+def test_flash_shape_rules(bad):
+    q, k, v, _ = _qkv(4, 2, 256)
+    with pytest.raises(ValueError):
+        if bad == "heads":
+            flash_attention(q, jnp.tile(k, (1, 1, 2, 1))[:, :, :3], jnp.tile(v, (1, 1, 2, 1))[:, :, :3])
+        elif bad == "window_noncausal":
+            flash_attention(q, k, v, False, None, 128, 128, 64)
+        elif bad == "window_zero":
+            flash_attention(q, k, v, True, None, 128, 128, 0)
+        else:
+            flash_attention(q, k[:, :128], v[:, :128])
+
+
+# -- rotary tables -----------------------------------------------------
+def test_default_rope_closed_form():
+    inv_freq, scale = rope_inv_freq(128, {"rope_type": "default", "rope_theta": 500000})
+    want = [500000.0 ** (-2 * i / 128) for i in range(64)]
+    assert scale == 1.0 and np.allclose(inv_freq, want, rtol=1e-6)
+
+
+def test_yarn_closed_form():
+    """The published full-attention parameters: the dimensions that turn
+    32 times and once over 8,192 positions are 18.08 and 34.99, so the
+    ramp runs from pair 18 to pair 35; below it the frequencies stay,
+    above it they are divided by 16."""
+    rope = {"rope_type": "yarn", "rope_theta": 500000, "factor": 16,
+            "original_max_position_embeddings": 8192, "beta_fast": 32, "beta_slow": 1,
+            "attention_factor": 1.2772588722239782}
+    inv_freq, scale = rope_inv_freq(128, rope)
+    base = np.array([500000.0 ** (-2 * i / 128) for i in range(64)])
+    dim = lambda turns: 128 * math.log(8192 / (turns * 2 * math.pi)) / (2 * math.log(500000))
+    assert (math.floor(dim(32)), math.ceil(dim(1))) == (18, 35)
+    assert np.allclose(inv_freq[:19], base[:19], rtol=1e-6)
+    assert np.allclose(inv_freq[35:], base[35:] / 16, rtol=1e-6)
+    mid = 25
+    ramp = (mid - 18) / (35 - 18)
+    assert np.isclose(inv_freq[mid], base[mid] / 16 * ramp + base[mid] * (1 - ramp), rtol=1e-6)
+    assert scale == pytest.approx(1.2772588722239782)
+    # unset, the factor is 0.1 ln(16) + 1: the published number
+    del rope["attention_factor"]
+    assert rope_inv_freq(128, rope)[1] == pytest.approx(0.1 * math.log(16) + 1)
+    assert np.allclose(ref.rope_inv_freq(128, rope)[0], inv_freq, rtol=1e-6)
+
+
+def test_unknown_rope_type_is_refused():
+    with pytest.raises(ValueError, match="rope_type"):
+        rope_inv_freq(16, {"rope_type": "linear", "rope_theta": 1e4})
+
+
+# -- the held share ----------------------------------------------------
+def _layer(first, held, **kw):
+    return HeldExperts(num_experts=8, experts_per_token=2, expert_dim=32,
+                       experts_held=(first, held), **kw)
+
+
+def _uncut(weights):
+    """Layer 0's expert layer with all 8 experts: router from the
+    fixture, stacks drawn here."""
+    ks = jax.random.split(jax.random.PRNGKey(11), 3)
+    return {
+        "router": weights["layer_0"]["moe"]["router"],
+        "gate_proj": jax.random.normal(ks[0], (8, 64, 32)) / 8,
+        "up_proj": jax.random.normal(ks[1], (8, 64, 32)) / 8,
+        "down_proj": jax.random.normal(ks[2], (8, 32, 64)) / 6,
+    }
+
+
+def _share(p, first, held):
+    cut = lambda a: a[first:first + held]
+    return {"router": p["router"], "gate_proj": cut(p["gate_proj"]),
+            "up_proj": cut(p["up_proj"]), "down_proj": cut(p["down_proj"])}
+
+
+@pytest.mark.parametrize("ep", [1, 2, 4, 8])
+def test_the_shares_add_up(weights, ep):
+    """The partial outputs of all ``ep`` shares sum to the uncut
+    reference layer: nothing is computed twice, nothing is left out."""
+    p = _uncut(weights)
+    x = jax.random.normal(jax.random.PRNGKey(5), (2, T, 64))
+    whole = dict(MODEL, experts_held=[0, 8])
+    with jax.default_matmul_precision("highest"):
+        want = jnp.stack([ref._experts(s, p, whole, None, None) for s in x])
+        got = sum(
+            _layer(*experts_held(8, ep, r)).apply({"params": _share(p, *experts_held(8, ep, r))}, x)
+            for r in range(ep))
+        # and each share is the reference's share
+        one = ref._experts(x[0], _share(p, 4, 4), MODEL, None, None)
+        mine = _layer(4, 4).apply({"params": _share(p, 4, 4)}, x[:1])[0]
+    assert _close(got, want, 2e-5)
+    assert _close(mine, one, 2e-5)
+
+
+@pytest.mark.parametrize("held_first", [0, 6])
+def test_no_token_dropped_when_every_token_picks_one_expert(weights, held_first):
+    """The worst imbalance: every token's first choice is one held
+    expert. All N rows land in its group, none is dropped."""
+    p = _uncut(weights)
+    hot = held_first + 1
+    router = np.zeros((64, 8), np.float32)
+    router[:, hot] = 1.0  # x is positive below: expert `hot` wins everywhere
+    p = dict(p, router={"kernel": jnp.asarray(router)})
+    x = jnp.abs(jax.random.normal(jax.random.PRNGKey(6), (1, T, 64))) + 0.1
+    layer = _layer(held_first, 2)
+    with jax.default_matmul_precision("highest"):
+        got, state = layer.apply({"params": _share(p, held_first, 2)}, x, mutable=["counters"])
+        want = ref._experts(x[0], _share(p, held_first, 2), dict(MODEL, experts_held=[held_first, 2]),
+                            None, None)
+    c = {k: float(v) for k, v in state["counters"].items()}
+    assert c["moe_dropped"] == 0.0
+    assert c["moe_expert_tokens_max"] == T  # every token is in the hot expert's group
+    assert T <= c["moe_local_hits"] <= 2 * T
+    assert _close(got[0], want, 2e-5)
+    # more than one chunk (an even load's rows and a quarter is 20 of
+    # these 32), the last one part empty: the written-out backward too
+    share = _share(p, held_first, 2)
+    g = jax.random.normal(jax.random.PRNGKey(8), (T, 64))
+    with jax.default_matmul_precision("highest"):
+        mine = jax.grad(lambda q, x: jnp.sum(layer.apply({"params": q}, x)[0] * g), (0, 1))(share, x)
+        plain = jax.grad(lambda q, x: jnp.sum(ref._experts(
+            x[0], q, dict(MODEL, experts_held=[held_first, 2]), None, None) * g), (0, 1))(share, x)
+    assert all(_close(a, b, 2e-4) for a, b in zip(jax.tree.leaves(mine), jax.tree.leaves(plain)))
+
+
+@pytest.mark.parametrize("broken", ["one_chunk_short", "groups_too_small"])
+def test_moe_dropped_counts_what_the_chunk_loop_left_out(weights, monkeypatch, broken):
+    """``moe_dropped`` is the choices on held experts less the rows the
+    grouped product really ran on: a chunk loop that stops early, or
+    that hands the product smaller groups, reads above 0."""
+    from fedml_tpu.models import decoder
+
+    real = decoder._chunks
+
+    def chunks(tok, weight, sizes, rows):
+        count, slice_of = real(tok, weight, sizes, rows)
+        if broken == "one_chunk_short":
+            return count - 1, slice_of
+
+        def fewer(c):
+            t, w, inside, valid = slice_of(c)
+            return t, w, inside // 2, valid
+        return count, fewer
+
+    p = _share(_uncut(weights), 0, 4)
+    x = jax.random.normal(jax.random.PRNGKey(5), (2, T, 64))
+    _, state = _layer(0, 4).apply({"params": p}, x, mutable=["counters"])
+    assert float(state["counters"]["moe_dropped"]) == 0.0
+    monkeypatch.setattr(decoder, "_chunks", chunks)
+    _, state = _layer(0, 4).apply({"params": p}, x, mutable=["counters"])
+    c = {k: float(v) for k, v in state["counters"].items()}
+    assert 0 < c["moe_dropped"] <= c["moe_local_hits"]
+
+
+def test_bad_share_is_refused():
+    with pytest.raises(ValueError, match="share"):
+        experts_held(8, 3)
+    with pytest.raises(ValueError, match="share"):
+        _layer(6, 4).init(jax.random.PRNGKey(0), jnp.zeros((1, 4, 64)))
+    assert tuple(experts_held(64, 8, 1)) == (8, 8)
+
+
+def test_ep_specs_find_the_held_stacks(weights):
+    specs = ep_specs(weights)
+    moe = specs["layer_2"]["moe"]
+    assert all(moe[k][0] == "ep" for k in ("gate_proj", "up_proj", "down_proj"))
+    assert tuple(moe["router"]["kernel"]) == () and tuple(specs["lm_head"]["kernel"]) == ()
+
+
+def test_vmapped_lanes_carry_their_own_experts(weights, tokens):
+    """The round engine's shape: a cohort vmapped over lanes, every lane
+    its own weights; the grouped product runs lane after lane."""
+    m = models.create(_args(remat=True), 64)
+    x = tokens[:, :-1]
+
+    def loss(p, x):
+        logits, counters = m.apply_counted(p, x)
+        return (logits ** 2).mean(), counters
+
+    stacked = jax.tree.map(lambda a: jnp.stack([a, a * 1.01]), weights)
+    grads, counters = jax.jit(jax.vmap(jax.grad(loss, has_aux=True)))(stacked, jnp.stack([x, x]))
+    for lane in range(2):
+        g, c = jax.grad(loss, has_aux=True)(jax.tree.map(lambda a: a[lane], stacked), x)
+        assert all(_close(a[lane], b, 1e-4) for a, b in zip(jax.tree.leaves(grads), jax.tree.leaves(g)))
+        assert {k: float(v[lane]) for k, v in counters.items()} == {k: float(v) for k, v in c.items()}
+
+
+# -- data: a stated vocabulary -----------------------------------------
+def test_token_data_at_a_real_vocabulary():
+    """PERF.md section 7's open item: a dense [V, V] chain is 1.2 GB at
+    12,288; the sparse chain draws at any vocabulary, in range, with
+    y the next token of x."""
+    from fedml_tpu.data.synthetic import synthetic_sequences
+
+    x, y = synthetic_sequences(6, 512, 98304, seed=3)
+    assert x.shape == y.shape == (6, 512) and (x[:, 1:] == y[:, :-1]).all()
+    assert 0 <= x.min() and 50000 < x.max() < 98304
+    x2, _ = synthetic_sequences(6, 512, 98304, seed=3)
+    assert (x == x2).all()
+    # a chain, not noise: a token's successors are few
+    xs, ys = synthetic_sequences(64, 2048, 4096, seed=0)
+    follows = {}
+    for a, b in zip(xs.ravel(), ys.ravel()):
+        follows.setdefault(int(a), set()).add(int(b))
+    assert max(len(v) for v in follows.values()) <= 16
+
+
+def _fed_args(**over):
+    flat = dict(vars(_args(
+        client_num_in_total=4, client_num_per_round=2, comm_round=1, epochs=1, batch_size=2,
+        learning_rate=0.05, client_optimizer="sgd", federated_optimizer="FedAvg",
+        partition_method="homo", synthetic_train_size=16, synthetic_test_size=8, shuffle=False,
+        random_seed=0, frequency_of_the_test=1, backend="single_process", data_cache_dir="",
+        matmul_precision="highest", remat=True)))
+    flat.update(over)
+    return fedml_tpu.init(Arguments(argparse.Namespace(**flat), training_type="simulation"))
+
+
+def test_sliced_vocabulary_draws_scores_and_loses_over_the_slice(weights):
+    args = _fed_args()
+    ds = data.load(args)
+    x, y = np.asarray(ds.packed_train.x), np.asarray(ds.packed_train.y)
+    assert ds.class_num == 64 and x.dtype == np.int32 and x.shape == (4, 2, 2, T)
+    assert 0 <= min(x.min(), y.min()) and max(x.max(), y.max()) < 64
+    m = models.create(args, ds.class_num)
+    logits = m.apply(weights, x[0, 0])
+    assert logits.shape == (2, T, 64)  # scored over the slice
+    loss, metrics = m.loss_fn(logits, y[0, 0], jnp.ones((2,)))
+    with jax.default_matmul_precision("highest"):
+        want = sum(ref._sequence_loss_sum(weights, a, b, MODEL, None, None)
+                   for a, b in zip(x[0, 0], y[0, 0])) / (2 * T)
+    assert float(metrics["count"]) == 2 * T  # counted in tokens
+    assert abs(float(loss) - float(want)) <= 1e-4 * float(want)
+
+
+# -- one federated round through the normal path -----------------------
+@pytest.fixture(scope="module")
+def one_round(weights):
+    from fedml_tpu.simulation.fedavg_api import FedAvgAPI
+
+    args = _fed_args()
+    ds = data.load(args)
+    api = FedAvgAPI(args, None, ds, models.create(args, ds.class_num))
+    api.global_params = jax.tree.map(jnp.copy, weights)
+    api.train()
+    return args, ds, api
+
+
+def test_one_round_matches_the_references_round(weights, one_round):
+    args, ds, api = one_round
+    packed = (ds.packed_train.x, ds.packed_train.y, ds.packed_train.mask)
+    cohort = ref.sample_cohort(0, 4, 2)
+    with jax.default_matmul_precision("highest"):
+        want, loss = ref.fedavg_round(
+            weights, packed, ds.packed_num_samples, cohort, MODEL, {"lr": 0.05, "epochs": 1})
+        test_loss = ref.evaluate(
+            want, (ds.packed_test.x, ds.packed_test.y, ds.packed_test.mask), MODEL)
+    rec = api.history[-1]
+    assert abs(rec["train_loss_cohort"] - loss) <= 1e-5 * loss
+    assert abs(rec["test_loss"] - test_loss) <= 1e-5 * test_loss
+    moved = [float(jnp.linalg.norm(a - b)) for a, b in zip(
+        jax.tree.leaves(want), jax.tree.leaves(weights))]
+    gap = [float(jnp.linalg.norm(a - b)) for a, b in zip(
+        jax.tree.leaves(api.global_params), jax.tree.leaves(want))]
+    assert max(g / max(m, 1e-12) for g, m in zip(gap, moved)) < 2e-3
+
+
+def test_the_rounds_record_carries_the_counters(one_round):
+    """Fetched with the round's other metrics: token-choices on held
+    experts, the fullest expert, the mean, and nothing dropped."""
+    _, _, api = one_round
+    rec = api.history[-1]
+    # 2 clients x 2 steps x 4 layers, 2 sequences of 32 tokens a step
+    calls, n = 2 * 2 * 4, 2 * T
+    assert rec["moe_dropped"] == 0.0
+    assert 0 < rec["moe_local_hits"] <= calls * n * 2
+    assert rec["moe_expert_tokens_mean"] == pytest.approx(rec["moe_local_hits"] / 4)
+    assert rec["moe_expert_tokens_mean"] <= rec["moe_expert_tokens_max"] <= calls * n
+
+
+SCOPES = ("lm.embed", "blk.attn.window", "blk.attn.full", "moe.route", "moe.experts",
+          "moe.combine", "lm.head_loss")
+
+
+def test_scopes_name_the_round_executables_parts(one_round):
+    """Every scope the per-layer readers look for is a component of
+    some op_name in the lowered round executable and in the
+    evaluation's, inside ``fed.local_train`` where it trains."""
+    args, ds, api = one_round
+    packed = ds.packed_train
+    idx = jnp.asarray([0, 1], jnp.int32)
+    lowered = api._round_fn.lower(
+        api.global_params, api.server_state, packed, jnp.asarray(ds.packed_num_samples, jnp.float32),
+        idx, jax.random.PRNGKey(0))
+    text = lowered.as_text(debug_info=True)
+    for scope in SCOPES:
+        assert f"fed.local_train/" in text and f"/{scope}/" in text, scope
+    ev = api._eval_all.lower(api.global_params, packed).as_text(debug_info=True)
+    for scope in SCOPES:
+        assert f"/{scope}/" in ev, scope
