@@ -60,6 +60,8 @@ FLASH_OUT_ATOL = 3e-2
 # gradients pass through two more bf16 roundings; judged relative to the
 # largest reference gradient entry
 FLASH_GRAD_RTOL = 5e-2
+# the backward kernels' second comparison on the chip: B, T, H, KV, D, window
+GROUPED_WINDOW_SHAPE = (2, 1024, 8, 2, 128, 256)
 
 _COMPILE_EVENT_PREFIX = "/jax/core/compile/"
 _BACKEND_COMPILE_EVENT = _COMPILE_EVENT_PREFIX + "backend_compile_duration"
@@ -233,6 +235,12 @@ def _kernel_alone(bench_shape, check_shape) -> dict:
     def loss(attn):
         return lambda q, k, v: (attn(q, k, v).astype(jnp.float32) ** 2).sum()
 
+    def grad_rel_err(got, want):  # the worst entry, relative to the reference's largest
+        return max(
+            float(jnp.abs(g.astype(jnp.float32) - w).max() / jnp.abs(w).max())
+            for g, w in zip(got, want)
+        )
+
     out = {}
     # -- bench shape: forward and backward, compiled ------------------
     q, k, v = qkv(bench_shape, jnp.bfloat16, 0)
@@ -259,10 +267,7 @@ def _kernel_alone(bench_shape, check_shape) -> dict:
     got = jax.jit(flash)(q, k, v)
     got_g = jax.jit(jax.grad(loss(flash), (0, 1, 2)))(q, k, v)
     out_err = float(jnp.abs(got.astype(jnp.float32) - want).max())
-    grad_err = max(
-        float(jnp.abs(g.astype(jnp.float32) - w).max() / jnp.abs(w).max())
-        for g, w in zip(got_g, want_g)
-    )
+    grad_err = grad_rel_err(got_g, want_g)
     out["check_shape"] = "B%d T%d H%d D%d" % check_shape
     out["out_max_abs_err"] = round(out_err, 5)
     out["out_atol"] = FLASH_OUT_ATOL
@@ -270,6 +275,29 @@ def _kernel_alone(bench_shape, check_shape) -> dict:
     out["grad_rtol"] = FLASH_GRAD_RTOL
     _check(out_err <= FLASH_OUT_ATOL, f"flash vs dense: out err {out_err}")
     _check(grad_err <= FLASH_GRAD_RTOL, f"flash vs dense: grad err {grad_err}")
+
+    # -- grouped KV under a window: the backward kernels' other shape --
+    # (D = one lane tile, 4 query heads a KV head, a band of 256)
+    from fedml_tpu.models.decoder import dense_attention
+
+    B, T, H, KV, D, window = GROUPED_WINDOW_SHAPE
+    ks = jax.random.split(jax.random.PRNGKey(3), 3)
+    q = jax.random.normal(ks[0], (B, T, H, D), jnp.bfloat16)
+    k, v = (jax.random.normal(key, (B, T, KV, D), jnp.bfloat16) for key in ks[1:])
+    banded = lambda *a: flash_attention(*a, True, None, 256, 256, window)
+    with jax.default_matmul_precision("highest"):
+        want_g = jax.jit(jax.grad(loss(lambda *a: dense_attention(*a, window)), (0, 1, 2)))(
+            *(x.astype(jnp.float32) for x in (q, k, v))
+        )
+    got_g = jax.jit(jax.grad(loss(banded), (0, 1, 2)))(q, k, v)
+    grouped_err = grad_rel_err(got_g, want_g)
+    out["grouped_window_shape"] = "B%d T%d H%d KV%d D%d window %d" % GROUPED_WINDOW_SHAPE
+    out["grouped_window_grad_max_rel_err"] = round(grouped_err, 5)
+    _check(got_g[1].shape == k.shape, f"dk {got_g[1].shape} for k {k.shape}")
+    _check(
+        grouped_err <= FLASH_GRAD_RTOL,
+        f"flash vs dense, grouped KV under a window: grad err {grouped_err}",
+    )
 
     # -- the largest T the kernel takes -------------------------------
     d = bench_shape[3]

@@ -127,6 +127,28 @@ class TestFlashCrossLowersForTpu:
         hlo = _tpu_hlo(jax.grad(loss, argnums=(0, 1, 2)), *_qkv(4, 4096, 8, 64))
         assert "tpu_custom_call" in hlo
 
+    @pytest.mark.parametrize("window", [1024, None])
+    def test_backward_at_the_lm_cells_shape(self, window):
+        """Mellum2's layer in the benchmark's cell (a lane: B 4, T 4,096,
+        32 query heads on 4 KV heads of 128): the gradient lowers to the
+        forward kernel once -- as with the scan backward before PR 29 --
+        and the two backward kernels, whose names hold no forward
+        kernel's (the trace's roofline readers find kernels by
+        substring)."""
+        q = jax.ShapeDtypeStruct((4, 4096, 32, 128), jnp.bfloat16)
+        kv = jax.ShapeDtypeStruct((4, 4096, 4, 128), jnp.bfloat16)
+
+        def loss(q, k, v):
+            return flash_attention(q, k, v, True, None, 512, 512, window).astype(jnp.float32).sum()
+
+        hlo = _tpu_hlo(jax.grad(loss, argnums=(0, 1, 2)), q, kv, kv)
+        kind = "flash_attention" if window is None else "flash_attention_window"
+        other = "flash_attention_window" if window is None else "flash_attention"
+        assert hlo.count("tpu_custom_call") == 3
+        assert hlo.count(f'"{kind}_fwd"') == 1
+        assert hlo.count(f'"{kind}_bwd_dkv"') == 1 and hlo.count(f'"{kind}_bwd_dq"') == 1
+        assert hlo.count(f"{kind}_fwd") == 1 and f"{other}_fwd" not in hlo
+
     def test_lowers_under_the_default_matmul_precision(self):
         # fedml_tpu.init() sets matmul_precision="highest" by default;
         # the bf16 kernel must not inherit an fp32 contract precision

@@ -167,15 +167,38 @@ def test_flash_window_gqa_forward(h, kv, window, t, block):
     assert _close(got, dense_attention(q, k, v, window), 1e-5)
 
 
-# (window 1 sees only itself: every gradient of q and k is exactly 0)
-@pytest.mark.parametrize("h,kv,window,t,block", FLASH_CASES[:-1])
-def test_flash_window_gqa_backward(h, kv, window, t, block):
-    q, k, v, w = _qkv(h, kv, t, seed=1)
-    f = lambda q, k, v: (flash_attention(q, k, v, True, None, block, block, window) * w).sum()
-    d = lambda q, k, v: (dense_attention(q, k, v, window) * w).sum()
-    got, want = jax.grad(f, (0, 1, 2))(q, k, v), jax.grad(d, (0, 1, 2))(q, k, v)
+# the backward's cases: the forward's (window 1 left out: it sees only
+# itself, every gradient of q and k is exactly 0) at D 16 in float32, then
+# a group of 8 on one KV head, a window that no tile divides, a window
+# wider than the sequence, D = 64 (half a lane tile) and D = 128, and
+# bfloat16 inputs -- H, KV, window, T, block, D, dtype
+BACKWARD_CASES = [c + (16, jnp.float32) for c in FLASH_CASES[:-1]] + [
+    (8, 1, None, 384, 128, 16, jnp.float32), (2, 1, 1000, 2048, 512, 16, jnp.float32),
+    (4, 2, 512, 384, 128, 16, jnp.float32), (2, 2, None, 384, 128, 64, jnp.float32),
+    (4, 2, 100, 384, 128, 128, jnp.float32), (8, 1, 100, 384, 128, 64, jnp.bfloat16),
+    (4, 2, None, 256, 256, 128, jnp.bfloat16),
+]  # (T 384 walks 3 x 3 tiles of 128, T 2,048 4 x 4 of 512, T 256 and 512 one)
+
+
+@pytest.mark.parametrize("h,kv,window,t,block,d,dtype", BACKWARD_CASES)
+def test_flash_window_gqa_backward(h, kv, window, t, block, d, dtype):
+    """Against dense attention's gradient: float32 inputs to 2e-5 of the
+    largest entry; bfloat16 inputs against dense float32 attention on the
+    same rounded inputs to one bfloat16 rounding, 2**-8, in norm."""
+    f32 = lambda a: a.astype(jnp.float32)
+    q, k, v, w = (a.astype(dtype) for a in _qkv(h, kv, t, seed=1, b=1 if t > 512 else 2, d=d))
+    f = lambda q, k, v: (f32(flash_attention(q, k, v, True, None, block, block, window)) * f32(w)).sum()
+    dense = lambda q, k, v: (dense_attention(f32(q), f32(k), f32(v), window) * f32(w)).sum()
+    got = jax.grad(f, (0, 1, 2))(q, k, v)
+    with jax.default_matmul_precision("highest"):
+        want = jax.grad(dense, (0, 1, 2))(q, k, v)
     assert got[1].shape == k.shape  # a KV head's gradient, summed over its group
-    assert all(_close(a, b, 2e-5) for a, b in zip(got, want))
+    assert all(a.dtype == dtype for a in got)
+    if dtype == jnp.float32:
+        assert all(_close(a, b, 2e-5) for a, b in zip(got, want))
+    else:
+        errors = [float(jnp.linalg.norm(f32(a) - f32(b)) / jnp.linalg.norm(f32(b))) for a, b in zip(got, want)]
+        assert all(e < 2.0 ** -8 for e in errors), errors
 
 
 def test_flash_backward_bfloat16_operands_cost_one_rounding():
