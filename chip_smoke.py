@@ -49,7 +49,7 @@ STAGE_A_CF = os.path.join(
 STAGE_B_CF = os.path.join(
     REPO, "examples", "longcontext", "flash_one_chip", "fedml_config.yaml"
 )
-# B, T, H, D of bench.py's longctx phase
+# B, T, H, D of the kernel run alone: the LM cell's T, 8 heads of 64
 KERNEL_BENCH_SHAPE = (4, 4096, 8, 64)
 # where flash (bf16) is compared with parallel.sequence.full_attention
 KERNEL_CHECK_SHAPE = (2, 1024, 4, 64)

@@ -2,7 +2,7 @@
 or serving hot path.
 
 Host-hop aggregation costs orders of magnitude more than
-device-resident aggregation (bench detail.aggregation_exchange), and
+device-resident aggregation (it crosses the host link twice), and
 PR 2's DeferredMetrics exists exactly because one stray
 ``float(device_value)`` per round serialises the pipeline. This
 checker flags, **in the hot-path modules only**, the conversions that

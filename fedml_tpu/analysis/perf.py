@@ -10,18 +10,18 @@ server). Three outputs:
 * **roofline join** — per measured executable series: achieved
   FLOP/s = audit FLOPs x dispatch count / measured seconds,
   ``mfu_vs_bf16_peak`` against the per-device-kind peak table in
-  ``constants.py`` (THE shared denominator — bench and the watch loop
-  use the same one) and a compute- vs memory-bound verdict from
+  ``constants.py`` (the package's one peak table; unknown kinds
+  raise) and a compute- vs memory-bound verdict from
   arithmetic intensity vs the device's ridge point. The audit lowers
   small abstract shapes, so the joined MFU *attributes* time across
-  executables consistently; absolute MFU claims come from bench's
-  run-shaped captures.
+  executables consistently; absolute MFU comes from the benchmark
+  (``mfu_pct``, operations counted from shapes).
 * **idle-time ledger** — per round, the measured segments plus the
   ``round_idle_seconds{gap=...}`` gaps; segments + intra-round idle
   reconcile to ``round_wall_seconds`` (the CLI reports the
   reconciliation fraction; tests gate it at 5%). The PiPar overlap
   opportunity (ROADMAP item 1), measured for free every round.
-* **bench ratchet** — ``--ratchet BENCH_*.json`` groups records by
+* **record ratchet** — ``--ratchet <record>.json ...`` groups records by
   (phase, device_kind, smoke) via their mandatory meta blocks and
   fails loudly when the newest record regresses beyond ``--tolerance``
   against the best prior record of the SAME group — CPU smoke never
@@ -329,7 +329,7 @@ def join_roofline(
     }
 
 
-# -- bench-trajectory ratchet ------------------------------------------
+# -- record-trajectory ratchet -----------------------------------------
 
 _ROUND_RE = re.compile(r"r(\d+)")
 
@@ -418,8 +418,8 @@ def run_ratchet(
             continue
         if not metas:
             violations.append(
-                f"{path}: no meta block on any phase record (bench.py "
-                "stamps one on every record it writes)"
+                f"{path}: no meta block on any phase record (the "
+                "ratchet groups records by it)"
             )
             continue
         for meta in metas:

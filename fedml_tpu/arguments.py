@@ -240,7 +240,7 @@ _DEFAULTS: Dict[str, Any] = {
     # back the registry columns with .npy memmaps under this directory
     # instead of host RAM (None = in-RAM numpy)
     "registry_dir": None,
-    # A/B bit-identity harness (detail.planet bench): partition terms
+    # A/B bit-identity harness (tests/test_planet_scale.py): partition terms
     # per edge exactly as the tree would, but fold them into ONE flat
     # accumulator — the baseline the tree identity is asserted against
     "edge_flat_fold": False,

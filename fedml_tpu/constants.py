@@ -221,11 +221,11 @@ DEVICE_WINDOW_REPORT = "report"
 DEVICE_CLOSE_TARGET = "target"
 DEVICE_CLOSE_WINDOW = "window"
 
-# -- performance-attribution plane (analysis/perf.py, bench.py) -------
+# -- performance-attribution plane (analysis/perf.py) -----------------
 # Per-chip peaks by device kind: bf16 matmul TFLOP/s and HBM TB/s. THE
-# one table every MFU denominator and roofline ridge comes from (bench
-# detail.mfu_vs_bf16_peak, `fedml-tpu perf`'s roofline join), so no two
-# tools can disagree about a device's peak. A kind that is not here is
+# one table the package's roofline ridge comes from (`fedml-tpu perf`'s
+# roofline join); the benchmark keeps its own in benchmark/peaks.json and
+# tests/test_benchmark_seam.py holds the two v5e rows equal. A kind that is not here is
 # an error (peak_bf16_flops / hbm_bandwidth_bytes raise) — never a
 # silent 0. Keys are ``jax.devices()[0].device_kind`` strings; the chip
 # this repo is checked on reports "TPU v5 lite" (chip_smoke.py, PR 21).
@@ -253,7 +253,7 @@ HBM_BANDWIDTH_TBPS = {
 
 
 def normalize_device_kind(kind: str) -> str:
-    """Canonical device-kind label for bench meta / ratchet grouping:
+    """Canonical device-kind label for the ratchet's grouping:
     strips per-chip ordinals jax appends (``"TPU v5 lite0"`` ->
     ``"TPU v5 lite"``) and folds every CPU spelling (``TFRT_CPU_0``,
     ``cpu``, ``Cpu0``) to ``"cpu"`` so smoke records always group

@@ -162,7 +162,7 @@ def _stacked_norms(stacked: Params) -> jax.Array:
 # moment it lands, in O(model) memory, and is ORDER-INDEPENDENT at the
 # bit level: two worlds whose uploads arrive in different thread orders
 # finalize to identical float32 params. That property is what lets the
-# straggler bench assert sync-streaming == buffered baseline
+# tests (test_robustness.py) assert sync-streaming == buffered
 # bit-for-bit even though arrival order is nondeterministic.
 #
 # Order independence comes from an error-free transformation split
@@ -249,8 +249,8 @@ def exact_weighted_mean(stacked: Params, weights: jax.Array) -> Params:
 
     Every step is either elementwise or a fixed-order sequential fold,
     so a (data, fsdp)-sharded cohort finalizes to EXACTLY the bits of
-    the unsharded vmap run — the ``detail.multichip`` bench's
-    ``max_abs_diff == 0.0`` gate. Runs inside the donated round jit.
+    the unsharded vmap run — tests/test_mesh_simulator.py's
+    bitwise gate over every mesh shape. Runs inside the donated round jit.
     """
     w32 = weights.astype(jnp.float32)
 
@@ -596,7 +596,7 @@ class StreamingAccumulator:
         the union's sum to the usual ~2^-48 lowest-limb error and the
         float32 finalize stays bitwise independent of how uploads were
         partitioned across accumulators (tree == flat, asserted in
-        tests and the ``detail.planet`` bench). ``total_w``/``count``
+        tests/test_planet_scale.py). ``total_w``/``count``
         add exactly (python floats over integer sample counts); an
         empty other (count 0) is a no-op fold of zero limbs."""
         self.fold_limbs(other._limbs, other.total_w, count=other.count)
@@ -647,7 +647,7 @@ class StreamingAccumulator:
 def staleness_weight(sample_num: float, staleness: int, decay: float) -> float:
     """FedBuff-style staleness discount: an update trained against a
     model ``staleness`` publishes old contributes ``n * decay^s`` —
-    the unit oracle the async tests and bench pin against."""
+    the unit oracle tests/test_async_agg.py pins against."""
     if staleness < 0:
         raise ValueError(f"staleness must be >= 0, got {staleness}")
     # lint: host-sync-ok — pure host arithmetic (the unit oracle)

@@ -3,7 +3,7 @@
 Beyond the reference (and beyond PRs 5-8's probabilistic comm faults):
 every fault the federation could test so far was a per-message coin
 flip at the wire layer, and the exactly-once / recovery invariants were
-re-asserted by hand inside each bench world. This module makes faults
+re-asserted by hand inside each scenario world. This module makes faults
 *schedulable and exact* across every layer that holds the server's
 durable state:
 
@@ -22,16 +22,16 @@ durable state:
   ``server.publish`` / ``client.train``) let a step kill the
   client/server at a named point in the round protocol
   (``ProcessKilled`` propagates out of the manager's dispatch loop —
-  the in-process analog of kill -9, same as the chaos bench's manual
-  choreography);
+  the in-process analog of kill -9, same as tests/test_robustness.py's
+  manual choreography);
 - **clock** — a ``clock_skew`` fault steps the process's trace
   wall-clock anchor (an NTP-step analog the trace stitcher must
   survive; monotonic-clock consumers — heartbeats, staleness — are
   unaffected by design).
 
 Everything is occurrence-counted, so an identical ``(schedule, seed)``
-pair reproduces the identical fault trace — asserted by the
-``detail.chaosplan`` bench via telemetry counters
+pair reproduces the identical fault trace — asserted by
+tests/test_chaos.py via telemetry counters
 (``chaos_faults_injected_total{fault,event}``) and the ``chaos.fault``
 trace instants both runs emit.
 
@@ -382,14 +382,14 @@ def maybe_install_chaos(args) -> Optional[ChaosSchedule]:
     the world), so an identical spec reuses the installed instance —
     steps pin ``rank`` where per-process targeting matters. A
     different spec replaces it (a new world started in the same
-    process, e.g. consecutive bench worlds).
+    process, e.g. consecutive test worlds).
 
     A config with NO chaos knobs deliberately does not uninstall: a
     rank whose args carry no steps must join the world's installed
     schedule, not tear it down. The flip side: a still-armed schedule
     outlives its world, so anything that runs consecutive worlds in
-    one process (bench harnesses, test fixtures) must call
-    ``reset_chaos()`` between them — as bench.py and conftest do."""
+    one process (test fixtures, harnesses) must call
+    ``reset_chaos()`` between them — as tests/conftest.py does."""
     global _ACTIVE_KEY
     steps = validate_schedule(
         getattr(args, "chaos_schedule", None), "chaos_schedule"

@@ -88,8 +88,8 @@ def _norm_and_cos(delta: Params, ref: Params):
 def anomaly_score(
     norm: float, cos: Optional[float], ref_norm: Optional[float]
 ) -> float:
-    """THE score combination — the unit oracle tests and the defense
-    bench pin against. Neutral inputs (no reference yet) score 0.
+    """THE score combination — the unit oracle tests/test_defense.py
+    pins against. Neutral inputs (no reference yet) score 0.
 
     The cosine evidence is weighted by the upload's *capacity to harm*
     (its norm relative to the cohort's reference norm): a converged

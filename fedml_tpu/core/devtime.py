@@ -21,7 +21,7 @@ round's result, so in steady state per-call wall time converges on
 device time; ``serving.forward`` wraps the dispatch *and* its single
 ``np.asarray`` fetch, so its measurement is true device+transfer time.
 
-The hot-loop contract (bench detail.telemetry: ``host_syncs_per_round``
+The hot-loop contract (tests/test_telemetry.py: ``host_syncs_per_round``
 bit-identical with telemetry on/off) means this module must never add
 a device fetch or block — it is ``perf_counter`` reads and dict/deque
 updates only.

@@ -1,7 +1,7 @@
 """Post-hoc invariant checking over a finished run's artifacts.
 
 The exactly-once / recovery guarantees PRs 5-8 added were each
-re-asserted by hand inside the bench world that introduced them. This
+re-asserted by hand inside the scenario world that introduced them. This
 module is the ONE reusable checker: it replays a run's durable
 artifacts — ``round_wal.jsonl`` (the server's completed-round /
 publish ledger, ``core/checkpoint.py``), ``telemetry.jsonl`` (final
@@ -72,9 +72,9 @@ monotonic, so ANY decrease across a rank's successive snapshots proves
 a registry reset) and every counter-balanced invariant is then skipped
 (noted in the report), while the WAL-internal invariants always apply.
 
-Exposed as ``fedml_tpu.cli check --telemetry-dir`` and run
-automatically at the end of every chaos / straggler / defense /
-chaosplan bench world.
+Exposed as ``fedml_tpu.cli check --telemetry-dir``; the world tests
+(tests/test_chaos.py, test_robustness.py, test_async_agg.py,
+test_defense.py) run it over their own artifacts.
 """
 
 from __future__ import annotations

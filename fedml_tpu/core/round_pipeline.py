@@ -82,8 +82,8 @@ class RoundPipeline:
     and the drain points (checkpoint / end of run).
 
     ``stats`` after ``run``: rounds executed, flushes, host syncs, and
-    ``host_syncs_per_round`` — the figure ``bench.py`` reports under
-    ``detail.pipeline``.
+    ``host_syncs_per_round`` — the figure the benchmark's round-pipeline
+    layer reports and tests/test_round_pipeline.py bounds.
     """
 
     def __init__(self, api, depth: Optional[int] = None) -> None:
@@ -178,7 +178,7 @@ class RoundPipeline:
         # telemetry (core/telemetry.py): every instrument below is a
         # host-side counter bump / ring append — the hot loop gains no
         # device fetches, so host_syncs_per_round is bit-identical with
-        # telemetry on or off (bench detail.telemetry asserts this)
+        # telemetry on or off (tests/test_telemetry.py asserts this)
         tel = getattr(api, "telemetry", None)
         tel = tel if tel is not None and tel.enabled else None
         rec = tel.recorder if tel is not None else None

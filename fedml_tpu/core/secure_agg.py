@@ -128,8 +128,8 @@ def additive_share(
 # sum_{j != i} sign(i, j) * PRG(s_ij) with sign(i, j) = +1 iff i < j —
 # across any set that all uploaded, the signed terms cancel EXACTLY in
 # integer mod-p addition, which is what makes the masked streaming fold
-# bitwise identical to the unmasked one (proven in tests and the
-# detail.crossdevice bench). A device that checked in but never
+# bitwise identical to the unmasked one (proven in
+# tests/test_beehive.py). A device that checked in but never
 # uploaded leaves its pairwise terms dangling in everyone else's
 # uploads; survivors reveal Shamir shares of the vanished secret, the
 # server reconstructs b_v (verifying g^b_v against the published key),

@@ -25,10 +25,9 @@ except ImportError:  # pragma: no cover
 
 def current_rss_bytes() -> int:
     """This process's resident set size right now (0 only when
-    unmeasurable: no psutil AND no /proc). The planet-scale bench
-    differences this around a round to measure the
-    O(cohort)-not-O(registry) host-memory claim — and fails its gate
-    loudly on 0 rather than passing vacuously."""
+    unmeasurable: no psutil AND no /proc). tests/test_planet_scale.py
+    differences this around a 1M-registry round to hold the
+    O(cohort)-not-O(registry) host-memory claim."""
     if _HAS_PSUTIL:
         return int(psutil.Process().memory_info().rss)
     try:  # psutil-less Linux: statm field 2 is resident page count
@@ -52,23 +51,6 @@ def cpu_steal_ticks() -> Optional[int]:
         return int(fields[8])
     except (OSError, ValueError, IndexError):
         return None
-
-
-def peak_rss_bytes() -> int:
-    """Lifetime peak resident set size of this process (ru_maxrss).
-    Exported by the ``detail.planet`` bench as the
-    ``planet_peak_rss_bytes`` gauge — flat-memory claims are measured,
-    not asserted in prose."""
-    try:
-        import resource
-
-        peak = resource.getrusage(resource.RUSAGE_SELF).ru_maxrss
-    except (ImportError, ValueError):  # pragma: no cover — non-POSIX
-        return current_rss_bytes()
-    # linux reports KiB, macOS bytes
-    import sys
-
-    return int(peak if sys.platform == "darwin" else peak * 1024)
 
 
 def sample_host_stats() -> Dict[str, Any]:

@@ -7,7 +7,7 @@ right now, and where did the time/bytes go" — the signal
 heterogeneity-aware schedulers need (FedML Parrot, arXiv:2303.01778)
 and FedJAX-style simulation papers report per-phase (arXiv:2108.02117).
 With the async round pipeline keeping K rounds in flight on donated
-buffers, a silent stall or retrace storm is invisible until the bench
+buffers, a silent stall or retrace storm is invisible until the chip
 window is burned. This module is the missing aggregation point:
 
 - ``Telemetry``: a process-wide registry of counters / gauges /
@@ -29,8 +29,8 @@ Hot-loop contract: every instrument here is host-side only — counter
 bumps, deque appends, ``time.perf_counter`` reads. Telemetry reads
 device values exclusively through the existing ``DeferredMetrics``
 flush; it never adds a device fetch, so ``host_syncs_per_round`` is
-bit-identical with telemetry on or off (asserted by the bench
-``detail.telemetry`` phase and tests/test_telemetry.py).
+bit-identical with telemetry on or off (asserted by
+tests/test_telemetry.py and tests/test_round_spans.py).
 
 Robustness-layer vocabulary (docs/robustness.md): the reliable channel
 counts ``comm_retries_total`` / ``comm_dup_dropped_total`` /
@@ -39,7 +39,7 @@ counts ``comm_retries_total`` / ``comm_dup_dropped_total`` /
 (core/comm/grpc_backend.py), and the cross-silo server
 ``cross_silo_clients_declared_dead_total`` /
 ``cross_silo_resyncs_total`` — all tagged by ``msg_type`` where it
-exists, all exactly-once evidence the chaos bench asserts against.
+exists, all exactly-once evidence tests/test_robustness.py asserts against.
 """
 
 from __future__ import annotations

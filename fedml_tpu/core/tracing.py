@@ -455,7 +455,7 @@ def analyze_rounds(events: List[Dict[str, Any]]) -> List[Dict[str, Any]]:
       exactly when a span is missing or the aggregate was triggered by
       a different client than the straggler (deadline path), so
       ``coverage`` (= named segments / wall) is the chain-consistency
-      honesty metric the bench gates on.
+      honesty metric tests/test_tracing.py gates on.
 
     Slack per rank = straggler upload arrival − that rank's arrival
     (how much longer the slowest client ran past each client).
@@ -576,7 +576,7 @@ def trace_run(
     ``trace_merged.json`` (perfetto-loadable) and
     ``round_report.json`` into ``out_dir`` (default: the telemetry dir
     itself) and returns a summary. The ``fedml_tpu.cli trace``
-    subcommand and the ``detail.tracing`` bench phase both call this."""
+    subcommand and tests/test_tracing.py's world test both call this."""
     out_dir = out_dir or telemetry_dir
     merged = stitch_shards(telemetry_dir)
     rounds = analyze_rounds(merged["traceEvents"])

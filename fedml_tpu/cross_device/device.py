@@ -63,8 +63,8 @@ class DeviceHost(ClientManager):
 
     Drives ``rounds`` check-in rounds against the gateway and then
     exits its receive loop. Exposes the compile census
-    (``trace_count`` / ``shape_keys``) the tests and the
-    ``detail.crossdevice`` bench assert on.
+    (``trace_count`` / ``shape_keys``) that tests/test_beehive.py
+    asserts on.
     """
 
     def __init__(

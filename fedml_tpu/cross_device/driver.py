@@ -5,8 +5,8 @@ device population), runs ``args.comm_round`` check-in rounds end to
 end, exports telemetry artifacts (so ``InvariantChecker`` can audit
 the run offline against the RoundWAL it wrote), tears the fabric down,
 and returns a plain dict of results — final params, per-round close
-records, and the compile census. The bench (``detail.crossdevice``),
-the tests, and the ``fedml-tpu device`` CLI smoke all enter here;
+records, and the compile census. The tests (tests/test_beehive.py) and the
+``fedml-tpu device`` CLI smoke both enter here;
 nothing about the protocol lives in this file.
 """
 

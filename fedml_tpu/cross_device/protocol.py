@@ -12,7 +12,7 @@ owns everything BOTH ends must agree on byte-for-byte:
 - the int8 offer codec (``core/compression.Int8Codec``): the offer is
   lossy by design, and BOTH the masked and unmasked worlds train from
   the same decoded tree, which is one of the two legs of the bitwise
-  masked==unmasked identity the bench proves;
+  masked==unmasked identity tests/test_beehive.py proves;
 - participant-roster and share-reveal payload packing (numpy columns,
   msgpack-clean — no pickled objects cross the seam).
 

@@ -18,7 +18,7 @@ same inputs (:func:`hier_partition`); pass an explicit ``partition``
 to any facade to override.
 
 ``run_local_hier_world`` wires a whole LOCAL world as threads in one
-process — the test/bench harness, mirroring the thread worlds the flat
+process — the tests' harness, mirroring the thread worlds the flat
 scenario tests use.
 """
 

@@ -90,7 +90,6 @@ class RootServerManager(ServerManager):
         self._root_acc: Optional[StreamingAccumulator] = None
         self._last_broadcast_type = None
         self._round_t0 = None
-        self.round_walls: List[float] = []  # steady-round walls (bench)
         self.stragglers_dropped = 0
         self.quorum_closes = 0
         # quorum over CLIENTS, denominators summed over live edges
@@ -570,7 +569,6 @@ class RootServerManager(ServerManager):
             )
         if self._round_t0 is not None:
             wall = time.perf_counter() - self._round_t0
-            self.round_walls.append(wall)
             self.telemetry.observe("round_wall_seconds", wall)
             self.telemetry.observe(
                 "round_segment_seconds",

@@ -222,7 +222,7 @@ class FedMLServerManager(ServerManager):
         self._folded_ids = set()  # (rank, seq) ever folded (WAL-seeded)
         self._folded_since_publish = []
         self.async_folds = 0  # folds across incarnations (target counter)
-        # (rank, seq, staleness, sample_num, weight) — the bench checks
+        # (rank, seq, staleness, sample_num, weight) — tests check
         # these against the staleness_weight unit oracle
         self.async_weight_log = []
         # zero-upload deadline handling: rebroadcast (the downlink may

@@ -9,8 +9,8 @@ The seam has three parts:
 
 1. **Signal** — a pluggable :class:`PreemptionSignal` polled once per
    round at the round boundary (never inside a jit). Sources:
-   :class:`SimulatedPreemption` (scripted round trigger, the bench and
-   tests), :class:`FilePreemption` (touch a file from another process),
+   :class:`SimulatedPreemption` (scripted round trigger, the tests'
+   drill), :class:`FilePreemption` (touch a file from another process),
    :class:`MetadataPreemption` (the GCE metadata-server
    ``maintenance-event`` poll on real TPU VMs — stdlib urllib, absent
    server reads as "no event"), and :class:`ChaosPreemption` (the
@@ -38,8 +38,8 @@ The seam has three parts:
    lost — across the mesh reshape. PR 15's mesh-shape bit-identity
    (every ``(data, fsdp)`` shape finalizes bitwise equal to
    single-chip) then guarantees the resumed run's final params are
-   bitwise identical to an uninterrupted run: the ``detail.elastic``
-   bench gates ``max_abs_diff == 0.0`` at 8->4 forced devices.
+   bitwise identical to an uninterrupted run: tests/test_elastic_mesh.py
+   (``TestPreemptResume``) holds that at 8->4 forced devices.
 
 Counters: ``elastic_preemptions_total`` (on the preempt path) and
 ``elastic_resumes_total`` (on a resume that consumed a preempt WAL
@@ -90,7 +90,7 @@ class Preempted(RuntimeError):
     """Clean controlled exit after a drained round + durable state.
 
     Raised by :func:`preempt_now` AFTER the WAL preempt record and the
-    forced checkpoint are durable — the catcher (bench harness, a real
+    forced checkpoint are durable — the catcher (a test harness, a real
     launcher's supervisor) may exit the process knowing a restart on
     the surviving devices resumes bitwise-identically.
     """
@@ -124,7 +124,7 @@ class PreemptionSignal:
 
 class SimulatedPreemption(PreemptionSignal):
     """Scripted maintenance-event drill: fires once ``round_idx``
-    reaches ``at_round``. The bench's mid-run trigger."""
+    reaches ``at_round``. The tests' mid-run trigger."""
 
     def __init__(self, at_round: int, reason: str = "maintenance-simulated"):
         self.at_round = int(at_round)
@@ -380,7 +380,3 @@ def preempt_now(
     raise Preempted(notice, int(round_idx), int(round_idx))
 
 
-def recovery_clock() -> float:
-    """Monotonic stamp for the resume-world recovery metric (the bench
-    records time from restart-world build to first completed round)."""
-    return time.perf_counter()

@@ -402,8 +402,8 @@ def build_fed_mesh(
             f"{n} devices"
         )
     # an EXPLICIT smaller shape takes a device-prefix sub-mesh — the
-    # single-chip {'data': 1, 'fsdp': 1} baseline world the multichip
-    # bench compares every sharded shape against bitwise
+    # single-chip {'data': 1, 'fsdp': 1} baseline world that
+    # tests/test_mesh_simulator.py compares every sharded shape against
     arr = np.array(devices[: data * fsdp]).reshape((data, fsdp))
     return Mesh(arr, (AXIS_COHORT, AXIS_PARAM))
 

@@ -14,7 +14,7 @@ two scales the paper's "anywhere at any scale" claim actually couples:
   consumer of ``core/scheduler.py``);
 - ``tree``: a two-tier edge-aggregator tree whose fold rides PR 7's
   order-independent ``StreamingAccumulator`` — bit-identical to flat
-  aggregation, asserted in tests and the ``detail.planet`` bench;
+  aggregation, asserted in tests/test_planet_scale.py (``TestEdgeTree``);
 - ``engine``: the registry-backed round loop the simulator routes to
   when ``client_registry_size`` is set.
 """

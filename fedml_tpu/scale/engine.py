@@ -18,8 +18,8 @@ materializes ONLY its cohort:
       -> O(model) finalize.
 
 Peak host memory per round is O(cohort x client-data), independent of
-registry size — measured as RSS deltas by the ``detail.planet`` bench,
-bounded by tests. Eval runs on the dataset's global holdout packs (the
+registry size — bounded as an RSS delta on a 1M-client registry by
+tests/test_planet_scale.py. Eval runs on the dataset's global holdout packs (the
 per-client eval dicts the eager loader builds do not exist here).
 """
 
@@ -189,9 +189,9 @@ class PlanetRoundLoop:
 
     Constructed once and CACHED on the API across ``train()`` calls
     (``fedavg_api._planet_loop``) — the persistence is load-bearing:
-    the trace-count/shape-key census and the bench's warm-replay
-    "zero new compiles" RSS methodology both require the jit cache to
-    survive repeat ``train()`` calls. Owns the registry, the per-round
+    the trace-count/shape-key census requires the jit cache to
+    survive repeat ``train()`` calls (a warm replay compiles nothing
+    new). Owns the registry, the per-round
     pack/materialize/train/fold sequence, and the group-shaped jit
     cache. ``stats`` after ``run``: cohort size, edge count, trace
     count, shape-key census, waste fraction.
@@ -299,7 +299,7 @@ class PlanetRoundLoop:
         tel = getattr(api, "telemetry", None)
         tel = tel if tel is not None and tel.enabled else None
         E = max(1, self.edge_num)
-        # edge_flat_fold is the bench's A/B harness: terms still
+        # edge_flat_fold is the tests' A/B harness: terms still
         # partition per edge (identical term set, identical rounding)
         # but fold into ONE flat accumulator — the baseline the tree's
         # bit-identity is asserted against

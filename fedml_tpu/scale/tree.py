@@ -9,7 +9,7 @@ order-independent fold — and the root folds the E edge expansions via
 exact fold, the tree's float32 finalize is **bitwise identical** to
 folding every upload into one flat accumulator, for raw and for
 quantized (codec-encoded) uploads alike. That identity is asserted
-(tests + the ``detail.planet`` bench), not hoped: it is what lets an
+(tests/test_planet_scale.py ``TestEdgeTree``), not hoped: it is what lets an
 edge tier be inserted under a live federation without changing a single
 result bit.
 

@@ -109,7 +109,7 @@ class ModelEndpoint:
         self.swaps = 0
         # bucket -> trace count, incremented at TRACE time only (the
         # python body runs when jit retraces) — the compile-count
-        # regression surface for tests/bench, like _round_trace_count
+        # regression surface for tests, like _round_trace_count
         self.trace_counts: Dict[int, int] = {}
 
         def on_trace(bucket: int) -> None:

@@ -5,7 +5,7 @@ global model: a bounded request queue (``admission.py``), a continuous
 micro-batcher (``batcher.py``) and a versioned, hot-swappable endpoint
 (``endpoint.py``) driven by one worker thread. Frontends
 (``frontends.py``) and the training loop's checkpoint watcher publish
-into it; ``bench.py``'s ``detail.serving`` phase measures it.
+into it; tests/test_serving.py holds its one-trace-per-bucket gates.
 
 Telemetry (all host-side, the core/telemetry.py hot-loop contract):
 
@@ -180,7 +180,7 @@ class ServingEngine:
 
     def pause(self) -> None:
         """Hold the worker between batches; queued requests accumulate.
-        Deterministic-batching seam for tests/bench (a paused engine
+        Deterministic-batching seam for tests (a paused engine
         turns N submits into exactly one N-row micro-batch on resume)
         and a drain gate for operational hold-the-world moments.
 

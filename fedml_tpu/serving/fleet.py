@@ -144,7 +144,7 @@ class ServingFleet:
         self._lock = threading.Lock()
         self._rr = 0
         # routed-request tally per endpoint — the load-skew evidence
-        # the bench gate asserts on (<= 2x between live endpoints)
+        # tests assert on (<= 2x between live endpoints)
         self.routed: List[int] = [0] * len(self.engines)
         # the static deal: equal unit loads through the boustrophedon
         # assignment, flattened to a cycle over the endpoints
@@ -206,9 +206,6 @@ class ServingFleet:
     # -- introspection -------------------------------------------------
     def live_indices(self) -> List[int]:
         return [i for i, e in enumerate(self.engines) if e.alive()]
-
-    def depths(self) -> List[int]:
-        return [e.depth() for e in self.engines]
 
     def load_skew(self) -> float:
         """max/min routed requests over live endpoints (1.0 = perfectly

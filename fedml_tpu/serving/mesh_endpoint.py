@@ -13,8 +13,8 @@ Three properties carry over from the training mesh, deliberately:
   params gather REPLICATED (the FSDP at-use gather), the request batch
   and the result shard along ``data``. Per-example compute is never
   tensor-split, so a response is **bitwise identical** across mesh
-  shapes — the serving analog of the multichip round identity, and the
-  ``detail.serving`` bench gate.
+  shapes — the serving analog of the multichip round identity, held by
+  tests/test_serving_fleet.py (``TestMeshEndpoint``).
 - **Device-direct publish.** ``restore_target`` hands
   ``CheckpointWatcher`` an abstract state tree whose params leaves
   carry the mesh ``NamedSharding``s, so orbax restores each shard

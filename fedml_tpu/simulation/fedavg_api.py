@@ -502,7 +502,7 @@ class FedAvgAPI:
                 "(data, fsdp) mesh with %s: aggregation does not go "
                 "through the exact expansion fold, so final params are "
                 "correct to float tolerance but NOT bitwise identical "
-                "across mesh shapes (the detail.multichip identity "
+                "across mesh shapes (the mesh-shape bitwise identity "
                 "gate covers the plain FedAvg/FedProx path only)",
                 "defense_type" if self.robust is not None
                 else ("a custom server_aggregator"
@@ -678,8 +678,8 @@ class FedAvgAPI:
         freq = max(1, int(getattr(args, "frequency_of_the_test", 5)))
         ckpt, start_round = self._maybe_restore()
         if getattr(self, "_preempt_signal", None) is None:
-            # the elastic seam (parallel/elastic.py): tests and the
-            # bench inject a signal object directly; everyone else gets
+            # the elastic seam (parallel/elastic.py): tests inject a
+            # signal object directly; everyone else gets
             # it from the preempt_signal knob (validated to require
             # checkpoint_dir, so a notice always has somewhere durable
             # to land)

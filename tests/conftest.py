@@ -1,7 +1,7 @@
 """Test harness: force an 8-device virtual CPU mesh.
 
 The tests run on the CPU (the chip is reached through chip_smoke.py
-and bench.py, never through pytest); per the reference's own pattern of
+and benchmark/run.py, never through pytest); per the reference's own pattern of
 running every scenario single-host (SURVEY.md §4 "multi-node without a
 cluster"), all sharding tests run on
 ``--xla_force_host_platform_device_count=8`` CPU devices. The platform

@@ -199,6 +199,14 @@ class TestBeehiveWorld:
         steps = vanish_schedule(rounds=3)
         m = run_world(comm_round=3, chaos_schedule=steps)
         assert any(r["recovered"] > 0 for r in m["round_records"])
+        tel = Telemetry.get_instance()
+        assert tel.get_counter("device_mask_recovery_failures_total") == 0.0
+        rep = InvariantChecker(
+            telemetry_dir=m["args"].telemetry_dir,
+            checkpoint_dir=m["args"].checkpoint_dir,
+        ).check()
+        assert rep.ok, rep.to_dict()
+        assert "device_mask_recovery_verified" in rep.checked
         u = run_world(
             comm_round=3, chaos_schedule=steps, crossdevice_secure_agg=False
         )

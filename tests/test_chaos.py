@@ -2,7 +2,7 @@
 scheduled multi-layer fault injection — exact-message comm faults
 through the FaultInjector plan seam, WAL/checkpoint IO faults through
 the DurableIO seam, process kills at named barriers, clock skew — plus
-the crash-point enumeration the detail.chaosplan sweep runs on.
+the crash-point enumeration and one world per kind of crash point.
 """
 
 import os
@@ -216,26 +216,67 @@ class TestScheduleFiring:
         assert s.on_event("barrier", name="client.train") == []
         assert s.on_event("barrier", name="client.train", rank=2) != []
 
-    def test_identical_schedule_and_seed_fire_identically(self):
-        spec = [
-            {"at": {"event": "send", "msg_type": 3, "occurrence": 2},
-             "fault": "drop"},
-            {"at": {"event": "wal_append", "occurrence": 1},
-             "fault": "fsync_fail"},
-        ]
-        events = [
-            ("send", {"msg_type": 3}),
-            ("wal_append", {"round": 0}),
-            ("send", {"msg_type": 3}),
-            ("send", {"msg_type": 3}),
-        ]
+    @pytest.mark.parametrize(
+        "spec, events",
+        [
+            (
+                [
+                    {"at": {"event": "send", "msg_type": 3, "occurrence": 2},
+                     "fault": "drop"},
+                    {"at": {"event": "wal_append", "occurrence": 1},
+                     "fault": "fsync_fail"},
+                ],
+                [
+                    ("send", {"msg_type": 3}),
+                    ("wal_append", {"round": 0}),
+                    ("send", {"msg_type": 3}),
+                    ("send", {"msg_type": 3}),
+                ],
+            ),
+            # one step a seam, as a three-client world would raise them:
+            # the injector's plan, the durable-IO seam, a barrier
+            (
+                [
+                    {"at": {"event": "send", "msg_type": 3, "rank": 1,
+                            "occurrence": 1}, "fault": "drop"},
+                    {"at": {"event": "send", "msg_type": 3, "rank": 2,
+                            "occurrence": 2}, "fault": "duplicate"},
+                    {"at": {"event": "send", "msg_type": 3, "rank": 3,
+                            "occurrence": 1},
+                     "fault": {"kind": "delay", "delay_s": 0.2}},
+                    {"at": {"event": "wal_append", "occurrence": 1},
+                     "fault": {"kind": "latency", "delay_s": 0.05}},
+                    {"at": {"event": "wal_append", "occurrence": 2},
+                     "fault": "fsync_fail"},
+                    {"at": {"event": "barrier", "name": "server.round_close",
+                            "occurrence": 2},
+                     "fault": {"kind": "clock_skew", "skew_s": 0.5}},
+                ],
+                [
+                    (ev, ctx)
+                    for rnd in range(2)
+                    for ev, ctx in [
+                        ("send", {"msg_type": 3, "rank": 1}),
+                        ("send", {"msg_type": 3, "rank": 2}),
+                        ("send", {"msg_type": 3, "rank": 3}),
+                        ("barrier", {"name": "server.round_close",
+                                     "round": rnd}),
+                        ("wal_append", {"round": rnd}),
+                    ]
+                ],
+            ),
+        ],
+        ids=["two_steps", "one_step_a_seam"],
+    )
+    def test_identical_schedule_and_seed_fire_identically(self, spec, events):
         runs = []
         for _ in range(2):
             s = ChaosSchedule(spec, seed=5)
             for ev, ctx in events:
                 s.on_event(ev, **ctx)
+            assert s.pending() == 0
             runs.append([(f["step"], f["event"], f["fault"]) for f in s.fired])
-        assert runs[0] == runs[1] and len(runs[0]) == 2
+        assert runs[0] == runs[1] and len(runs[0]) == len(spec)
 
     def test_one_firing_per_event_no_phantom_burn(self):
         # two steps reaching their occurrence on the SAME event: only
@@ -669,20 +710,72 @@ class TestReliableInternalErrors:
         ch.stop_receive_message()
 
 
-@pytest.mark.slow  # a LOCAL world + server restart (>4s fast-gate budget)
+def _build_rank(args_factory, run_id, rank, **kw):
+    import fedml_tpu
+    from fedml_tpu import models
+    from fedml_tpu.data import load
+    from test_cross_silo import _mk_args
+
+    a = _mk_args(args_factory, run_id, "LOCAL", **kw)
+    a.rank = rank
+    a = fedml_tpu.init(a)
+    ds = load(a)
+    m = models.create(a, ds.class_num)
+    return a, ds, m
+
+
+def _build_world(args_factory, run_id, n_clients, **kw):
+    """Server, clients and the server's dataset (a restart reuses it)."""
+    from fedml_tpu.cross_silo import Client, Server
+
+    a0, ds0, m0 = _build_rank(args_factory, run_id, 0, **kw)
+    server = Server(a0, None, ds0, m0)
+    clients = []
+    for r in range(1, n_clients + 1):
+        a, ds, m = _build_rank(args_factory, run_id, r, **kw)
+        clients.append(Client(a, None, ds, m))
+    return server, clients, ds0
+
+
+def _start_clients(clients):
+    def run(c):
+        try:
+            c.run()
+        except ProcessKilled:  # lint: except-ok — a scheduled kill_client IS the test
+            pass
+
+    threads = [
+        threading.Thread(target=run, args=(c,), daemon=True) for c in clients
+    ]
+    for t in threads:
+        t.start()
+    return threads
+
+
 class TestScheduledCrashWorld:
+    # one point of every kind the sweep enumerates (WAL append: record
+    # lost, torn, durable; checkpoint publish: params lost, WAL behind)
+    @pytest.mark.parametrize(
+        "point",
+        [
+            {"event": "wal_append", "occurrence": 2, "mode": "before"},
+            {"event": "wal_append", "occurrence": 2, "mode": "torn",
+             "nbytes": 40},
+            {"event": "wal_append", "occurrence": 2, "mode": "after"},
+            {"event": "ckpt_publish", "occurrence": 2, "mode": "before"},
+            {"event": "ckpt_publish", "occurrence": 2, "mode": "after"},
+        ],
+        ids=lambda p: f"{p['event']}-{p['mode']}",
+    )
     def test_scheduled_server_kill_recovers_with_clean_invariants(
-        self, args_factory, tmp_path
+        self, args_factory, tmp_path, point
     ):
-        """End-to-end mini of the chaosplan sweep: a schedule kills the
-        server at an exact WAL-append boundary; a restarted server
-        resumes from checkpoint+WAL, the world completes, and the
-        post-hoc InvariantChecker is clean on the artifacts."""
-        import fedml_tpu
-        from fedml_tpu import models
+        """The crash-point sweep, one point a case: a schedule kills
+        the server at an exact durable-write boundary; a restarted
+        server resumes from checkpoint+WAL, the world completes, and
+        the post-hoc InvariantChecker is clean on the artifacts."""
         from fedml_tpu.core.invariants import InvariantChecker
-        from fedml_tpu.cross_silo import Client, Server
-        from fedml_tpu.data import load
+        from fedml_tpu.cross_silo import Server
 
         reset_chaos()
         Telemetry.reset()
@@ -697,28 +790,10 @@ class TestScheduledCrashWorld:
             heartbeat_timeout_s=60.0,
             client_num_in_total=2,
             client_num_per_round=2,
-            chaos_schedule=[
-                {"at": {"event": "wal_append", "occurrence": 2},
-                 "fault": {"kind": "kill_server", "when": "before"}},
-            ],
+            chaos_schedule=crash_point_schedule(point),
         )
-
-        def build(rank):
-            from test_cross_silo import _mk_args
-
-            a = _mk_args(args_factory, "chaos_kill_world", "LOCAL", **kw)
-            a.rank = rank
-            a = fedml_tpu.init(a)
-            ds = load(a)
-            m = models.create(a, ds.class_num)
-            return a, ds, m
-
-        a0, ds0, m0 = build(0)
-        server = Server(a0, None, ds0, m0)
-        clients = []
-        for r in (1, 2):
-            a, ds, m = build(r)
-            clients.append(Client(a, None, ds, m))
+        run_id = "chaos_kill_world"
+        server, clients, ds0 = _build_world(args_factory, run_id, 2, **kw)
         killed = {}
 
         def srv():
@@ -729,16 +804,12 @@ class TestScheduledCrashWorld:
                 if server.manager._failure_detector is not None:
                     server.manager._failure_detector.stop()
 
-        threads = [
-            threading.Thread(target=c.run, daemon=True) for c in clients
-        ]
-        for t in threads:
-            t.start()
+        threads = _start_clients(clients)
         st = threading.Thread(target=srv, daemon=True)
         st.start()
         st.join(timeout=120)
         assert killed, "scheduled kill never fired"
-        a0b, _, m0b = build(0)
+        a0b, _, m0b = _build_rank(args_factory, run_id, 0, **kw)
         server2 = Server(a0b, None, ds0, m0b)
         server2.run()
         for t in threads:
@@ -748,9 +819,68 @@ class TestScheduledCrashWorld:
         report = InvariantChecker(telemetry_dir=td, checkpoint_dir=ck).check()
         assert report.ok, report.to_dict()
         assert "chaos_trace_consistent" in report.checked
+        assert "cohort_accounting" in report.checked
 
 
-@pytest.mark.slow  # two LOCAL worlds + a restart (>4s fast-gate budget)
+class TestScheduledWorlds:
+    def test_async_defended_world_reaches_its_fold_target(
+        self, args_factory, tmp_path
+    ):
+        """The two seams only an async world has, under the clipping
+        defense: a client killed at its ``client.train`` barrier and a
+        clock step at a ``server.publish`` barrier. The survivors reach
+        the fold target and the checker proves exactly-once folds,
+        monotone versions and no reissued sequence numbers from the
+        artifacts."""
+        from fedml_tpu.core.chaos import active_chaos
+        from fedml_tpu.core.invariants import InvariantChecker
+
+        reset_chaos()
+        Telemetry.reset()
+        ck, td = str(tmp_path / "ck"), str(tmp_path / "td")
+        kw = dict(
+            client_num_in_total=3,
+            client_num_per_round=3,
+            agg_mode="async",
+            async_publish_every=2,
+            staleness_decay=0.5,
+            staleness_max=64,
+            defense_type="norm_diff_clipping",
+            norm_bound=1.0,
+            heartbeat_interval_s=0.1,
+            heartbeat_timeout_s=1.5,
+            checkpoint_dir=ck,
+            checkpoint_freq=1,
+            telemetry_dir=td,
+            chaos_schedule=[
+                # every rank trains once: the kill cannot miss
+                {"at": {"event": "barrier", "name": "client.train",
+                        "rank": 2, "occurrence": 1},
+                 "fault": "kill_client"},
+                {"at": {"event": "barrier", "name": "server.publish",
+                        "occurrence": 2},
+                 "fault": {"kind": "clock_skew", "skew_s": 0.25}},
+            ],
+        )
+        run_id = "chaos_async_defended"
+        server, clients, ds0 = _build_world(args_factory, run_id, 3, **kw)
+        threads = _start_clients(clients)
+        server.run()
+        for t in threads:
+            t.join(timeout=60)
+        assert not any(t.is_alive() for t in threads)
+        mgr = server.manager
+        assert mgr.async_folds >= mgr._async_target_folds()
+        assert server.aggregator.defense_clipped > 0
+        assert active_chaos().pending() == 0, "a scheduled step never fired"
+        report = InvariantChecker(telemetry_dir=td, checkpoint_dir=ck).check()
+        assert report.ok, report.to_dict()
+        for name in ("exactly_once_folds", "version_monotone",
+                     "no_reissued_seqs", "no_lost_unreported_folds"):
+            assert name in report.checked, report.to_dict()
+        reset_chaos()
+
+
 class TestAsyncRestartRace:
     """PR 10's pinned pre-existing race, reproduced deterministically
     with a chaos schedule and fixed: a client killed BEFORE the server
@@ -761,25 +891,12 @@ class TestAsyncRestartRace:
     leaves the awaited set, so the handshake completes over the
     survivors."""
 
-    def _build(self, args_factory, run_id, rank, **kw):
-        import fedml_tpu
-        from fedml_tpu import models
-        from fedml_tpu.data import load
-        from test_cross_silo import _mk_args
-
-        a = _mk_args(args_factory, run_id, "LOCAL", **kw)
-        a.rank = rank
-        a = fedml_tpu.init(a)
-        ds = load(a)
-        m = models.create(a, ds.class_num)
-        return a, ds, m
-
     def test_client_killed_before_server_crash_does_not_stall_resume(
         self, args_factory, tmp_path
     ):
         import fedml_tpu
         from fedml_tpu.core.invariants import InvariantChecker
-        from fedml_tpu.cross_silo import Client, Server
+        from fedml_tpu.cross_silo import Server
 
         reset_chaos()
         Telemetry.reset()
@@ -807,12 +924,7 @@ class TestAsyncRestartRace:
             ],
         )
         run_id = "async_restart_race"
-        a0, ds0, m0 = self._build(args_factory, run_id, 0, **kw)
-        server = Server(a0, None, ds0, m0)
-        clients = []
-        for r in (1, 2):
-            a, ds, m = self._build(args_factory, run_id, r, **kw)
-            clients.append(Client(a, None, ds, m))
+        server, clients, ds0 = _build_world(args_factory, run_id, 2, **kw)
         killed = {}
 
         def srv():
@@ -823,18 +935,7 @@ class TestAsyncRestartRace:
                 if server.manager._failure_detector is not None:
                     server.manager._failure_detector.stop()
 
-        def cli(c):
-            try:
-                c.run()
-            except ProcessKilled:  # lint: except-ok — the scheduled rank-1 kill IS the test
-                pass
-
-        threads = [
-            threading.Thread(target=cli, args=(c,), daemon=True)
-            for c in clients
-        ]
-        for t in threads:
-            t.start()
+        threads = _start_clients(clients)
         st = threading.Thread(target=srv, daemon=True)
         st.start()
         st.join(timeout=120)
@@ -843,7 +944,7 @@ class TestAsyncRestartRace:
 
         # restart: rank 1 is long dead and will never re-announce.
         # Pre-fix, this run() blocked forever awaiting rank 1's ONLINE.
-        a0b, _, m0b = self._build(args_factory, run_id, 0, **kw)
+        a0b, _, m0b = _build_rank(args_factory, run_id, 0, **kw)
         server2 = Server(a0b, None, ds0, m0b)
         done = {}
 

@@ -5,7 +5,6 @@ placements around it — no CPU run or interpreted kernel can pass for a
 device run, and the compile cache goes where it is put from outside.
 """
 
-import json
 import logging
 import os
 import subprocess
@@ -24,8 +23,6 @@ pytestmark = pytest.mark.smoke
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
-sys.path.insert(0, REPO)
-import bench  # noqa: E402
 
 
 class TestChipSmokeRefusesCpu:
@@ -111,7 +108,7 @@ class TestFlashCrossLowersForTpu:
     @pytest.mark.parametrize(
         "shape",
         [
-            (4, 4096, 8, 64),   # bench.py longctx
+            (4, 4096, 8, 64),   # chip_smoke stage B, the kernel alone
             (2, 2048, 4, 128),  # D = one full lane tile
             (8, 1024, 12, 64),  # chip_smoke stage B: B*H = 96
         ],
@@ -120,7 +117,7 @@ class TestFlashCrossLowersForTpu:
         hlo = _tpu_hlo(lambda q, k, v: flash_attention(q, k, v, True), *_qkv(*shape))
         assert "tpu_custom_call" in hlo
 
-    def test_backward_at_bench_shape(self):
+    def test_backward_at_the_kernel_alone_shape(self):
         def loss(q, k, v):
             return flash_attention(q, k, v, True).astype(jnp.float32).sum()
 
@@ -298,113 +295,6 @@ class TestNoSilentMfu:
         assert perf.join_roofline(
             {"executables": []}, measured, "cpu"
         )["peak_bf16_flops"] is None
-
-    def test_bench_mfu_detail_on_cpu_has_flops_but_no_mfu(self):
-        out = bench._mfu_detail(1e9, 2.0)
-        assert out["model_flops_per_sec"] == 2e9
-        assert "mfu_vs_bf16_peak" not in out
-
-
-class TestMultichipShapes:
-    def test_four_chip_host_exercises_fsdp(self):
-        shapes = dict(bench._multichip_shapes(4))
-        assert shapes == {
-            "1x1": {"data": 1, "fsdp": 1},
-            "4x1": {"data": 4, "fsdp": 1},
-            "2x2": {"data": 2, "fsdp": 2},
-        }
-
-    def test_other_hosts(self):
-        assert [k for k, _ in bench._multichip_shapes(1)] == ["1x1"]
-        assert [k for k, _ in bench._multichip_shapes(2)] == ["1x1", "2x1"]
-        assert [k for k, _ in bench._multichip_shapes(8)] == [
-            "1x1", "8x1", "4x2", "2x4",
-        ]
-        # every shape fits the devices there are
-        for n in (1, 2, 4, 8):
-            for _, shape in bench._multichip_shapes(n):
-                assert shape["data"] * shape["fsdp"] <= n
-
-
-def _fake_children(failing=()):
-    """A stand-in for bench._run_phase_subprocess: every phase child
-    'succeeds' with a minimal record except the ones named."""
-    calls = []
-
-    def run(phase_args, timeout_s):
-        phase = phase_args[1]
-        calls.append(phase_args)
-        if phase in failing:
-            return None, "rc=1: boom"
-        if phase == "headline":
-            return {"metric": "fedavg_rounds_per_sec", "value": 2.0,
-                    "unit": "rounds/s", "vs_baseline": 3.0, "detail": {}}, "ok"
-        if phase == "sweep":
-            c = int(phase_args[3])
-            return {"clients": c, "rounds_per_sec": 1.0,
-                    "samples_per_sec": 100.0 * c}, "ok"
-        return {"rounds_per_sec": 1.0}, "ok"
-
-    return run, calls
-
-
-def _keys(node):
-    if isinstance(node, dict):
-        for k, v in node.items():
-            yield k
-            yield from _keys(v)
-    elif isinstance(node, list):
-        for v in node:
-            yield from _keys(v)
-
-
-class TestBenchHasNoFallback:
-    def test_parent_returns_zero_when_every_child_passes(self, monkeypatch, capsys):
-        run, calls = _fake_children()
-        monkeypatch.setattr(bench, "_run_phase_subprocess", run)
-        assert bench.main() == 0
-        out = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
-        assert "failed_phases" not in out
-        assert set(out["detail"]) >= set(bench._DETAIL_PHASES) | {
-            "scaling", "bf16", "longctx", "mesh",
-        }
-        # the children are placed by the environment, never by the parent
-        assert not any("--cpu" in c for c in calls)
-
-    def test_parent_returns_nonzero_when_a_child_fails(self, monkeypatch, capsys):
-        run, _ = _fake_children(failing={"dense", "longctx"})
-        monkeypatch.setattr(bench, "_run_phase_subprocess", run)
-        assert bench.main() != 0
-        out = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
-        assert [f["phase"] for f in out["failed_phases"]] == ["dense", "longctx"]
-        assert "dense" not in out["detail"] and "longctx" not in out["detail"]
-        bad = [k for k in _keys(out)
-               if k.endswith("_cpu_fallback") or k.endswith("_skipped")]
-        assert not bad, bad
-
-    def test_no_headline_no_rate(self, monkeypatch, capsys):
-        run, calls = _fake_children(failing={"headline"})
-        monkeypatch.setattr(bench, "_run_phase_subprocess", run)
-        assert bench.main() != 0
-        assert capsys.readouterr().out.strip() == ""
-        assert len(calls) == 1  # nothing else runs without a headline
-
-    def test_child_without_cpu_flag_refuses_a_cpu_platform(self, tmp_path):
-        with pytest.raises(RuntimeError, match="without --cpu"):
-            bench._phase_main(
-                ["--phase", "headline", "--out", str(tmp_path / "o.json")]
-            )
-        assert not (tmp_path / "o.json").exists()
-
-    def test_parent_module_does_not_import_jax(self):
-        # one process per chip: the parent must stay off JAX
-        r = subprocess.run(
-            [sys.executable, "-c",
-             "import sys; sys.path.insert(0, %r); import bench; "
-             "sys.exit(1 if 'jax' in sys.modules else 0)" % REPO],
-            capture_output=True, text=True, timeout=60,
-        )
-        assert r.returncode == 0, r.stderr
 
 
 class TestSiloLauncherOnAChipHost:

@@ -311,7 +311,7 @@ class TestAnomalyScreen:
         """Regression: once a federation converges, accepted norms
         collapse toward zero — a ratio against a near-zero median read
         ANY ordinary step as a 4x anomaly and mass-quarantined honest
-        ranks (measured in the async bench world). The reference norm
+        ranks (measured in an async poisoned world). The reference norm
         floors at a fraction of the clip radius: deltas far below the
         clip bound can never be norm-anomalous."""
         s = AnomalyScreen(
@@ -527,41 +527,52 @@ class TestAggregatorDefenseUnit:
         )
 
 
+def _run_world(make, run_id, n=4, **kw):
+    """One LOCAL world to its end; the server and its evaluation of the
+    final model on the clean test split."""
+    from fedml_tpu.cross_silo import Client, Server
+
+    Telemetry.reset()
+    a0, ds0, m0 = _build_node(make, run_id, 0, n=n, **kw)
+    server = Server(a0, None, ds0, m0)
+    clients = []
+    for r in range(1, n + 1):
+        a, ds, m = _build_node(make, run_id, r, n=n, **kw)
+        clients.append(Client(a, None, ds, m))
+    threads = [threading.Thread(target=c.run, daemon=True) for c in clients]
+    for t in threads:
+        t.start()
+    server.run()
+    for t in threads:
+        t.join(timeout=60)
+    assert not any(t.is_alive() for t in threads)
+    stats = server.aggregator.test_on_server_for_all_clients(a0.comm_round)
+    return server, stats
+
+
+def _quarantined_ranks(tel):
+    # defense_quarantined_total{rank=N}
+    return {
+        int(key.rsplit("rank=", 1)[1].rstrip("}"))
+        for key in tel.counters_matching("defense_quarantined_total")
+    }
+
+
+@pytest.mark.smoke
 class TestDefendedWorlds:
-    @pytest.mark.slow  # two LOCAL worlds (>4s fast-gate budget)
     def test_stream_equals_buffered_with_weak_dp(self, args_factory):
         """Bit-identity extends to weak_dp: per-term clip + finalize
         noise from the derived key are shared by both modes."""
-
-        def world(run_id, mode):
-            Telemetry.reset()
-            from fedml_tpu.cross_silo import Client, Server
-
-            a0, ds0, m0 = _build_node(
-                args_factory, run_id, 0, agg_mode=mode,
-                defense_type="weak_dp", norm_bound=1.0, stddev=0.01,
-            )
-            server = Server(a0, None, ds0, m0)
-            clients = []
-            for r in range(1, 5):
-                a, ds, m = _build_node(
-                    args_factory, run_id, r, agg_mode=mode,
-                    defense_type="weak_dp", norm_bound=1.0, stddev=0.01,
-                )
-                clients.append(Client(a, None, ds, m))
-            threads = [
-                threading.Thread(target=c.run, daemon=True) for c in clients
-            ]
-            for t in threads:
-                t.start()
-            server.run()
-            for t in threads:
-                t.join(timeout=60)
-            assert not any(t.is_alive() for t in threads)
-            return server
-
-        buffered = world("wdp_buf", "buffered")
-        streamed = world("wdp_str", "stream")
+        kw = dict(defense_type="weak_dp", norm_bound=1.0, stddev=0.01)
+        buffered, _ = _run_world(
+            args_factory, "wdp_buf", agg_mode="buffered", **kw
+        )
+        streamed, _ = _run_world(
+            args_factory, "wdp_str", agg_mode="stream", **kw
+        )
+        assert buffered.aggregator.peak_buffered == 4
+        assert streamed.aggregator.peak_buffered == 0
+        assert streamed.aggregator.defense_clipped > 0
         jax.tree.map(
             lambda a, b: np.testing.assert_array_equal(
                 np.asarray(a), np.asarray(b)
@@ -570,7 +581,60 @@ class TestDefendedWorlds:
             streamed.aggregator.get_global_model_params(),
         )
 
-    @pytest.mark.slow  # async LOCAL world (>4s fast-gate budget)
+    def test_poisoned_world_diverges_and_the_defended_one_recovers(
+        self, args_factory, tmp_path
+    ):
+        """Two of six silos train on poisoned shards (one label flip,
+        one backdoor pattern; data/poison.py). Undefended, the server's
+        loss on the clean test split blows up; with clipping and the
+        anomaly screen both attackers are quarantined, every round
+        still completes, the model lands back near the clean run, and
+        every aggregated upload is exactly one fold."""
+        from fedml_tpu.core.invariants import InvariantChecker
+
+        n, rounds = 6, 6
+        size = dict(
+            n=n, rounds=rounds, synthetic_train_size=360,
+            synthetic_test_size=120,
+        )
+        poison = dict(
+            poison_type=["label_flip", "backdoor_pattern"],
+            poisoned_client_idxs=[1, 4], poison_sample_fraction=1.0,
+        )
+        attackers = {2, 5}  # silo idx + 1
+        defense = dict(
+            defense_type="norm_diff_clipping", norm_bound=1.0,
+            defense_anomaly_threshold=0.35, defense_quarantine_rounds=3,
+        )
+        _, clean_stats = _run_world(args_factory, "pw_clean", **size)
+        _, undef_stats = _run_world(
+            args_factory, "pw_undef", **size, **poison
+        )
+        assert undef_stats["loss"] > 3.0 * clean_stats["loss"]
+
+        ck, td = str(tmp_path / "ck"), str(tmp_path / "td")
+        defended, def_stats = _run_world(
+            args_factory, "pw_def", **size, **poison, **defense,
+            checkpoint_dir=ck, checkpoint_freq=1, telemetry_dir=td,
+        )
+        tel = Telemetry.get_instance()
+
+        def total(counter):
+            return sum(tel.counters_matching(counter).values())
+
+        assert attackers <= _quarantined_ranks(tel)
+        assert defended.manager.round_idx == rounds
+        # the screen scores uploads as they arrive, so the defended loss
+        # is one of a few values (0.021, 0.025 seen) against 0.208
+        assert def_stats["loss"] < 0.5 * undef_stats["loss"]
+        assert total("defense_clipped_total") > 0
+        assert total("defense_quarantined_rejected_total") >= 1
+        folds = total("agg_folds_total")
+        assert folds == total("cross_silo_clients_aggregated_total")
+        assert folds <= n * rounds
+        report = InvariantChecker(telemetry_dir=td, checkpoint_dir=ck).check()
+        assert report.ok, report.to_dict()
+
     def test_async_finishes_when_only_quarantined_ranks_remain(
         self, args_factory
     ):
@@ -636,7 +700,6 @@ class TestDefendedWorlds:
         assert mgr.aggregator.quarantined_ranks() == {3}
         assert mgr.async_folds < mgr._async_target_folds()  # stall finish
 
-    @pytest.mark.slow  # Byzantine LOCAL world (>4s fast-gate budget)
     def test_quarantined_rank_cannot_stall_quorum_round(self, args_factory):
         """A rank quarantined MID-ROUND drops through the drop-expected
         path: the round completes without waiting on it, later

@@ -145,14 +145,17 @@ class TestLimbTravel:
         state = {"limbs": [self._tree(0)] * 3, "total_w": 1.0, "count": 1}
         assert reshape_limb_state(state, None) is state
 
+    @pytest.mark.parametrize("uplink", ["raw", "int8"])
     def test_limbs_reshard_and_fold_bitwise_across_the_reshape(
-        self, eight_devices
+        self, eight_devices, uplink
     ):
         """The travel contract: fold half the uploads on the 8-device
         mesh, export/reshard/fold_limbs onto the 4-device survivor
         mesh, fold the rest there — finalize must equal the
-        single-mesh fold of all four EXACTLY."""
+        single-mesh fold of all four EXACTLY, for raw uploads and for
+        int8-encoded ones (decode + weight fused in the term)."""
         from fedml_tpu.core.aggregation import StreamingAccumulator
+        from fedml_tpu.core.compression import Int8Codec
 
         mesh8 = build_fed_mesh(
             devices=eight_devices, mesh_shape={"data": 8, "fsdp": 1}
@@ -162,12 +165,23 @@ class TestLimbTravel:
         )
         ups = [self._tree(i) for i in range(4)]
         ws = [3.0, 1.0, 5.0, 2.0]
+        if uplink == "raw":
+            def fold(acc, i, mesh):
+                acc.fold(shard_tree(ups[i], mesh), ws[i])
+        else:
+            codec = Int8Codec()
+            encs = [codec.encode(u) for u in ups]
+
+            def fold(acc, i, mesh):
+                acc.fold_encoded(
+                    codec, encs[i], shard_tree(ups[0], mesh), ws[i]
+                )
         ref = StreamingAccumulator(shard_tree(ups[0], mesh8))
-        for u, w in zip(ups, ws):
-            ref.fold(shard_tree(u, mesh8), w)
+        for i in range(4):
+            fold(ref, i, mesh8)
         acc8 = StreamingAccumulator(shard_tree(ups[0], mesh8))
-        for u, w in zip(ups[:2], ws[:2]):
-            acc8.fold(shard_tree(u, mesh8), w)
+        for i in (0, 1):
+            fold(acc8, i, mesh8)
         state = reshape_limb_state(acc8.export_state(), mesh4)
         for limb in state["limbs"]:
             for leaf in jax.tree.leaves(limb):
@@ -176,8 +190,8 @@ class TestLimbTravel:
         acc4.fold_limbs(
             state["limbs"], state["total_w"], count=state["count"]
         )
-        for u, w in zip(ups[2:], ws[2:]):
-            acc4.fold(shard_tree(u, mesh4), w)
+        for i in (2, 3):
+            fold(acc4, i, mesh4)
         assert acc4.count == ref.count and acc4.total_w == ref.total_w
         for a, b in zip(
             jax.tree.leaves(ref.finalize()), jax.tree.leaves(acc4.finalize())

@@ -210,8 +210,8 @@ class TestPlanning:
         assert agg._tree is None  # the ROOT does the tree merge
 
 
+@pytest.mark.smoke
 class TestBitIdentity:
-    @pytest.mark.slow
     def test_tree_over_ranks_matches_inproc_tree_and_flat(self, args_factory):
         flat = _run_flat(args_factory, "hier_flat")
         Telemetry.reset()
@@ -227,7 +227,6 @@ class TestBitIdentity:
         assert _params_equal(flat, inproc)
         assert _params_equal(flat, hier)
 
-    @pytest.mark.slow
     def test_bit_identity_int8_uplinks(self, args_factory):
         flat = _run_flat(args_factory, "hier_flat8", compression="int8")
         Telemetry.reset()
@@ -238,8 +237,8 @@ class TestBitIdentity:
         assert _params_equal(flat, hier)
 
 
+@pytest.mark.smoke
 class TestTwoHopExactlyOnce:
-    @pytest.mark.slow
     def test_drop_dup_faults_heal_to_exactly_once(self, args_factory):
         clean = _run_hier(args_factory, "hier_clean_x1")
         clean_params = jax.tree.map(
@@ -704,8 +703,8 @@ class TestEdgeDeath:
                 mgr._failure_detector.stop()
 
 
+@pytest.mark.smoke
 class TestEdgeCrashRestart:
-    @pytest.mark.slow
     def test_edge_kill_at_barrier_recovers_bit_identical(
         self, args_factory, tmp_path
     ):
@@ -796,6 +795,7 @@ class TestEdgeCrashRestart:
         assert [r["round_idx"] for r in sub] == [0, 1]
 
 
+@pytest.mark.smoke
 class TestMultiTierChecker:
     # measured ~2.3s: inside the fast-gate budget, so tier-1 keeps one
     # real three-tier world end-to-end

@@ -171,8 +171,8 @@ class TestFedMeshConstruction:
         assert mesh.shape == {"data": 8, "fsdp": 1}
 
     def test_explicit_subset_mesh(self, eight_devices):
-        """{'data': 1, 'fsdp': 1} — the single-chip baseline world the
-        multichip bench compares every sharded shape against."""
+        """{'data': 1, 'fsdp': 1} — the single-chip baseline world
+        every sharded shape is compared against."""
         mesh = build_fed_mesh(mesh_shape={"data": 1, "fsdp": 1})
         assert mesh.shape == {"data": 1, "fsdp": 1}
         assert is_fed_mesh(mesh)

@@ -163,6 +163,7 @@ def _flash_qkv(seed):
     return mk(), mk(), mk()
 
 
+@pytest.mark.smoke
 class TestFlashAttention:
     @pytest.mark.parametrize("causal", [True, False])
     def test_matches_full(self, causal):

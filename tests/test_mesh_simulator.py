@@ -37,6 +37,7 @@ def _args(make, **kw):
     return make(**base)
 
 
+@pytest.mark.smoke
 class TestMesh:
     def test_build_mesh_shapes(self, eight_devices):
         m = build_mesh()
@@ -118,6 +119,7 @@ class TestMesh:
             SimulatorMesh(args, None, dataset, model)
 
 
+@pytest.mark.smoke
 class TestFedMesh:
     """The (data, fsdp) production mesh (parallel/layout.py + the
     build_round_fn fed branch): cohort sharded along ``data``, params
@@ -135,15 +137,16 @@ class TestFedMesh:
         return sim
 
     def test_mesh_shapes_bitwise_identical(self, eight_devices, args_factory):
-        """{data: 4, fsdp: 2} and {data: 8} both finalize to EXACTLY
-        the single-chip {data: 1, fsdp: 1} world's float32 bits — the
-        per-client compute is never tensor-split (FSDP gathers at use)
-        and the exact expansion fold is placement-independent. This is
-        the ``detail.multichip`` bench's max_abs_diff == 0.0 gate as a
-        tier-1 test."""
+        """{data: 4, fsdp: 2}, {data: 8} and {data: 2, fsdp: 4} all
+        finalize to EXACTLY the single-chip {data: 1, fsdp: 1} world's
+        float32 bits — the per-client compute is never tensor-split
+        (FSDP gathers at use) and the exact expansion fold is
+        placement-independent."""
         base = self._world(args_factory, {"data": 1, "fsdp": 1})
         base_params = jax.tree.map(np.asarray, base.fl_trainer.global_params)
-        for shape in ({"data": 4, "fsdp": 2}, {"data": 8}):
+        for shape in (
+            {"data": 4, "fsdp": 2}, {"data": 8}, {"data": 2, "fsdp": 4}
+        ):
             sim = self._world(args_factory, shape)
             jax.tree.map(
                 lambda a, b: np.testing.assert_array_equal(a, np.asarray(b)),
@@ -208,6 +211,7 @@ class TestFedMesh:
             SimulatorMesh(args, None, dataset, model)
 
 
+@pytest.mark.smoke
 class TestOnMeshAggregation:
     """stream ≡ buffered stays BITWISE on the mesh: the streaming
     fold's order-independence argument holds when the limbs and terms
@@ -327,6 +331,7 @@ class TestOnMeshAggregation:
         )
 
 
+@pytest.mark.smoke
 class TestPlanetOnFedMesh:
     """The registry-backed planet loop's (bucket, nb) group fns shard
     over the fed mesh — mesh and no-mesh worlds train to float

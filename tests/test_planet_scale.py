@@ -400,7 +400,6 @@ def _build_planet(**kw):
 
 
 class TestRegistrySimulation:
-    @pytest.mark.slow  # ~3 full registry trains (jit compiles per shape)
     def test_trains_deterministically_and_tree_equals_flat(self):
         _, _, api = _build_planet()
         stats = api.train()
@@ -420,7 +419,6 @@ class TestRegistrySimulation:
         api3.train()
         assert _max_diff(api.global_params, api3.global_params) == 0.0
 
-    @pytest.mark.slow  # 1M-registry columns + one materialized round
     def test_1m_registry_round_memory_is_o_cohort(self):
         """A 1M-client registry round: columns cost ~22 MB and the
         sample->pack->materialize path for a 1k cohort stays under a
@@ -433,6 +431,13 @@ class TestRegistrySimulation:
         plan = pack_cohort(
             reg.num_samples[idx], idx, 32, speed_tier=reg.speed_tier[idx]
         )
+        # the first materialization pays for the backend's start and
+        # the first compile (~110 MB of RSS when this test runs
+        # alone): the process's cost, not the round's
+        g = plan.groups[0]
+        b, _ = reg.materialize_group(g.client_idx, g.nb, 32, (12,), 10)
+        jax.block_until_ready(b.x)
+        del b
         rss0 = current_rss_bytes()
         for g in plan.groups:
             b, _ = reg.materialize_group(g.client_idx, g.nb, 32, (12,), 10)

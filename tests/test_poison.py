@@ -1,6 +1,6 @@
 """data/poison.py attack synthesis + the loader's poisoned-world wiring.
 
-The defense bench (`bench.py --phase defense`) and docs/robustness.md's
+tests/test_defense.py's poisoned worlds and docs/robustness.md's
 threat model lean on these mechanisms being deterministic and correctly
 labelled/triggered — a poison that silently no-ops would make every
 "defended vs undefended" comparison vacuous.

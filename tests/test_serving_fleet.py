@@ -6,7 +6,6 @@ identity asserted), the CheckpointWatcher sharded restore target
 (drain to live endpoints under delay/kill, counted sheds, SLO door)."""
 
 import threading
-import time
 
 import jax
 import numpy as np
@@ -288,6 +287,33 @@ class TestFleetRouting:
             assert sum(fleet.routed) == 12
             assert fleet.load_skew() <= 2.0
 
+    def test_fleet_wide_swap_reaches_every_endpoint_without_a_retrace(self):
+        """``ServingFleet.hot_swap`` publishes one tree to every
+        endpoint: both answer from the new weights, at the same
+        version, through the executables they already traced."""
+        from fedml_tpu.serving import ServingFleet
+
+        args, model, params = _build(serve_fleet_size=2)
+        xs = [
+            np.random.RandomState(i).randn(8).astype(np.float32)
+            for i in range(8)
+        ]
+        with ServingFleet.build(model, params, args) as fleet:
+            before = [f.result(timeout=30) for f in fleet.submit_burst(xs)]
+            traces = [dict(e.endpoint.trace_counts) for e in fleet.engines]
+            assert all(traces), "an endpoint served nothing before the swap"
+            new = model.init(jax.random.PRNGKey(7))
+            version = fleet.hot_swap(new)
+            after = [f.result(timeout=30) for f in fleet.submit_burst(xs)]
+            assert [e.endpoint.swaps for e in fleet.engines] == [1, 1]
+            assert {e.endpoint.version for e in fleet.engines} == {version}
+            assert traces == [
+                dict(e.endpoint.trace_counts) for e in fleet.engines
+            ]
+        want = np.asarray(model.apply(new, np.stack(xs)))
+        np.testing.assert_allclose(np.stack(after), want, atol=1e-6)
+        assert not np.allclose(np.stack(before), np.stack(after))
+
     def test_static_deal_uses_assign_by_load(self):
         from fedml_tpu.core.scheduler import assign_by_load
         from fedml_tpu.serving import ServingFleet
@@ -322,14 +348,13 @@ class TestFleetRouting:
                 fleet.engines[0].submit(np.zeros(8, np.float32))
                 for _ in range(4)
             ]
-            futs = []
             for _ in range(8):
-                futs.append(fleet.submit(np.zeros(8, np.float32)))
-                time.sleep(0.02)  # let the live engine drain to depth 0
-            assert fleet.routed[1] == 8  # all drained to the live peer
-            assert fleet.routed[0] == 0
+                f = fleet.submit(np.zeros(8, np.float32))
+                assert fleet.routed[0] == 0  # went to the live peer
+                f.result(timeout=30)  # which is back at depth 0
+            assert fleet.routed[1] == 8
             fleet.engines[0].resume()
-            for f in stuck + futs:
+            for f in stuck:
                 f.result(timeout=30)
 
     def test_killed_endpoint_drains_to_live_and_sheds_counted(self):

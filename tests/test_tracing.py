@@ -475,7 +475,6 @@ def _run_cross_silo_world(args_factory, tmp_path, **overrides):
     return server, params
 
 
-@pytest.mark.slow  # two full LOCAL worlds + jit compiles
 class TestCrossSiloWorldTracing:
     def test_world_traces_stitch_and_aggregation_identical_on_off(
         self, tmp_path, args_factory
