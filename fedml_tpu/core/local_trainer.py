@@ -4,13 +4,22 @@ TPU-native replacement for the reference's per-client torch loop
 (``simulation/single_process/fedavg/my_model_trainer_classification.py:18-93``
 — the [HOT LOOP] in SURVEY.md §3.1). Design:
 
-- one ``lax.scan`` over epochs wrapping one ``lax.scan`` over packed
+- one ``lax.scan`` over epochs wrapping one step loop over packed
   batches — a single XLA computation per client round, no Python in the
   loop, params never leave the device (the reference round-trips through
   ``.cpu().state_dict()`` every round);
-- fully-masked (padding) batches are skipped exactly: both params and
-  optimizer state are reverted via ``where``, so padded clients match the
-  reference's ragged iteration bit-for-bit under any optimizer;
+- a step on a fully-masked (padding) batch changes nothing: both params
+  and optimizer state are reverted via ``where``, so padded clients match
+  the reference's ragged iteration bit-for-bit under any optimizer. That
+  revert is the guard; what is *not computed at all* is the tail: handed
+  ``steps`` (an upper bound on the index of the last batch that holds a
+  sample — ``last_real_step`` reads it from the mask; the round engine
+  hands each lane of a ragged cohort its own), the loop ends there, and
+  only an empty batch *before* the bound (a hole in the mask) is still
+  stepped and reverted. Without ``steps`` the loop is a ``lax.scan``
+  over all ``num_batches`` with a static trip count: a client packed to
+  its own length has nothing to skip, and a dynamic loop could only
+  cost it;
 - per-epoch reshuffle over the flattened example axis reproduces
   ``DataLoader(shuffle=True)`` semantics inside jit;
 - the returned function is **vmappable over a leading client axis**
@@ -86,6 +95,19 @@ def _shuffle_batches(b: Batches, rng: jax.Array) -> Batches:
     return rebatch(shuffled, b.num_batches, b.batch_size)
 
 
+def last_real_step(mask: jax.Array) -> jax.Array:
+    """``[..., num_batches, batch_size]`` mask -> int32 ``[...]``: one
+    past the index of the last batch that holds a sample (0 where none
+    does). An upper bound on the steps of every epoch with or without
+    the reshuffle, which compacts the real samples to the head and so
+    only lowers the index: what ``local_train`` takes as ``steps``."""
+    nb = mask.shape[-2]
+    real = mask.sum(axis=-1) > 0
+    return jnp.max(
+        jnp.where(real, jnp.arange(1, nb + 1, dtype=jnp.int32), 0), axis=-1
+    )
+
+
 def make_local_train_fn(
     apply_fn: Callable[[Params, jax.Array], jax.Array],
     loss_fn: Callable[[jax.Array, jax.Array, jax.Array], Tuple[jax.Array, Dict]],
@@ -101,8 +123,15 @@ def make_local_train_fn(
     ``correct`` / ``count`` so callers can weight by true sample count.
     ``apply_fn`` may return ``(logits, counters)`` (``FedModel.
     apply_counted``: a dict of float32 scalars, e.g. an expert layer's
-    token counts); they are summed over the epoch's steps and ride in
-    ``metrics`` under their own names.
+    token counts); they are summed over the steps the epoch ran and ride
+    in ``metrics`` under their own names.
+
+    ``local_train(params, batches, rng, lr_mult=None, steps=None)``:
+    ``steps`` is an int32 scalar, at least ``last_real_step`` of this
+    client's mask. Any such bound gives the parameters of the full loop bit
+    for bit; the metrics are then summed in the loop's carry, step by
+    step (a model's counters over the steps run, so one that counts
+    padding batches too reads less).
 
     Donation contract: the function is pure in its arguments — it never
     aliases ``params`` into its outputs' buffers itself, so the round
@@ -131,7 +160,7 @@ def make_local_train_fn(
         return loss, metrics
 
     def local_train(
-        params: Params, batches: Batches, rng: jax.Array, lr_mult=None
+        params: Params, batches: Batches, rng: jax.Array, lr_mult=None, steps=None
     ):
         global_params = params
         opt_state = optimizer.init(params)
@@ -161,11 +190,9 @@ def make_local_train_fn(
                 )
             return (p, s), metrics
 
-        def epoch(carry, ep_rng):
-            p, s = carry
-            b = _shuffle_batches(batches, ep_rng) if shuffle else batches
-            (p, s), metrics = jax.lax.scan(train_step, (p, s), (b.x, b.y, b.mask))
-            summed = {
+        def summed(metrics):
+            # of one step's metrics, or of a scan's stacked ones
+            return {
                 "loss_sum": (metrics["loss"] * metrics["count"])
                 .sum()
                 .astype(jnp.float32),
@@ -173,7 +200,38 @@ def make_local_train_fn(
                 "count": metrics["count"].sum().astype(jnp.float32),
                 **{k: v.sum().astype(jnp.float32) for k, v in metrics["counters"].items()},
             }
-            return (p, s), summed
+
+        def epoch(carry, ep_rng):
+            b = _shuffle_batches(batches, ep_rng) if shuffle else batches
+            data = (b.x, b.y, b.mask)
+            # THE place that chooses the loop: a static trip count is a
+            # scan over the packed batches, a handed bound a while loop
+            # that indexes them (one step body either way)
+            if steps is None:
+                carry, metrics = jax.lax.scan(train_step, carry, data)
+                return carry, summed(metrics)
+
+            def step(i, loop_carry):
+                state, sums = loop_carry
+                state, m = train_step(
+                    state,
+                    jax.tree.map(
+                        lambda a: jax.lax.dynamic_index_in_dim(a, i, 0, keepdims=False),
+                        data,
+                    ),
+                )
+                return state, jax.tree.map(jnp.add, sums, summed(m))
+
+            # what a step adds, as shapes: the sums start from zero
+            zeros = jax.tree.map(
+                jnp.zeros_like,
+                jax.eval_shape(
+                    lambda c, d: summed(train_step(c, d)[1]),
+                    carry,
+                    jax.tree.map(lambda a: a[0], data),
+                ),
+            )
+            return jax.lax.fori_loop(0, steps, step, (carry, zeros))
 
         ep_rngs = jax.random.split(rng, epochs)
         (params, _), per_epoch = jax.lax.scan(epoch, (params, opt_state), ep_rngs)
@@ -183,6 +241,11 @@ def make_local_train_fn(
     return local_train
 
 
+# what the round engine adds to a round's summed metrics itself
+# (``simulation/fedavg_api.build_round_fn``): no model's counters
+LANE_STEPS = ("steps_run", "steps_packed")
+
+
 def model_counters(summed) -> Dict[str, float]:
     """What a counting model (``FedModel.apply_counted``) added to a round's
     summed training metrics, as host floats: the round's record carries
@@ -190,8 +253,15 @@ def model_counters(summed) -> Dict[str, float]:
     fetched with the loss at an evaluation round."""
     return {
         k: float(v)  # lint: host-sync-ok — the eval-round fetch, with the loss
-        for k, v in summed.items() if k not in ("loss_sum", "correct", "count")
+        for k, v in summed.items()
+        if k not in ("loss_sum", "correct", "count") + LANE_STEPS
     }
+
+
+def lane_steps(summed) -> Dict[str, float]:
+    """The round engine's two counts out of a round's summed metrics, as
+    host floats for the round's record (a sequential round has none)."""
+    return {k: float(summed[k]) for k in LANE_STEPS if k in summed}  # lint: host-sync-ok — with the loss, as above
 
 
 def make_eval_fn(

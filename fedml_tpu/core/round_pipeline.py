@@ -54,7 +54,7 @@ import numpy as np
 # part of this module's public surface before the factor-out
 from .bucketing import bucket_cohort, pad_cohort_idx  # noqa: F401
 from .devtime import measure as _devtime
-from .local_trainer import model_counters
+from .local_trainer import LANE_STEPS, lane_steps, model_counters
 from .tracking import DeferredMetrics
 
 __all__ = ["RoundPipeline", "bucket_cohort", "pad_cohort_idx"]
@@ -187,6 +187,11 @@ class RoundPipeline:
 
         inflight: deque = deque()
         final_stats: Dict[str, float] = {}
+        # every round's (steps_run, steps_packed): device scalars until
+        # an evaluation round's record takes them to the host with it,
+        # where they add up over the call
+        steps_pending: list = []
+        steps_total = np.zeros(2)
         # per-round wall durations: dispatch-to-next-dispatch, finalized
         # when the following round dispatches (a deferred record may be
         # flushed K-1 rounds after its round; "now - t0" there would
@@ -197,7 +202,7 @@ class RoundPipeline:
         ckpt_freq = getattr(api, "_ckpt_freq", 1)
 
         def flush(upto: Optional[int]) -> None:
-            nonlocal final_stats
+            nonlocal final_stats, steps_total
             if not len(self.deferred):
                 return
             with span("flush.fetch"):
@@ -220,6 +225,7 @@ class RoundPipeline:
                         # (K=1's same-iteration flush): legacy
                         # semantics, round start to now
                         dt = time.perf_counter() - t0r
+                    steps_total += np.sum(host["lane_steps"], axis=0)
                     stats = self._stats_from_host(r, host, dt)
                     api.history.append(stats)
                     final_stats = stats
@@ -269,6 +275,7 @@ class RoundPipeline:
                 # brings the queue back to exactly K (depth=1: wait on the
                 # round just dispatched, i.e. fully synchronous)
                 inflight.append(summed["count"])
+                steps_pending.append([summed[k] for k in LANE_STEPS])
                 with span("round.wait"):
                     while len(inflight) >= self.depth:
                         jax.block_until_ready(inflight.popleft())  # lint: host-sync-ok — THE back-pressure sync (depth bound)
@@ -289,8 +296,10 @@ class RoundPipeline:
                         prev_round = round_idx
                         self.deferred.push(
                             round_idx,
-                            {"summed": summed, "train": train_sums, "test": test_sums},
+                            {"summed": summed, "train": train_sums, "test": test_sums,
+                             "lane_steps": steps_pending},
                         )
+                        steps_pending = []
                     # flush every eval round, but only records at least
                     # K-1 rounds old — the fetch never waits on in-flight
                     # compute (K=1: flush this round's record immediately,
@@ -339,6 +348,13 @@ class RoundPipeline:
                 (self.deferred.host_syncs + self._extra_syncs) / n_rounds, 4
             ),
         }
+        # lane-steps the cohort's step loops ran over those its lanes
+        # were packed to, all rounds of this call (1.0: nothing skipped)
+        if steps_total[1]:
+            share = steps_total[0] / steps_total[1]  # host numbers both
+            self.stats["lane_steps_run_share"] = share
+            if tel is not None:
+                tel.set_gauge("pipeline_lane_steps_run_share", share)
         api.pipeline_stats = self.stats
         if tel is not None:
             tel.set_gauge("pipeline_depth", self.depth)
@@ -371,4 +387,5 @@ class RoundPipeline:
             / max(float(summed["count"]), 1.0),  # lint: host-sync-ok
         }
         stats.update(model_counters(summed))
+        stats.update(lane_steps(summed))
         return stats

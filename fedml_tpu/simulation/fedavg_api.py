@@ -22,6 +22,7 @@ round loop; ``fedopt/fedopt_api.py``; ``fednova/fednova_trainer.py:136-165``;
 from __future__ import annotations
 
 import logging
+import math
 import time
 from typing import Any, Dict, List, Optional, Tuple
 
@@ -40,6 +41,8 @@ from ..core.aggregation import (
 )
 from ..core.local_trainer import (
     compute_dtype_from_args,
+    lane_steps,
+    last_real_step,
     make_eval_fn,
     make_local_train_fn,
     model_counters,
@@ -65,6 +68,18 @@ def _take(b: Batches, idx: jax.Array) -> Batches:
     )
 
 
+# multiply-adds a lane's step does at least -- one per parameter and
+# sample: a floor, which a convolution's reuse of its weights or a
+# sequence axis only raises -- from which the lanes of a ragged cohort
+# run one after another (build_round_fn's ``ragged``). On the v5e that
+# halved a round at 8.9e7 (ResNet-18 at batch 8) and at 7.2e8 (at batch
+# 64, the benchmark's cells); at 1.35e7 (a 420k-parameter CNN at batch
+# 32) it won at a bucket of 32 and was measured at no wider one, and at
+# 2.5e5 it lost (PERF.md §6, PR 31). Whatever reads under the constant,
+# a model this floor undercounts too, keeps the static scan. Not a knob
+_HEAVY_LANE_STEP = 5e7
+
+
 def build_round_fn(
     local_train,
     aggregate,
@@ -75,6 +90,7 @@ def build_round_fn(
     keep_stacked: bool = False,
     on_trace=None,
     sample_shape: Optional[Tuple[int, ...]] = None,
+    ragged: bool = False,
 ):
     """THE round engine, as a pure function of its collaborators.
 
@@ -109,10 +125,31 @@ def build_round_fn(
     everything after ``fed.gather`` sees the cohort it always saw. None
     where a sample is one-dimensional and ``packed`` is the dataset's
     own arrays.
+
+    ``ragged``: a fact about the federation, the trainer and the
+    model, read off all three by the caller (``FedAvgAPI.
+    _build_jitted``) -- some client leaves whole batches of the shared
+    ``num_batches`` empty, ``local_train`` is the stock one that takes
+    ``steps`` (``core/local_trainer.py``), and one lane's step is work
+    enough for the chip. Then the cohort is a ``lax.map`` over its
+    lanes in ``idx`` order, each lane's loop ending at its own last
+    real batch (``last_real_step`` of the mask it trains on): batches
+    after that are not stepped, and a padded lane runs no step. What is
+    still stepped and reverted is an empty batch before a lane's last
+    real one. A lane's steps, batches, random stream and place in the
+    stacked outputs are what they were; one executable per bucket.
+    Without ``ragged`` -- or under any mesh, whose lane axis a scan
+    would cut -- the cohort is one vmap over a static scan of all
+    ``num_batches``, as it always lowered.
+
+    The round's summed metrics carry ``steps_run`` and ``steps_packed``
+    (float32) beside the loss: the lane-steps an epoch's loops run, and
+    ``bucket x num_batches``, what the lanes were packed to.
     """
     from ..parallel.layout import is_fed_mesh
 
     fed = mesh is not None and is_fed_mesh(mesh)
+    ragged = ragged and mesh is None
 
     def round_fn(
         global_params, server_state, packed: Batches, nsamples, idx, rng,
@@ -182,15 +219,21 @@ def build_round_fn(
             cohort, server_state = preprocess(cohort, server_state)
         rngs = jax.random.split(rng, idx.shape[0])
         with jax.named_scope("fed.local_train"):
-            if use_round_lr:
+            if not ragged:
                 # round-indexed LR: one multiplier for the whole cohort
+                extra = (lr_mult,) if use_round_lr else ()
                 new_stacked, train_metrics = jax.vmap(
-                    local_train, in_axes=(None, 0, 0, None)
-                )(train_params, cohort, rngs, lr_mult)
+                    local_train, in_axes=(None, 0, 0) + (None,) * len(extra)
+                )(train_params, cohort, rngs, *extra)
+                steps_run = idx.shape[0] * cohort.num_batches
             else:
-                new_stacked, train_metrics = jax.vmap(
-                    local_train, in_axes=(None, 0, 0)
-                )(train_params, cohort, rngs)
+                lr = lr_mult if use_round_lr else None
+                steps = last_real_step(cohort.mask)
+                steps_run = steps.sum()
+                new_stacked, train_metrics = jax.lax.map(
+                    lambda lane: local_train(train_params, lane[0], lane[1], lr, lane[2]),
+                    (cohort, rngs, steps),
+                )
         if fed:
             from ..parallel.layout import pin_cohort_outputs
 
@@ -210,6 +253,8 @@ def build_round_fn(
                 # hops at any cohort size
                 new_global = constrain_tree(new_global, mesh)
             summed = {k: v.sum() for k, v in train_metrics.items()}
+            summed["steps_run"] = jnp.asarray(steps_run, jnp.float32)
+            summed["steps_packed"] = jnp.float32(idx.shape[0] * cohort.num_batches)
         if keep_stacked:
             return new_global, new_state, summed, new_stacked
         return new_global, new_state, summed
@@ -228,19 +273,7 @@ def build_eval_all(eval_fn):
     return eval_all
 
 
-@auditable(
-    "simulation.round_fn",
-    donate=(0, 1),
-    round_shaped=True,
-    census_budget=lambda ctx: pow2_budget(ctx.cohort_buckets),
-)
-def _audit_round_fn_cases(ctx):
-    """`fedml-tpu audit` provider: the EXACT round engine the runtime
-    jits (same builder, same donation), lowered across the pow2 cohort
-    census against ShapeDtypeStruct trees — no dataset, no params,
-    nothing executed. The donation checker verifies the (0, 1)
-    aliasing contract the round pipeline's K-in-flight chaining rides
-    on; the host-transfer checker proves the hot loop is device-pure."""
+def _audit_cases(ctx, ragged: bool):
     from ..analysis.compiled import LoweringCase
 
     params = ctx.abstract_params()
@@ -251,7 +284,7 @@ def _audit_round_fn_cases(ctx):
         return weighted_average(stacked, weights), server_state
 
     fn = jax.jit(
-        build_round_fn(ctx.local_train_fn(), aggregate),
+        build_round_fn(ctx.local_train_fn(), aggregate, ragged=ragged),
         donate_argnums=(0, 1),
     )
     n_total = max(ctx.cohort_buckets) * 2
@@ -269,6 +302,35 @@ def _audit_round_fn_cases(ctx):
         )
         for b in ctx.cohort_buckets
     ]
+
+
+@auditable(
+    "simulation.round_fn",
+    donate=(0, 1),
+    round_shaped=True,
+    census_budget=lambda ctx: pow2_budget(ctx.cohort_buckets),
+)
+def _audit_round_fn_cases(ctx):
+    """`fedml-tpu audit` provider: the EXACT round engine the runtime
+    jits (same builder, same donation), lowered across the pow2 cohort
+    census against ShapeDtypeStruct trees — no dataset, no params,
+    nothing executed. The donation checker verifies the (0, 1)
+    aliasing contract the round pipeline's K-in-flight chaining rides
+    on; the host-transfer checker proves the hot loop is device-pure."""
+    return _audit_cases(ctx, ragged=False)
+
+
+@auditable(
+    "simulation.round_fn_ragged",
+    donate=(0, 1),
+    round_shaped=True,
+    census_budget=lambda ctx: pow2_budget(ctx.cohort_buckets),
+)
+def _audit_round_fn_ragged_cases(ctx):
+    """The same engine as a ragged federation of a heavy model runs it
+    (``ragged``: a ``lax.map`` over the lanes, each step loop ending at
+    a bound read from the mask), under the same two checkers."""
+    return _audit_cases(ctx, ragged=True)
 
 
 @auditable(
@@ -588,6 +650,14 @@ class FedAvgAPI:
                     "jit.retrace", cat="compile", bucket=int(idx.shape[0])
                 )
 
+        # read off the dataset, the trainer and the model where
+        # sample_shape is read off the dataset: no flag, no model's name
+        self._ragged = (
+            self.mesh is None
+            and self.client_trainer is None
+            and self._has_empty_batches()
+            and self._lane_step_floor() >= _HEAVY_LANE_STEP
+        )
         round_fn = build_round_fn(
             self._local_train,
             self._aggregate,
@@ -597,6 +667,7 @@ class FedAvgAPI:
             keep_stacked=self._keep_stacked,
             on_trace=on_trace,
             sample_shape=sample_store.sample_shape(self.dataset.packed_train),
+            ragged=self._ragged,
         )
         self._round_fn = jax.jit(round_fn, donate_argnums=(0, 1))
         # donation deliberately NOT safe here: the sequential loop
@@ -608,15 +679,30 @@ class FedAvgAPI:
         self._eval_all = jax.jit(build_eval_all(self._eval))
         self._eval_global = jax.jit(self._eval)
 
+    def _has_empty_batches(self) -> bool:
+        """Does some client leave a whole batch of the packed
+        ``num_batches`` empty? Read off the dataset's host counts; a
+        federation packed to its clients' own length says no."""
+        ns = self.dataset.packed_num_samples
+        if ns is None or not len(ns):
+            return False
+        packed = self.dataset.packed_train
+        fewest = int(np.ceil(np.min(ns) / packed.batch_size))  # lint: host-sync-ok — host counts
+        return fewest < packed.num_batches
+
+    def _lane_step_floor(self) -> int:
+        """Multiply-adds one lane's step does at least: a parameter
+        meets every sample of the batch once or more."""
+        weights = sum(math.prod(a.shape) for a in jax.tree.leaves(self.global_params))
+        return weights * self.dataset.packed_train.batch_size
+
     def _round_exec_name(self) -> str:
         """Registry name of the round executable this api dispatches —
         the ``executable`` tag on its ``exec_device_seconds`` series,
         matched against audit_report.json by ``fedml-tpu perf``."""
-        return (
-            "simulation.round_fn_mesh"
-            if self.mesh is not None
-            else "simulation.round_fn"
-        )
+        if self.mesh is not None:
+            return "simulation.round_fn_mesh"
+        return "simulation.round_fn_ragged" if self._ragged else "simulation.round_fn"
 
     def _post_round_stacked(self, stacked: Params, idx: np.ndarray, rng) -> None:
         """Host-side hook fed the per-client cohort params when
@@ -823,6 +909,7 @@ class FedAvgAPI:
                             float(summed["count"]), 1.0  # lint: host-sync-ok — same eval-round fetch
                         )
                         stats.update(model_counters(summed))
+                        stats.update(lane_steps(summed))
                         self.history.append(stats)
                         final_stats = stats
                         self.metrics_reporter.report_server_training_metric(stats)

@@ -319,6 +319,7 @@ class TestRepoAtHead:
         reg = load_registry()
         assert {
             "simulation.round_fn",
+            "simulation.round_fn_ragged",
             "simulation.round_fn_mesh",
             "planet.group_fn",
             "serving.forward",
@@ -330,6 +331,7 @@ class TestRepoAtHead:
         # the round/fold/group executables CLAIM donation; the auditor
         # holds them to it (test below proves the claims verify)
         assert reg["simulation.round_fn"].donate == (0, 1)
+        assert reg["simulation.round_fn_ragged"].donate == (0, 1)
         assert reg["simulation.round_fn_mesh"].donate == (0, 1)
         assert reg["planet.group_fn"].donate == (0,)
         assert reg["agg.fold_tree"].donate == (0,)
@@ -358,9 +360,8 @@ class TestRepoAtHead:
         by_name = {}
         for e in report["executables"]:
             by_name.setdefault(e["executable"], []).append(e)
-        assert len(by_name["simulation.round_fn"]) == len(
-            AuditContext().cohort_buckets
-        )
+        for name in ("simulation.round_fn", "simulation.round_fn_ragged"):
+            assert len(by_name[name]) == len(AuditContext().cohort_buckets)
         for e in report["executables"]:
             assert e["flops"] is not None and e["flops"] > 0
             assert e["bytes_accessed"] is not None
@@ -369,6 +370,7 @@ class TestRepoAtHead:
         # grandfathered anymore
         for e in (
             by_name["simulation.round_fn"]
+            + by_name["simulation.round_fn_ragged"]
             + by_name["simulation.round_fn_mesh"]
             + by_name["planet.group_fn"]
             + by_name["agg.fold_tree"]
@@ -398,6 +400,7 @@ class TestRepoAtHead:
         assert main(["--only", "planet.group_fn"]) == 0
         assert main(["--only", "agg.weighted_term"]) == 0
         assert main(["--only", "simulation.round_fn_mesh"]) == 0
+        assert main(["--only", "simulation.round_fn_ragged"]) == 0
 
     @pytest.mark.slow  # subprocess pays interpreter + jax startup
     def test_cli_audit_ci_exits_zero_at_head(self, tmp_path):

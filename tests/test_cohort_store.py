@@ -20,7 +20,7 @@ import fedml_tpu
 from fedml_tpu import models
 from fedml_tpu.core import sample_store
 from fedml_tpu.data import load
-from fedml_tpu.simulation import FedAvgAPI
+from fedml_tpu.simulation import FedAvgAPI, fedavg_api
 from fedml_tpu.simulation.fedavg_api import FedOptAPI, build_round_fn
 from tests.conftest import make_args
 
@@ -46,12 +46,15 @@ def _staged(api):
 
 # -- the same round, bit for bit -----------------------------------------
 
-@pytest.mark.parametrize("cls, data, model, valid", [
+WORLDS = pytest.mark.parametrize("cls, data, model, valid", [
     (FedAvgAPI, IMAGES, "cnn", None),
     (FedAvgAPI, IMAGES, "lr", (1.0, 1.0, 1.0, 0.0)),
     (FedOptAPI, IMAGES, "lr", (1.0, 1.0, 1.0, 0.0)),
     (FedAvgAPI, FLAT, "lr", (1.0, 1.0, 1.0, 0.0)),
 ], ids=["images", "images_padded_lanes", "images_padded_lanes_fedopt", "flat"])
+
+
+@WORLDS
 def test_round_through_store_equals_round_through_take(cls, data, model, valid):
     api = _world(cls, model=model, server_optimizer="adam", **data)
     packed = api.dataset.packed_train
@@ -69,6 +72,39 @@ def test_round_through_store_equals_round_through_take(cls, data, model, valid):
     assert bool(jax.tree.leaves(want[1])) == (cls is FedOptAPI)  # a server state to compare
     for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
         assert np.array_equal(np.asarray(a), np.asarray(b))
+
+
+@WORLDS
+def test_ragged_round_through_store_equals_static_round_through_take(
+        cls, data, model, valid, monkeypatch):
+    """The same worlds with their lane-step counted heavy (ISSUE 31):
+    lanes one after another, each to its last real batch, through the
+    store, against the plain static round above. Every lane's trained
+    parameters bit for bit; what is summed over lanes (the global
+    parameters, the server's moments, the metrics) within float32
+    rounding of the sum's order."""
+    monkeypatch.setattr(fedavg_api, "_HEAVY_LANE_STEP", 0)
+    api = _world(type("Stacked", (cls,), {"_keep_stacked": True}),
+                 model=model, server_optimizer="adam", **data)
+    assert api._ragged
+    plain = jax.jit(build_round_fn(
+        api._local_train, api._aggregate, api._preprocess, sample_shape=None,
+        keep_stacked=True))
+    call = (jnp.asarray(api.dataset.packed_num_samples),
+            jnp.asarray([4, 1, 5, 1], jnp.int32), jax.random.PRNGKey(7))
+    kwargs = {} if valid is None else {"valid": jnp.asarray(valid)}
+    copy = lambda t: jax.tree.map(jnp.copy, t)  # noqa: E731  (_round_fn donates)
+    want = plain(copy(api.global_params), copy(api.server_state), api.dataset.packed_train,
+                 *call, **kwargs)
+    got = api._round_fn(
+        copy(api.global_params), copy(api.server_state), api._sample_store(), *call, **kwargs)
+    assert jax.tree.structure(got) == jax.tree.structure(want)
+    for a, b in zip(jax.tree.leaves(got[3]), jax.tree.leaves(want[3])):
+        assert np.array_equal(np.asarray(a), np.asarray(b))
+    run, packed_to = float(got[2].pop("steps_run")), float(want[2].pop("steps_run"))
+    assert run <= packed_to and (valid is None or run < packed_to)  # a padded lane runs no step
+    for a, b in zip(jax.tree.leaves(got[:3]), jax.tree.leaves(want[:3])):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-6, atol=1e-7)
 
 
 def test_identity_branch_is_the_datasets_own_arrays():
