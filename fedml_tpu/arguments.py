@@ -443,13 +443,18 @@ _DEFAULTS: Dict[str, Any] = {
     "num_experts": 8,  # Switch MoE expert count
     "capacity_factor": 1.25,  # MoE per-expert token capacity slack
     # model: moe_decoder (models/decoder.py): RMSNorm, grouped-KV rotary
-    # attention, window and full layers, routed gated-SiLU experts
+    # attention (window and full layers) or a gated short convolution as
+    # a layer's operator, a dense gated-SiLU MLP on the leading layers
+    # and routed gated-SiLU experts after them, a tied or untied head
     "hidden_size": 256,  # moe_decoder model width
     "num_kv_heads": 2,  # moe_decoder KV heads (num_heads a multiple)
     "head_dim": 64,  # moe_decoder head width (not tied to hidden_size / num_heads)
     # moe_decoder layer pattern, one entry per layer: "sliding_attention"
-    # | "full_attention" (None = num_layers full layers)
+    # | "full_attention" | "conv" (None = num_layers full layers)
     "layer_types": None,
+    "conv_L_cache": 3,  # moe_decoder: taps of a conv layer's causal depthwise filter
+    "num_dense_layers": 0,  # moe_decoder: leading layers whose feed-forward is a dense MLP
+    "intermediate_size": 0,  # moe_decoder: that dense MLP's width
     "sliding_window": 1024,  # moe_decoder: keys a sliding layer sees, itself included
     # moe_decoder rotary parameters per layer type, as a published
     # config.json has them: {layer type: {rope_type: default | yarn,
@@ -460,6 +465,12 @@ _DEFAULTS: Dict[str, Any] = {
     "experts_per_token": 2,  # moe_decoder: experts a token is routed to (top-k)
     "expert_dim": 128,  # moe_decoder: width of one expert's gated MLP
     "norm_topk_prob": True,  # moe_decoder: renormalise the top-k routing weights
+    "norm_topk_eps": 0.0,  # moe_decoder: added to the top-k weights' sum before dividing by it
+    "router_scoring": "softmax",  # moe_decoder: "softmax" | "sigmoid" over all experts, float32
+    # moe_decoder: a float32 leaf added to the scores for the top-k choice
+    # only (the weights stay the unbiased scores: its gradient is zero)
+    "use_expert_bias": False,
+    "tie_word_embeddings": False,  # moe_decoder: the head is the embedding's rows
     "rms_norm_eps": 1e-6,  # moe_decoder RMSNorm epsilon
     # moe_decoder: the chips that share each expert layer, and which of
     # them this is: the layer holds num_experts / expert_parallel

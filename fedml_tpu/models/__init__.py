@@ -256,6 +256,13 @@ def create(args, output_dim: int) -> FedModel:
                 rms_norm_eps=float(getattr(args, "rms_norm_eps", 1e-6)),
                 attention=getattr(args, "attention_impl", "full"),
                 remat=bool(getattr(args, "remat", False)),
+                num_dense_layers=int(getattr(args, "num_dense_layers", 0) or 0),
+                intermediate_size=int(getattr(args, "intermediate_size", 0) or 0),
+                conv_L_cache=int(getattr(args, "conv_L_cache", 3)),
+                router_scoring=getattr(args, "router_scoring", "softmax"),
+                use_expert_bias=bool(getattr(args, "use_expert_bias", False)),
+                norm_topk_eps=float(getattr(args, "norm_topk_eps", 0.0)),
+                tie_word_embeddings=bool(getattr(args, "tie_word_embeddings", False)),
             ),
             task="nwp",
             example_shape=(seq_len,),

@@ -8,7 +8,26 @@ a rotary embedding whose parameters depend on the layer's *type*
 (``sliding_attention`` layers attend a causal window with the default
 rotary table, ``full_attention`` layers attend everything before them
 and may carry YaRN), and a routed gated-SiLU expert layer in place of
-every MLP. All sizes are arguments; nothing is a model's name.
+the MLP. A layer's *operator* may also be a gated short convolution
+(``conv``, below) and its feed-forward a dense gated-SiLU MLP on the
+first ``num_dense_layers`` layers; the head may be tied to the
+embedding. All sizes are arguments; nothing is a model's name.
+
+**The gated short convolution** (``layer_types`` entry ``conv``): ``B,
+C, u = split3(in_proj(h))``; ``z_t = sum_j w_j * (B * u)_{t - (L - 1)
++ j}`` over ``L = conv_L_cache`` taps, one filter a channel, causal
+(zeros before the sequence), no activation; ``out_proj(C * z)``. The
+taps are ``L`` shifted multiply-adds in the compute type; it carries no
+state past the sequence and has no rotary table.
+
+**The router** scores all experts in float32 with ``softmax`` or
+``sigmoid``. With ``use_expert_bias`` a float32 leaf ``expert_bias``
+[num_experts] is added to the scores *for the choice only*: the weights
+are the chosen experts' unbiased scores, so the bias's gradient is
+exactly zero and SGD leaves it as it was drawn. The top-k weights are
+divided by their sum plus ``norm_topk_eps`` (``norm_topk_prob``). (A
+published ``routed_scaling_factor`` other than 1 has no argument here
+yet: no configuration run so far sets one.)
 
 **The expert layer holds a share.** ``experts_held = (first, count)``
 (``parallel/expert.py``) says which of the ``num_experts`` experts live
@@ -37,13 +56,22 @@ v5e, PR 28). Inside that lane loop JAX's name stack starts anew: its
 operations carry ``moe.route`` / ``moe.experts`` / ``moe.combine`` and
 not the scopes around the model (``fed.local_train``).
 
+Where the cohort's lanes run one after another instead
+(``build_round_fn``'s ``ragged``) nothing is vmapped, the loop is not
+entered and the expert layer's operations keep the scopes around them.
+
 Scopes (HLO op metadata; ``benchmark/layer_metrics`` reads them from
 device traces): ``lm.embed``, ``blk.attn.window``, ``blk.attn.full``,
-``moe.route``, ``moe.experts``, ``moe.combine``, ``lm.head_loss`` (the
-head here, the loss in ``core/losses.py``). Counters (collection
-``counters``, summed over layers; ``FedModel.apply_counted``):
-``moe_local_hits``, ``moe_expert_tokens_max``,
-``moe_expert_tokens_mean``, ``moe_dropped``.
+``blk.conv`` (norm, ``in_proj``, gates, taps, ``out_proj``),
+``blk.mlp.dense``, ``moe.route``, ``moe.experts``, ``moe.combine``,
+``lm.head_loss`` (the head here -- a tied head's product with the
+embedding's transpose too -- and the loss in ``core/losses.py``).
+Counters (collection ``counters``, summed over layers;
+``FedModel.apply_counted``): ``moe_local_hits``,
+``moe_expert_tokens_max``, ``moe_expert_tokens_mean``, ``moe_dropped``
+and, on a layer with a selection bias, ``moe_bias_moved``: the
+token-choices the bias changed against the unbiased top-k (a layer
+without a bias sows none, and its program is what it was).
 """
 
 from __future__ import annotations
@@ -59,7 +87,7 @@ import numpy as np
 from flax.core import freeze
 from jax.custom_batching import sequential_vmap
 
-SLIDING, FULL = "sliding_attention", "full_attention"
+SLIDING, FULL, CONV = "sliding_attention", "full_attention", "conv"
 _NEG_INF = -1e30
 
 
@@ -190,6 +218,36 @@ class Attention(nn.Module):
         return _proj(x.shape[-1], "o_proj")(o.reshape(B, T, H * D))
 
 
+class GatedShortConv(nn.Module):
+    """The operator of a ``conv`` layer (module docstring), [B, T, C] ->
+    [B, T, C]: ``conv_kernel`` [taps, C] holds one causal filter a
+    channel, tap ``taps - 1`` on the token itself."""
+
+    taps: int
+
+    @nn.compact
+    def __call__(self, x):
+        T, C = x.shape[1:]
+        gate_in, gate_out, u = jnp.split(_proj(3 * C, "in_proj")(x), 3, axis=-1)
+        w = self.param("conv_kernel", nn.initializers.lecun_normal(), (self.taps, C)).astype(x.dtype)
+        # zeros before the sequence; tap j reads token t - (taps - 1) + j
+        padded = jnp.pad(gate_in * u, ((0, 0), (self.taps - 1, 0), (0, 0)))
+        z = sum(w[j] * padded[:, j:j + T] for j in range(self.taps))
+        return _proj(C, "out_proj")(gate_out * z)
+
+
+class GatedMLP(nn.Module):
+    """The dense feed-forward of a leading layer: ``down(silu(gate x) *
+    up x)``."""
+
+    width: int
+
+    @nn.compact
+    def __call__(self, x):
+        h = jax.nn.silu(_proj(self.width, "gate_proj")(x)) * _proj(self.width, "up_proj")(x)
+        return _proj(x.shape[-1], "down_proj")(h)
+
+
 # -- the expert layer -----------------------------------------------------
 
 def _gated_silu(xs, wg, wu, wd, sizes):
@@ -315,6 +373,9 @@ class HeldExperts(nn.Module):
     expert_dim: int
     experts_held: Tuple[int, int]  # (first, count)
     norm_topk_prob: bool = True
+    scoring: str = "softmax"  # | "sigmoid"
+    use_expert_bias: bool = False
+    norm_topk_eps: float = 0.0
 
     @nn.compact
     def __call__(self, x):
@@ -323,6 +384,8 @@ class HeldExperts(nn.Module):
         first, held = self.experts_held
         if not (0 <= first and held > 0 and first + held <= E):
             raise ValueError(f"experts_held {self.experts_held} is no share of {E} experts")
+        if self.scoring not in ("softmax", "sigmoid"):
+            raise ValueError(f"router scoring {self.scoring!r}: 'softmax' or 'sigmoid'")
         init = nn.initializers.lecun_normal(batch_axis=(0,))
         wg = self.param("gate_proj", init, (held, C, self.expert_dim))
         wu = self.param("up_proj", init, (held, C, self.expert_dim))
@@ -333,10 +396,23 @@ class HeldExperts(nn.Module):
             # are cast back up; the scores decide a discrete choice)
             logits = nn.Dense(E, use_bias=False, name="router", dtype=jnp.float32)(
                 xf.astype(jnp.float32))
-            probs = jax.nn.softmax(logits, axis=-1)
-            weight, expert = jax.lax.top_k(probs, K)  # [N, K]
+            scores = jax.nn.softmax(logits, axis=-1) if self.scoring == "softmax" else jax.nn.sigmoid(logits)
+            moved = None
+            if self.use_expert_bias:
+                # the bias enters the choice only: the weights are the
+                # chosen experts' unbiased scores (its gradient is 0)
+                bias = self.param("expert_bias", nn.initializers.zeros, (E,)).astype(jnp.float32)
+                _, expert = jax.lax.top_k(scores + bias, K)
+                weight = jnp.take_along_axis(scores, expert, axis=-1)
+                # a choice the unbiased top-k would not have made: K or
+                # more experts score above it
+                above = jnp.sum(scores[:, None, :] > weight[:, :, None], axis=-1)
+                moved = jnp.sum(above >= K)
+            else:
+                weight, expert = jax.lax.top_k(scores, K)  # [N, K]
             if self.norm_topk_prob:
-                weight = weight / jnp.sum(weight, axis=-1, keepdims=True)
+                total = jnp.sum(weight, axis=-1, keepdims=True)
+                weight = weight / (total + self.norm_topk_eps if self.norm_topk_eps else total)
             here = (expert >= first) & (expert < first + held)
             # absent experts sort last, as one group past the held ones
             local = jnp.where(here, expert - first, held).reshape(N * K)
@@ -356,13 +432,16 @@ class HeldExperts(nn.Module):
         rows = min(room, -(-5 * N * K * held // (4 * E)))
         y, done = held_experts(rows, xf, tok, sorted_weight, sizes, wg, wu, wd)
         f32 = lambda v: jnp.asarray(v, jnp.float32)
-        for name, value in (
-            ("moe_local_hits", hits), ("moe_expert_tokens_max", jnp.max(sizes)),
-            ("moe_expert_tokens_mean", f32(hits) / held),
+        counted = {
+            "moe_local_hits": hits, "moe_expert_tokens_max": jnp.max(sizes),
+            "moe_expert_tokens_mean": f32(hits) / held,
             # choices on held experts less the rows the chunk loop gave
             # the grouped product: 0 while the loop walks the whole list
-            ("moe_dropped", f32(hits) - jax.lax.stop_gradient(done)),
-        ):
+            "moe_dropped": f32(hits) - jax.lax.stop_gradient(done),
+        }
+        if moved is not None:
+            counted["moe_bias_moved"] = moved
+        for name, value in counted.items():
             self.sow("counters", name, f32(value), reduce_fn=jnp.add, init_fn=lambda: f32(0))
         return y.astype(x.dtype).reshape(B, T, C)
 
@@ -370,35 +449,50 @@ class HeldExperts(nn.Module):
 # -- block and model ------------------------------------------------------
 
 class DecoderBlock(nn.Module):
+    """``x + operator(norm(x))`` then ``x + ffn(norm(x))``. The operator
+    is attention (``kind`` sliding or full) or the gated short
+    convolution (``conv``); the feed-forward the routed experts
+    (``experts``: ``HeldExperts``' fields) or, with ``experts`` None, a
+    dense gated MLP of ``intermediate_size``."""
+
+    kind: str
     num_heads: int
     num_kv_heads: int
     head_dim: int
     window: Optional[int]
     attention: str
     eps: float
-    num_experts: int
-    experts_per_token: int
-    expert_dim: int
-    experts_held: Tuple[int, int]
-    norm_topk_prob: bool
+    conv_taps: int
+    intermediate_size: int
+    experts: Optional[Any]  # HeldExperts' fields (a frozen dict), or None: a dense MLP
 
     @nn.compact
     def __call__(self, x, cos, sin):
-        with jax.named_scope("blk.attn.window" if self.window is not None else "blk.attn.full"):
-            x = x + Attention(
-                self.num_heads, self.num_kv_heads, self.head_dim, self.window,
-                self.attention, self.eps, name="attn",
-            )(RMSNorm(self.eps, name="attn_norm")(x), cos, sin)
-        return x + HeldExperts(
-            self.num_experts, self.experts_per_token, self.expert_dim,
-            self.experts_held, self.norm_topk_prob, name="moe",
-        )(RMSNorm(self.eps, name="ffn_norm")(x))
+        if self.kind == CONV:
+            with jax.named_scope("blk.conv"):
+                x = x + GatedShortConv(self.conv_taps, name="conv")(
+                    RMSNorm(self.eps, name="conv_norm")(x))
+        else:
+            with jax.named_scope("blk.attn.window" if self.window is not None else "blk.attn.full"):
+                x = x + Attention(
+                    self.num_heads, self.num_kv_heads, self.head_dim, self.window,
+                    self.attention, self.eps, name="attn",
+                )(RMSNorm(self.eps, name="attn_norm")(x), cos, sin)
+        h = RMSNorm(self.eps, name="ffn_norm")(x)
+        if self.experts is not None:
+            return x + HeldExperts(**self.experts, name="moe")(h)
+        with jax.named_scope("blk.mlp.dense"):
+            return x + GatedMLP(self.intermediate_size, name="mlp")(h)
 
 
 class MoEDecoderLM(nn.Module):
     """Causal LM over ``vocab_size`` rows (a slice of a larger
     vocabulary is a smaller vocabulary: ids, logits and loss are over
-    it): tokens [B, T] -> float32 logits [B, T, vocab_size]."""
+    it): tokens [B, T] -> float32 logits [B, T, vocab_size]. The first
+    ``num_dense_layers`` layers carry a dense MLP of
+    ``intermediate_size``, every other one the routed experts; with
+    ``tie_word_embeddings`` the head is the embedding's rows (one leaf,
+    the gradients of both uses summed)."""
 
     vocab_size: int
     hidden_size: int
@@ -416,29 +510,46 @@ class MoEDecoderLM(nn.Module):
     rms_norm_eps: float = 1e-6
     attention: str = "full"
     remat: bool = False
+    num_dense_layers: int = 0
+    intermediate_size: int = 0
+    conv_L_cache: int = 3
+    router_scoring: str = "softmax"
+    use_expert_bias: bool = False
+    norm_topk_eps: float = 0.0
+    tie_word_embeddings: bool = False
 
     @nn.compact
     def __call__(self, tokens, train: bool = False):
         T = tokens.shape[1]
+        embed = nn.Embed(self.vocab_size, self.hidden_size, name="embed")
         with jax.named_scope("lm.embed"):
-            x = nn.Embed(self.vocab_size, self.hidden_size, name="embed")(tokens.astype(jnp.int32))
-        # one table per layer type, made once
+            x = embed(tokens.astype(jnp.int32))
+        for kind in self.layer_types:
+            if kind not in (SLIDING, FULL, CONV):
+                raise ValueError(f"layer type {kind!r}: {SLIDING!r}, {FULL!r} or {CONV!r}")
+        # one table per attention layer type, made once
         tables = {
             kind: rope_tables(T, self.head_dim, dict(self.rope_parameters[kind]))
-            for kind in dict.fromkeys(self.layer_types)
+            for kind in dict.fromkeys(self.layer_types) if kind != CONV
         }
+        experts = freeze(dict(
+            num_experts=self.num_experts, experts_per_token=self.experts_per_token,
+            expert_dim=self.expert_dim, experts_held=tuple(self.experts_held),
+            norm_topk_prob=self.norm_topk_prob, scoring=self.router_scoring,
+            use_expert_bias=self.use_expert_bias, norm_topk_eps=self.norm_topk_eps,
+        ))
         block = nn.remat(DecoderBlock) if self.remat else DecoderBlock
         for i, kind in enumerate(self.layer_types):
-            if kind not in (SLIDING, FULL):
-                raise ValueError(f"layer type {kind!r}: {SLIDING!r} or {FULL!r}")
             x = block(
-                self.num_heads, self.num_kv_heads, self.head_dim,
+                kind, self.num_heads, self.num_kv_heads, self.head_dim,
                 self.sliding_window if kind == SLIDING else None, self.attention,
-                self.rms_norm_eps, self.num_experts, self.experts_per_token, self.expert_dim,
-                tuple(self.experts_held), self.norm_topk_prob, name=f"layer_{i}",
-            )(x, *tables[kind])
+                self.rms_norm_eps, self.conv_L_cache, self.intermediate_size,
+                experts if i >= self.num_dense_layers else None, name=f"layer_{i}",
+            )(x, *tables.get(kind, (None, None)))
         with jax.named_scope("lm.head_loss"):
             x = RMSNorm(self.rms_norm_eps, name="final_norm")(x)
+            if self.tie_word_embeddings:
+                return embed.attend(x).astype(jnp.float32)
             return _proj(self.vocab_size, "lm_head")(x).astype(jnp.float32)
 
 

@@ -110,9 +110,9 @@ def test_kernel_is_named_in_the_tpu_lowering(kernel):
     text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).trace(s, s, s).lower(
         lowering_platforms=("tpu",)).as_text()
     assert kernel in set(re.findall(r"flash_attention\w*", text))
-    read = json.loads(_read(
-        "benchmark", "workloads", "c2_t4096_b4_eval10.json"))["kernel_names"]
-    assert set(read) <= set(KERNELS)
+    for traffic in ("c2_t4096_b4_eval10.json", "c2_t4096_b2_eval10.json"):
+        read = json.loads(_read("benchmark", "workloads", traffic))["kernel_names"]
+        assert set(read) <= set(KERNELS)
 
 
 @pytest.mark.parametrize("cell", CELLS)
@@ -121,6 +121,21 @@ def test_every_cell_is_described_where_readers_look(cell):
     perf = _read("PERF.md")
     cells_section = perf[perf.index("## 4. Cells"):perf.index("## 5.")]
     assert f"`{cell}`" in cells_section
+
+
+def test_the_lane_after_lane_familys_tiny_cell_runs_on_the_cpu():
+    """``benchmark/tests/`` is outside this suite's command (PERF.md
+    section 7, ask 10), so the rehearsal of the ``fedavg_lm_lanes``
+    family's whole run -- set-up, window, release, the comparison with
+    its plain reference -- is started from here, in a process of its
+    own (the benchmark's tests bring their own ``conftest.py``)."""
+    r = subprocess.run(
+        [sys.executable, "-m", "pytest", "benchmark/tests/test_fedavg_lfm2.py::test_end_to_end_line",
+         "-q", "-p", "no:cacheprovider", "-p", "no:xdist", "-p", "no:randomly"],
+        cwd=REPO, capture_output=True, text=True, timeout=900,
+        env={**os.environ, "JAX_PLATFORMS": "cpu"},
+    )
+    assert r.returncode == 0 and "1 passed" in r.stdout, (r.stdout[-1500:], r.stderr[-800:])
 
 
 def test_every_test_the_ci_scripts_name_exists():

@@ -7,9 +7,17 @@ dense masked attention, a loop over experts, no kernel, no vmap).
 Small sizes on the CPU: hidden 64, 4 query / 2 KV heads of 16, 8 experts
 top-2 of width 32, window 8, T 32, vocabulary 64, layers S S S F. What a
 scope or a kernel *costs* is the chip's to say.
+
+A second configuration of the same block goes through the cases whose
+assertion is the same (``case``): gated short-convolution layers beside
+one full-attention layer (C F C C C), a dense leading layer of 96, a
+sigmoid router with a selection bias, a tied head, a federation that
+leaves whole batches empty -- against its own plain reference
+(``benchmark/reference/fedavg_lfm2.py``).
 """
 
 import argparse
+import functools
 import importlib.util
 import math
 import os
@@ -18,12 +26,13 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from flax.core import freeze
 
 import fedml_tpu
 from fedml_tpu import data, models
 from fedml_tpu.arguments import Arguments
 from fedml_tpu.models.decoder import (
-    FULL, SLIDING, HeldExperts, dense_attention, rope_inv_freq,
+    CONV, FULL, SLIDING, DecoderBlock, GatedShortConv, HeldExperts, dense_attention, rope_inv_freq,
 )
 from fedml_tpu.ops.flash_attention import flash_attention
 from fedml_tpu.parallel.expert import ep_specs, experts_held
@@ -39,6 +48,7 @@ def _load(path, name):
 
 
 ref = _load("benchmark/reference/fedavg_mellum2.py", "ref_fedavg_mellum2")
+ref_conv = _load("benchmark/reference/fedavg_lfm2.py", "ref_fedavg_lfm2")
 
 ROPE = {
     FULL: {"rope_type": "yarn", "rope_theta": 500000, "factor": 16,
@@ -67,9 +77,71 @@ def _args(**over):
     return argparse.Namespace(**flat)
 
 
+# the second configuration: conv layers, a dense leading layer, a biased
+# sigmoid router, a tied head
+MODEL_CONV = {
+    "vocab_size": 64, "hidden_size": 64, "head_dim": 16, "num_attention_heads": 4,
+    "num_key_value_heads": 2, "layer_types": [CONV, FULL, CONV, CONV, CONV], "num_dense_layers": 1,
+    "intermediate_size": 96, "conv_L_cache": 3, "rope_theta": 1e6, "num_experts": 8,
+    "num_experts_per_tok": 2, "moe_intermediate_size": 32, "norm_topk_prob": True,
+    "use_expert_bias": True, "expert_bias_std": 0.05, "routed_scaling_factor": 1.0, "norm_eps": 1e-5,
+    "experts_held": [4, 4],
+}
+
+
+def _args_conv(**over):
+    flat = dict(
+        model="moe_decoder", dataset="token_stream", vocab_size=64, seq_len=T, hidden_size=64,
+        num_heads=4, num_kv_heads=2, head_dim=16, num_experts=8, experts_per_token=2,
+        expert_dim=32, layer_types=list(MODEL_CONV["layer_types"]), num_dense_layers=1,
+        intermediate_size=96, conv_L_cache=3,
+        rope_parameters={FULL: {"rope_type": "default", "rope_theta": 1e6}}, router_scoring="sigmoid",
+        use_expert_bias=True, norm_topk_eps=1e-6, rms_norm_eps=1e-5,
+        tie_word_embeddings=True, expert_parallel=2, expert_rank=1, attention_impl="full",
+    )
+    flat.update(over)
+    return argparse.Namespace(**flat)
+
+
+class Case:
+    """One configuration of the block with its plain reference; the
+    seeded weights are drawn on first use."""
+
+    def __init__(self, ref, model, args, faults, scopes, counters):
+        self.ref, self.model, self.args = ref, model, args
+        self.faults, self.scopes, self.counters = faults, scopes, counters
+
+    @functools.cached_property
+    def weights(self):
+        return self.ref.init_params(7, self.model)
+
+
+LM_SCOPES = ("lm.embed", "moe.route", "moe.experts", "moe.combine", "lm.head_loss")
+MOE_COUNTERS = ("moe_local_hits", "moe_expert_tokens_max", "moe_expert_tokens_mean", "moe_dropped")
+CASES = {
+    "window_softmax": Case(
+        ref, MODEL, _args, ("no_window", "no_renorm", "no_yarn"),
+        LM_SCOPES + ("blk.attn.window", "blk.attn.full"), MOE_COUNTERS),
+    "conv_sigmoid": Case(
+        ref_conv, MODEL_CONV, _args_conv,
+        ("no_bias", "acausal_conv", "no_c_gate", "no_renorm", "dense_width"),
+        LM_SCOPES + ("blk.attn.full", "blk.conv", "blk.mlp.dense"), MOE_COUNTERS + ("moe_bias_moved",)),
+}
+
+
+@pytest.fixture(scope="module", params=list(CASES))
+def case(request):
+    return CASES[request.param]
+
+
 @pytest.fixture(scope="module")
 def weights():
-    return ref.init_params(7, MODEL)
+    return CASES["window_softmax"].weights
+
+
+@pytest.fixture(scope="module")
+def weights_conv():
+    return CASES["conv_sigmoid"].weights
 
 
 @pytest.fixture(scope="module")
@@ -83,26 +155,38 @@ def _close(a, b, tol):
 
 
 # -- the model against the reference -----------------------------------
-def test_parameter_tree_is_the_references(weights):
-    m = models.create(_args(), 64)
+def test_parameter_tree_is_the_references(case):
+    m = models.create(case.args(), 64)
     have = jax.tree.map(lambda a: (a.shape, str(a.dtype)), m.init(jax.random.PRNGKey(0)))
-    want = jax.tree.map(lambda a: (a.shape, str(a.dtype)), weights)
+    want = jax.tree.map(lambda a: (a.shape, str(a.dtype)), case.weights)
     assert have == want
 
 
+def test_the_softmax_configuration_grew_no_leaf_and_no_counter(weights, tokens):
+    """What the second configuration added is off by default: no
+    ``expert_bias`` leaf, no ``mlp``, an untied head, and the four
+    counters there were."""
+    m = models.create(_args(), 64)
+    tree = m.init(jax.random.PRNGKey(0))
+    assert set(tree) == {"embed", "final_norm", "lm_head"} | {f"layer_{i}" for i in range(4)}
+    assert all(set(tree[f"layer_{i}"]) == {"attn_norm", "attn", "ffn_norm", "moe"} for i in range(4))
+    assert set(tree["layer_0"]["moe"]) == {"router", "gate_proj", "up_proj", "down_proj"}
+    assert set(m.apply_counted(weights, tokens[:, :-1])[1]) == set(MOE_COUNTERS)
+
+
 @pytest.mark.parametrize("remat", [False, True])
-def test_forward_matches_reference(weights, tokens, remat):
-    m = models.create(_args(remat=remat), 64)
+def test_forward_matches_reference(case, tokens, remat):
+    m = models.create(case.args(remat=remat), 64)
     with jax.default_matmul_precision("highest"):
-        got = m.apply(weights, tokens[:, :-1])
-        want = jnp.stack([ref.forward(weights, t[:-1], MODEL) for t in tokens])
+        got = m.apply(case.weights, tokens[:, :-1])
+        want = jnp.stack([case.ref.forward(case.weights, t[:-1], case.model) for t in tokens])
     assert got.dtype == jnp.float32 and got.shape == (3, T, 64)
     assert _close(got, want, 2e-5)
 
 
 @pytest.mark.parametrize("remat", [False, True])
-def test_gradients_match_reference(weights, tokens, remat):
-    m = models.create(_args(remat=remat), 64)
+def test_gradients_match_reference(case, tokens, remat):
+    m = models.create(case.args(remat=remat), 64)
     x, y = tokens[:, :-1], tokens[:, 1:]
 
     def prog(p):
@@ -110,35 +194,43 @@ def test_gradients_match_reference(weights, tokens, remat):
         return -jnp.take_along_axis(logp, y[..., None], axis=-1).sum()
 
     def plain(p):
-        return sum(ref._sequence_loss_sum(p, a, b, MODEL, None, None) for a, b in zip(x, y))
+        return sum(case.ref._sequence_loss_sum(p, a, b, case.model, None, None) for a, b in zip(x, y))
 
     with jax.default_matmul_precision("highest"):
-        (lp, gp), (lr, gr) = jax.value_and_grad(prog)(weights), jax.value_and_grad(plain)(weights)
+        (lp, gp), (lr, gr) = jax.value_and_grad(prog)(case.weights), jax.value_and_grad(plain)(case.weights)
     assert abs(float(lp) - float(lr)) <= 1e-5 * abs(float(lr))
     bad = [jax.tree_util.keystr(k) for (k, a), b in zip(
         jax.tree_util.tree_leaves_with_path(gp), jax.tree.leaves(gr)) if not _close(a, b, 2e-4)]
     assert not bad, bad
 
 
-@pytest.mark.parametrize("fault", ["no_window", "no_renorm", "no_yarn"])
-def test_each_planted_fault_moves_the_reference(weights, tokens, fault):
+@pytest.mark.parametrize("name,fault", [(n, f) for n in CASES for f in CASES[n].faults])
+def test_each_planted_fault_moves_the_reference(tokens, name, fault):
     """What the benchmark's limits have to catch is not a no-op at this
     size: the reference with a fault planted disagrees with itself."""
+    c = CASES[name]
     with jax.default_matmul_precision("highest"):
-        good = ref.forward(weights, tokens[0, :-1], MODEL)
-        bad = ref.forward(weights, tokens[0, :-1], MODEL, fault=fault)
+        good = c.ref.forward(c.weights, tokens[0, :-1], c.model)
+        bad = c.ref.forward(c.weights, tokens[0, :-1], c.model, fault=fault)
     assert not _close(bad, good, 1e-3)
 
 
-def test_flash_path_matches_dense_path(weights):
+def test_flash_path_matches_dense_path(case):
     """attention_impl flash (the interpreter here) and full agree on a
     sequence the kernel can tile; a window wider than T is every key."""
     toks = jax.random.randint(jax.random.PRNGKey(3), (2, 128), 0, 64)
     over = dict(seq_len=128, sliding_window=40)
     with jax.default_matmul_precision("highest"):
-        dense = models.create(_args(**over), 64).apply(weights, toks)
-        flash = models.create(_args(attention_impl="flash", **over), 64).apply(weights, toks)
+        dense = models.create(case.args(**over), 64).apply(case.weights, toks)
+        flash = models.create(case.args(attention_impl="flash", **over), 64).apply(case.weights, toks)
     assert _close(flash, dense, 2e-5)
+
+
+def test_unknown_layer_kind_and_scoring_are_refused(weights_conv, tokens):
+    with pytest.raises(ValueError, match="layer type"):
+        models.create(_args_conv(layer_types=["conv", "recurrent"]), 64).init(jax.random.PRNGKey(0))
+    with pytest.raises(ValueError, match="scoring"):
+        models.create(_args_conv(router_scoring="tanh"), 64).init(jax.random.PRNGKey(0))
 
 
 def test_flash_refuses_a_sequence_it_cannot_tile(weights, tokens):
@@ -167,6 +259,14 @@ def test_flash_window_gqa_forward(h, kv, window, t, block):
     assert _close(got, dense_attention(q, k, v, window), 1e-5)
 
 
+def test_flash_forward_32_query_8_kv_heads_of_64():
+    """The second configuration's attention layer: a group of 4 at half
+    a lane tile's head width."""
+    q, k, v, _ = _qkv(32, 8, 256, b=1, d=64)
+    got = flash_attention(q, k, v, True, None, 128, 128, None)
+    assert _close(got, dense_attention(q, k, v, None), 1e-5)
+
+
 # the backward's cases: the forward's (window 1 left out: it sees only
 # itself, every gradient of q and k is exactly 0) at D 16 in float32, then
 # a group of 8 on one KV head, a window that no tile divides, a window
@@ -177,6 +277,8 @@ BACKWARD_CASES = [c + (16, jnp.float32) for c in FLASH_CASES[:-1]] + [
     (4, 2, 512, 384, 128, 16, jnp.float32), (2, 2, None, 384, 128, 64, jnp.float32),
     (4, 2, 100, 384, 128, 128, jnp.float32), (8, 1, 100, 384, 128, 64, jnp.bfloat16),
     (4, 2, None, 256, 256, 128, jnp.bfloat16),
+    # 32 query heads on 8 KV heads of 64, no window: the second configuration's layer
+    (32, 8, None, 256, 128, 64, jnp.float32), (32, 8, None, 256, 128, 64, jnp.bfloat16),
 ]  # (T 384 walks 3 x 3 tiles of 128, T 2,048 4 x 4 of 512, T 256 and 512 one)
 
 
@@ -419,6 +521,110 @@ def test_vmapped_lanes_carry_their_own_experts(weights, tokens):
         assert {k: float(v[lane]) for k, v in counters.items()} == {k: float(v) for k, v in c.items()}
 
 
+# -- the gated short convolution ----------------------------------------
+def _conv_params(seed=0, c=16, taps=3):
+    ks = jax.random.split(jax.random.PRNGKey(seed), 3)
+    return {"in_proj": {"kernel": jax.random.normal(ks[0], (c, 3 * c)) / 4},
+            "conv_kernel": jax.random.normal(ks[1], (taps, c)),
+            "out_proj": {"kernel": jax.random.normal(ks[2], (c, c)) / 4}}
+
+
+@pytest.mark.parametrize("taps", [3, 4, 1])
+def test_gated_short_conv_against_a_per_token_loop(taps):
+    """``B, C, u = split3(in_proj(h))``; ``z_t = sum_j w_j (B u)_{t-(L-1)+j}``
+    over ``L`` taps, zeros before the sequence; ``out_proj(C z)``: token
+    by token in numpy, and the reference's shifted slices. Three taps
+    are the configurations'; the filter's length is an argument, so
+    another length (and a single tap: no neighbour read) is held to the
+    same loop."""
+    p = _conv_params(taps=taps)
+    x = jax.random.normal(jax.random.PRNGKey(4), (2, 12, 16))
+    with jax.default_matmul_precision("highest"):
+        got = GatedShortConv(taps).apply({"params": p}, x)
+        plain = jnp.stack([ref_conv._conv_op(s, p, None, None) for s in x])
+    w_in, w, w_out = (np.asarray(a, np.float64) for a in (
+        p["in_proj"]["kernel"], p["conv_kernel"], p["out_proj"]["kernel"]))
+    want = np.zeros((2, 12, 16))
+    for b in range(2):
+        bcu = np.asarray(x[b], np.float64) @ w_in
+        gate_in, gate_out, u = bcu[:, :16], bcu[:, 16:32], bcu[:, 32:]
+        for t in range(12):
+            back = range(t - (taps - 1), t + 1)  # the tokens the taps sit on, the last on t itself
+            z = sum(w[j] * gate_in[i] * u[i] for j, i in enumerate(back) if i >= 0)
+            want[b, t] = (gate_out[t] * z) @ w_out
+    assert _close(got, want, 1e-5) and _close(plain, want, 1e-5)
+
+
+def test_gated_short_conv_is_causal():
+    """Token t's output does not move when token t + 1 does -- and the
+    reference's acausal fault does move it."""
+    p = _conv_params(1)
+    x = jax.random.normal(jax.random.PRNGKey(5), (1, 10, 16))
+    moved = x.at[0, 6].add(1.0)
+    a, b = (GatedShortConv(3).apply({"params": p}, v)[0] for v in (x, moved))
+    assert np.array_equal(np.asarray(a[:6]), np.asarray(b[:6])) and not _close(a[6:9], b[6:9], 1e-3)
+    assert _close(a[9:], b[9:], 1e-6)  # three taps reach two tokens back
+    fa, fb = (ref_conv._conv_op(v[0], p, None, "acausal_conv") for v in (x, moved))
+    assert not _close(fa[5], fb[5], 1e-3)
+
+
+# -- the selection bias ---------------------------------------------------
+def _biased_layer(first=0, held=8, **kw):
+    return HeldExperts(num_experts=8, experts_per_token=2, expert_dim=32, experts_held=(first, held),
+                       scoring="sigmoid", use_expert_bias=True, norm_topk_eps=1e-6, **kw)
+
+
+def test_selection_bias_has_zero_gradient_and_changes_the_choices(weights_conv):
+    """The bias enters the choice only: its gradient is exactly zero
+    (nothing stops it: it is a leaf like any other), the weights are the
+    unbiased scores, and leaving it out picks other experts."""
+    p = dict(weights_conv["layer_1"]["moe"])
+    x = jax.random.normal(jax.random.PRNGKey(5), (2, T, 64))
+    layer = _biased_layer(4, 4)
+    g = jax.grad(lambda q: jnp.sum(layer.apply({"params": q}, x) ** 2))(p)
+    assert g["expert_bias"].shape == (8,) and not np.any(np.asarray(g["expert_bias"]))
+    assert float(jnp.abs(g["router"]["kernel"]).max()) > 0
+    y, state = layer.apply({"params": p}, x, mutable=["counters"])
+    moved = float(state["counters"]["moe_bias_moved"])
+    assert 0 < moved < 2 * 2 * T  # some of the 128 tokens' two choices, not all
+    flat = dict(p, expert_bias=jnp.zeros(8))
+    y0, state0 = layer.apply({"params": flat}, x, mutable=["counters"])
+    assert float(state0["counters"]["moe_bias_moved"]) == 0.0 and not _close(y0, y, 1e-3)
+    # the count is the choices that differ from the unbiased top-k's
+    score = jax.nn.sigmoid(x.reshape(-1, 64) @ p["router"]["kernel"])
+    with_bias = np.asarray(jax.lax.top_k(score + p["expert_bias"], 2)[1])
+    without = np.asarray(jax.lax.top_k(score, 2)[1])
+    assert moved == sum(len(set(a) - set(b)) for a, b in zip(with_bias, without))
+    with jax.default_matmul_precision("highest"):
+        want = ref_conv._experts(x[0], p, MODEL_CONV, None, None)
+        unbiased = ref_conv._experts(x[0], p, MODEL_CONV, None, "no_bias")
+    assert _close(y[0], want, 2e-5) and _close(y0[0], unbiased, 2e-5)
+
+
+@pytest.mark.parametrize("ep", [1, 2, 4])
+def test_the_shares_of_a_conv_layer_add_up(ep):
+    """The second configuration's layer, uncut: the operator and the
+    residual counted once, the partial outputs of all ``ep`` shares of
+    the biased sigmoid experts on top give the reference's whole layer."""
+    whole = dict(MODEL_CONV, layer_types=[CONV], num_dense_layers=0, experts_held=[0, 8])
+    p = ref_conv.init_params(3, whole)["layer_0"]
+    x = jax.random.normal(jax.random.PRNGKey(9), (1, T, 64))
+    with jax.default_matmul_precision("highest"):
+        mid = x[0] + ref_conv._conv_op(ref_conv._rms(x[0], p["conv_norm"]["scale"], 1e-5), p["conv"], None, None)
+        want = mid + ref_conv._experts(ref_conv._rms(mid, p["ffn_norm"]["scale"], 1e-5), p["moe"], whole, None, None)
+        got = mid
+        for r in range(ep):
+            first, held = experts_held(8, ep, r)
+            experts = freeze(dict(
+                num_experts=8, experts_per_token=2, expert_dim=32, experts_held=(first, held),
+                norm_topk_prob=True, scoring="sigmoid", use_expert_bias=True, norm_topk_eps=1e-6))
+            block = DecoderBlock(CONV, 4, 2, 16, None, "full", 1e-5, 3, 0, experts)
+            cut = lambda a: a[first:first + held]
+            share = dict(p, moe=dict(p["moe"], **{k: cut(p["moe"][k]) for k in ("gate_proj", "up_proj", "down_proj")}))
+            got = got + (block.apply({"params": share}, x, None, None)[0] - mid)
+    assert _close(got, want, 2e-5)
+
+
 # -- data: a stated vocabulary -----------------------------------------
 def test_token_data_at_a_real_vocabulary():
     """PERF.md section 7's open item: a dense [V, V] chain is 1.2 GB at
@@ -439,8 +645,8 @@ def test_token_data_at_a_real_vocabulary():
     assert max(len(v) for v in follows.values()) <= 16
 
 
-def _fed_args(**over):
-    flat = dict(vars(_args(
+def _fed_args(args=_args, **over):
+    flat = dict(vars(args(
         client_num_in_total=4, client_num_per_round=2, comm_round=1, epochs=1, batch_size=2,
         learning_rate=0.05, client_optimizer="sgd", federated_optimizer="FedAvg",
         partition_method="homo", synthetic_train_size=16, synthetic_test_size=8, shuffle=False,
@@ -469,57 +675,80 @@ def test_sliced_vocabulary_draws_scores_and_loses_over_the_slice(weights):
 
 # -- one federated round through the normal path -----------------------
 @pytest.fixture(scope="module")
-def one_round(weights):
-    from fedml_tpu.simulation.fedavg_api import FedAvgAPI
+def one_round(case):
+    """``window_softmax``: 4 silos of 4 sequences, the vmapped static
+    scan. ``conv_sigmoid``: 10 sequences over 4 silos (3, 3, 2, 2) at
+    batch 2, so two silos leave the second batch empty and -- with the
+    engine's floor on a lane step's work lowered for this tiny model --
+    the cohort runs lane after lane (``lax.map``)."""
+    from fedml_tpu.simulation import fedavg_api
 
-    args = _fed_args()
+    ragged = case is CASES["conv_sigmoid"]
+    args = _fed_args(case.args, **({"synthetic_train_size": 10} if ragged else {}))
     ds = data.load(args)
-    api = FedAvgAPI(args, None, ds, models.create(args, ds.class_num))
-    api.global_params = jax.tree.map(jnp.copy, weights)
+    heavy = fedavg_api._HEAVY_LANE_STEP
+    fedavg_api._HEAVY_LANE_STEP = 0 if ragged else heavy
+    try:
+        api = fedavg_api.FedAvgAPI(args, None, ds, models.create(args, ds.class_num))
+    finally:
+        fedavg_api._HEAVY_LANE_STEP = heavy
+    assert api._round_exec_name() == ("simulation.round_fn_ragged" if ragged else "simulation.round_fn")
+    api.global_params = jax.tree.map(jnp.copy, case.weights)
     api.train()
     return args, ds, api
 
 
-def test_one_round_matches_the_references_round(weights, one_round):
+def test_one_round_matches_the_references_round(case, one_round):
     args, ds, api = one_round
     packed = (ds.packed_train.x, ds.packed_train.y, ds.packed_train.mask)
-    cohort = ref.sample_cohort(0, 4, 2)
+    cohort = case.ref.sample_cohort(0, 4, 2)
     with jax.default_matmul_precision("highest"):
-        want, loss = ref.fedavg_round(
-            weights, packed, ds.packed_num_samples, cohort, MODEL, {"lr": 0.05, "epochs": 1})
-        test_loss = ref.evaluate(
-            want, (ds.packed_test.x, ds.packed_test.y, ds.packed_test.mask), MODEL)
+        want, loss = case.ref.fedavg_round(
+            case.weights, packed, ds.packed_num_samples, cohort, case.model, {"lr": 0.05, "epochs": 1})
+        test_loss = case.ref.evaluate(
+            want, (ds.packed_test.x, ds.packed_test.y, ds.packed_test.mask), case.model)
     rec = api.history[-1]
     assert abs(rec["train_loss_cohort"] - loss) <= 1e-5 * loss
     assert abs(rec["test_loss"] - test_loss) <= 1e-5 * test_loss
     moved = [float(jnp.linalg.norm(a - b)) for a, b in zip(
-        jax.tree.leaves(want), jax.tree.leaves(weights))]
+        jax.tree.leaves(want), jax.tree.leaves(case.weights))]
     gap = [float(jnp.linalg.norm(a - b)) for a, b in zip(
         jax.tree.leaves(api.global_params), jax.tree.leaves(want))]
-    assert max(g / max(m, 1e-12) for g, m in zip(gap, moved)) < 2e-3
+    # a leaf that no gradient reaches (the selection bias) moved by nothing at all
+    assert max(g / m if m else g for g, m in zip(gap, moved)) < 2e-3
 
 
-def test_the_rounds_record_carries_the_counters(one_round):
+def test_the_rounds_record_carries_the_counters(case, one_round):
     """Fetched with the round's other metrics: token-choices on held
-    experts, the fullest expert, the mean, and nothing dropped."""
-    _, _, api = one_round
+    experts, the fullest expert, the mean, nothing dropped -- and the
+    lane-steps the engine ran of those it packed."""
+    _, ds, api = one_round
     rec = api.history[-1]
-    # 2 clients x 2 steps x 4 layers, 2 sequences of 32 tokens a step
-    calls, n = 2 * 2 * 4, 2 * T
+    assert set(case.counters) | {"steps_run", "steps_packed"} <= set(rec)
+    sparse = len(case.model["layer_types"]) - case.model.get("num_dense_layers", 0)
+    # 2 sequences of 32 tokens a step, 2 choices a token, over the steps run and the sparse layers
+    calls, n = rec["steps_run"] * sparse, 2 * T
     assert rec["moe_dropped"] == 0.0
     assert 0 < rec["moe_local_hits"] <= calls * n * 2
     assert rec["moe_expert_tokens_mean"] == pytest.approx(rec["moe_local_hits"] / 4)
     assert rec["moe_expert_tokens_mean"] <= rec["moe_expert_tokens_max"] <= calls * n
+    assert rec["steps_packed"] == 2 * ds.packed_train.mask.shape[1]
+    if case is CASES["conv_sigmoid"]:
+        # silos 0 and 1 hold 3 sequences (2 steps), 2 and 3 hold 2 (1 step of their 2)
+        cohort = case.ref.sample_cohort(0, 4, 2)
+        assert rec["steps_run"] == sum(2 if c < 2 else 1 for c in cohort) < rec["steps_packed"] + (
+            1 if all(c < 2 for c in cohort) else 0)
+        assert 0 < rec["moe_bias_moved"] < rec["steps_run"] * sparse * n * 2
+    else:
+        assert rec["steps_run"] == rec["steps_packed"] and "moe_bias_moved" not in rec
 
 
-SCOPES = ("lm.embed", "blk.attn.window", "blk.attn.full", "moe.route", "moe.experts",
-          "moe.combine", "lm.head_loss")
-
-
-def test_scopes_name_the_round_executables_parts(one_round):
+def test_scopes_name_the_round_executables_parts(case, one_round):
     """Every scope the per-layer readers look for is a component of
     some op_name in the lowered round executable and in the
-    evaluation's, inside ``fed.local_train`` where it trains."""
+    evaluation's, inside ``fed.local_train`` where it trains. Lane after
+    lane nothing is vmapped, so the expert layer's operations keep
+    ``fed.local_train`` around their own scope."""
     args, ds, api = one_round
     packed = ds.packed_train
     idx = jnp.asarray([0, 1], jnp.int32)
@@ -527,8 +756,15 @@ def test_scopes_name_the_round_executables_parts(one_round):
         api.global_params, api.server_state, packed, jnp.asarray(ds.packed_num_samples, jnp.float32),
         idx, jax.random.PRNGKey(0))
     text = lowered.as_text(debug_info=True)
-    for scope in SCOPES:
+    for scope in case.scopes:
         assert f"fed.local_train/" in text and f"/{scope}/" in text, scope
+    if case is CASES["conv_sigmoid"]:
+        import re
+
+        # composed names are the compiled executable's (the lowered text nests its locations)
+        names = re.findall(r'op_name="([^"]*moe\.experts[^"]*)"', lowered.compile().as_text())
+        inside = [n for n in names if "fed.local_train" in n]
+        assert len(inside) > 0.9 * len(names) > 0, (len(inside), len(names))
     ev = api._eval_all.lower(api.global_params, packed).as_text(debug_info=True)
-    for scope in SCOPES:
+    for scope in case.scopes:
         assert f"/{scope}/" in ev, scope
