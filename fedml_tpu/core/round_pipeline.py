@@ -174,6 +174,9 @@ class RoundPipeline:
             idx_plan, lr_plan, key_plan, head_plan = self.precompute(
                 start_round, comm_rounds
             )
+            # what an evaluation reads: the packed splits less the
+            # clients' padding (made by train() before this call)
+            eval_train, eval_test = api._eval_splits()
 
         # telemetry (core/telemetry.py): every instrument below is a
         # host-side counter bump / ring append — the hot loop gains no
@@ -286,12 +289,8 @@ class RoundPipeline:
 
                 if round_idx % freq == 0 or round_idx == comm_rounds - 1:
                     with span("eval"):
-                        train_sums = api._eval_all(
-                            api.global_params, api.dataset.packed_train
-                        )
-                        test_sums = api._eval_all(
-                            api.global_params, api.dataset.packed_test
-                        )
+                        train_sums = api._eval_all(api.global_params, eval_train)
+                        test_sums = api._eval_all(api.global_params, eval_test)
                         t_dispatch[round_idx] = t0
                         prev_round = round_idx
                         self.deferred.push(

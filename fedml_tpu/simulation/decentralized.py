@@ -104,6 +104,7 @@ class DecentralizedDSGDAPI(FedAvgAPI):
     def train(self) -> Dict[str, float]:
         args = self.args
         packed = self.dataset.packed_train
+        self._eval_splits()  # staged before the first round, not inside it
         freq = max(1, int(getattr(args, "frequency_of_the_test", 5)))
         final_stats: Dict[str, float] = {}
         for round_idx in range(int(args.comm_round)):
@@ -159,6 +160,7 @@ class DecentralizedPushSumAPI(DecentralizedDSGDAPI):
     def train(self) -> Dict[str, float]:
         args = self.args
         packed = self.dataset.packed_train
+        self._eval_splits()  # staged before the first round, not inside it
         freq = max(1, int(getattr(args, "frequency_of_the_test", 5)))
         final_stats: Dict[str, float] = {}
         for round_idx in range(int(args.comm_round)):

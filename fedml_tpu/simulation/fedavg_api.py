@@ -263,14 +263,71 @@ def build_round_fn(
 
 
 def build_eval_all(eval_fn):
-    """vmap-over-clients eval reduction, module-level for the same
-    no-self-closure reason as :func:`build_round_fn`."""
+    """vmap-over-lanes eval reduction, module-level for the same
+    no-self-closure reason as :func:`build_round_fn`: ``eval_fn``'s scan
+    over ``num_batches`` on every lane of the leading axis at once, the
+    lanes' sums added up. Its outputs are sums over all samples, so the
+    leading axis need not be the clients: the evaluation hands it
+    :func:`dense_eval_split`'s arrays, not the per-client packing,
+    whose ``num_batches`` is the largest client's."""
 
     def eval_all(params, packed: Batches):
         sums = jax.vmap(eval_fn, in_axes=(None, 0))(params, packed)
         return jax.tree.map(lambda x: x.sum(), sums)
 
     return eval_all
+
+
+@jax.jit
+def real_slots(*masks: jax.Array) -> jax.Array:
+    """How many slots of each ``[L, nb, bs]`` mask hold a sample, as one
+    int32 vector (one fetch for all of them)."""
+    return jnp.stack([jnp.sum(m != 0, dtype=jnp.int32) for m in masks])
+
+
+def dense_eval_split(packed: Batches, n_real: int) -> Tuple[Batches, Dict[str, int]]:
+    """``(split, facts)``: what an evaluation reads of ``packed``, a
+    ``[L, nb, bs, ...]`` split of which ``n_real`` slots hold a sample.
+
+    Packed per client, every client has the largest one's
+    ``num_batches`` and the evaluation runs the forward pass of all the
+    slots that only pad (half of them in a Dirichlet(0.5) federation).
+    An evaluation sums over all samples, so nothing in it depends on
+    which client a sample sits with: the real slots move to the head --
+    the three leading axes flattened, a stable order by ``1 - mask`` as
+    ``_shuffle_batches`` has it, the first ``L x nb' x bs`` kept -- and
+    the split is ``[L, nb', bs, ...]`` with ``nb' = max(1, ceil(n_real
+    / (L x bs)))``: the same lanes, the same batch size, fewer batches.
+    One jitted pass over global arrays that returns global arrays (a
+    mesh-placed split keeps its placement: the leading axis has its old
+    length). Where ``nb' == nb`` the split is ``packed`` itself, no
+    copy. ``facts`` are the ``eval.staged`` instant's counts."""
+    lanes, nb, bs = packed.mask.shape
+    dense_nb = min(nb, max(1, -(-n_real // (lanes * bs))))
+    facts = {"nb": nb, "nb_dense": dense_nb, "real": n_real,
+             "slots": lanes * dense_nb * bs, "bytes": 0}
+    if dense_nb == nb:
+        return packed, facts
+    keep = lanes * dense_nb * bs
+
+    def to_head(b: Batches) -> Batches:
+        order = jnp.argsort(1 - b.mask.reshape(-1), stable=True)[:keep]
+
+        def move(a: jax.Array) -> jax.Array:
+            # a slot is one row (half the v5e's temporaries of a gather
+            # of image-shaped slots: a sandbox compile, PR 33)
+            rows = jnp.take(a.reshape((lanes * nb * bs, -1)), order, axis=0)
+            return rows.reshape((lanes, dense_nb, bs) + a.shape[3:])
+
+        return jax.tree.map(move, b)
+
+    from jax.sharding import NamedSharding
+
+    placed = [getattr(a, "sharding", None) for a in (packed.x, packed.y, packed.mask)]
+    on_mesh = all(isinstance(s, NamedSharding) for s in placed)
+    dense = jax.jit(to_head, out_shardings=Batches(*placed) if on_mesh else None)(packed)
+    facts["bytes"] = sum(a.nbytes for a in jax.tree.leaves(dense))
+    return dense, facts
 
 
 def _audit_cases(ctx, ragged: bool):
@@ -494,6 +551,11 @@ class FedAvgAPI:
         # object, so that dropping the API frees the store's copy too
         self._store: Optional[Tuple[jax.Array, Batches]] = None
         self._store_stagings = 0
+        # (packed_train.x, packed_test.x they were made from, the two
+        # splits an evaluation reads): held and freed the same way;
+        # samples over slots in them
+        self._eval_held: Optional[Tuple[jax.Array, jax.Array, Tuple[Batches, Batches]]] = None
+        self._eval_real_share: Optional[float] = None
 
         self.rng = jax.random.PRNGKey(int(getattr(args, "random_seed", 0)))
         self.rng, init_rng = jax.random.split(self.rng)
@@ -724,6 +786,35 @@ class FedAvgAPI:
                 self.telemetry.recorder.instant("store.staged", cat="data", **facts)
         return self._store[1]
 
+    def _eval_splits(self) -> Tuple[Batches, Batches]:
+        """What an evaluation reads, training split and held-out split:
+        the dataset's packed splits without the clients' padding
+        (:func:`dense_eval_split`; the packed split itself where it has
+        none to lose), made once per ``(packed_train.x, packed_test.x)``
+        from the masks alone and before a loop's first round. Every
+        loop's evaluation takes them from here."""
+        train, test = self.dataset.packed_train, self.dataset.packed_test
+        held = self._eval_held
+        if held is None or held[0] is not train.x or held[1] is not test.x:
+            self._eval_held = None  # the old copies go before the new ones come
+            counts = np.asarray(real_slots(train.mask, test.mask)).tolist()  # lint: host-sync-ok — one fetch at set-up, outside the round loop
+            (tr, tr_facts), (te, te_facts) = (
+                dense_eval_split(split, n) for split, n in zip((train, test), counts)
+            )
+            share = self._eval_real_share = (tr_facts["real"] + te_facts["real"]) / (
+                tr_facts["slots"] + te_facts["slots"])
+            self._eval_held = (train.x, test.x, (tr, te))
+            if self.telemetry.enabled:
+                self.telemetry.set_gauge("pipeline_eval_real_share", share)
+                self.telemetry.recorder.instant(
+                    "eval.staged", cat="data",
+                    bytes=tr_facts["bytes"] + te_facts["bytes"], real_share=share,
+                    **{f"{name}_{k}": facts[k]
+                       for name, facts in (("train", tr_facts), ("test", te_facts))
+                       for k in ("nb", "nb_dense", "real")},
+                )
+        return self._eval_held[2]
+
     # -- reference-parity sampling ------------------------------------
     def _client_sampling(
         self, round_idx: int, client_num_in_total: int, client_num_per_round: int
@@ -760,6 +851,7 @@ class FedAvgAPI:
                 if self._multi_controller
                 else jnp.asarray(self.dataset.packed_num_samples)
             )
+            self._eval_splits()
         comm_rounds = int(args.comm_round)
         freq = max(1, int(getattr(args, "frequency_of_the_test", 5)))
         ckpt, start_round = self._maybe_restore()
@@ -792,6 +884,9 @@ class FedAvgAPI:
                     packed, nsamples, comm_rounds, freq, ckpt, start_round
                 )
             self.pipeline_stats["store_stagings"] = self._store_stagings
+            if self._eval_real_share is not None:
+                # samples over slots of one evaluation, both splits
+                self.pipeline_stats["eval_real_share"] = self._eval_real_share
             return stats
         finally:
             if ckpt is not None:
@@ -1111,8 +1206,13 @@ class FedAvgAPI:
 
     # -- evaluation (fedavg_api.py:238 _local_test_on_all_clients) ----
     def _local_test_on_all_clients(self, round_idx: int) -> Dict[str, float]:
-        train_sums = self._eval_all(self.global_params, self.dataset.packed_train)
-        test_sums = self._eval_all(self.global_params, self.dataset.packed_test)
+        """Loss and accuracy over every client's training samples and
+        over every client's held-out ones: sums over samples, so read
+        from :meth:`_eval_splits` (the packed splits less the clients'
+        padding), not from the per-client packing."""
+        train, test = self._eval_splits()
+        train_sums = self._eval_all(self.global_params, train)
+        test_sums = self._eval_all(self.global_params, test)
         tr = self.model.metrics_from_sums(train_sums)
         te = self.model.metrics_from_sums(test_sums)
         return {

@@ -48,6 +48,7 @@ class HierarchicalFLAPI(FedAvgAPI):
     def train(self) -> Dict[str, float]:
         args = self.args
         packed = self._sample_store()
+        self._eval_splits()
         nsamples = jnp.asarray(self.dataset.packed_num_samples)
         groups = self._groups()
         group_rounds = int(getattr(args, "group_comm_round", 1))

@@ -69,23 +69,31 @@ def test_executables_lower_under_the_names_the_trace_readers_look_up(
 ):
     """``round_fn_device_ms`` and ``eval_device_ms`` find their
     executable by its module name; lowered with the arguments
-    ``train()`` itself passes."""
+    ``train()`` itself passes: the sample store, and for the evaluation
+    the accessor's split (hetero clients: shorter than the packing)."""
     args = fedml_tpu.init(args_factory(
         dataset="mnist", synthetic_train_size=120, synthetic_test_size=40,
         model="lr", client_num_in_total=4, client_num_per_round=2,
         comm_round=1, epochs=1, batch_size=10, frequency_of_the_test=1,
+        partition_method="hetero", partition_alpha=0.1,
     ))
     ds = load(args)
     api = FedAvgAPI(args, None, ds, models.create(args, ds.class_num))
-    jitted = getattr(api, attr)
+    jitted, passed = getattr(api, attr), []
 
     def lower_and_stop(*a, **kw):
+        passed.extend(a)
         raise _Lowered(jitted.lower(*a, **kw).as_text())
 
     setattr(api, attr, lower_and_stop)
     with pytest.raises(_Lowered) as ei:
         api.train()
     assert f"module @{name} " in str(ei.value)
+    if attr == "_eval_all":
+        assert passed[1] is api._eval_splits()[0]
+        assert passed[1].num_batches < ds.packed_train.num_batches
+    else:
+        assert passed[2] is api._sample_store()
 
 
 KERNELS = [
