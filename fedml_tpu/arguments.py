@@ -450,8 +450,23 @@ _DEFAULTS: Dict[str, Any] = {
     "num_kv_heads": 2,  # moe_decoder KV heads (num_heads a multiple)
     "head_dim": 64,  # moe_decoder head width (not tied to hidden_size / num_heads)
     # moe_decoder layer pattern, one entry per layer: "sliding_attention"
-    # | "full_attention" | "conv" (None = num_layers full layers)
+    # | "full_attention" | "conv" (None = num_layers full layers); with
+    # sublayers also "mamba" | "moe" and no "conv"
     "layer_types": None,
+    # moe_decoder: every layer_types entry is ONE sublayer, x + mixer(norm(x)):
+    # attention, the routed experts ("moe") or a Mamba-2 mixer ("mamba"),
+    # instead of an operator followed by a feed-forward
+    "sublayers": False,
+    "qk_norm": True,  # moe_decoder: RMS-normalise q and k per head before the rotation
+    # moe_decoder "mamba" sublayers (ops/ssd.py): heads and their width
+    # (the inner width is their product), B / C groups, state size, the
+    # causal depthwise convolution's taps, the scan's chunk
+    "ssm_num_heads": 4,
+    "ssm_head_dim": 64,
+    "ssm_groups": 1,
+    "ssm_state_size": 128,
+    "ssm_conv_kernel": 4,
+    "ssm_chunk_size": 128,
     "conv_L_cache": 3,  # moe_decoder: taps of a conv layer's causal depthwise filter
     "num_dense_layers": 0,  # moe_decoder: leading layers whose feed-forward is a dense MLP
     "intermediate_size": 0,  # moe_decoder: that dense MLP's width
@@ -460,7 +475,8 @@ _DEFAULTS: Dict[str, Any] = {
     # config.json has them: {layer type: {rope_type: default | yarn,
     # rope_theta, factor, original_max_position_embeddings, beta_fast,
     # beta_slow, attention_factor}} (None = rope_type default at
-    # rope_theta 10000 on both layer types)
+    # rope_theta 10000 on both layer types; an attention type the
+    # given table leaves out is not rotated, {} rotates none)
     "rope_parameters": None,
     "experts_per_token": 2,  # moe_decoder: experts a token is routed to (top-k)
     "expert_dim": 128,  # moe_decoder: width of one expert's gated MLP
@@ -470,6 +486,13 @@ _DEFAULTS: Dict[str, Any] = {
     # moe_decoder: a float32 leaf added to the scores for the top-k choice
     # only (the weights stay the unbiased scores: its gradient is zero)
     "use_expert_bias": False,
+    # moe_decoder: an expert is "gated_silu" (down(silu(gate x) * up x)) |
+    # "relu2" (down(relu(up x) ** 2), no gate)
+    "expert_activation": "gated_silu",
+    # moe_decoder: width of a shared expert of the same form, on every
+    # token beside the routed ones (0 = none)
+    "shared_expert_dim": 0,
+    "routed_scaling_factor": 1.0,  # moe_decoder: multiplies the (renormalised) top-k weights
     "tie_word_embeddings": False,  # moe_decoder: the head is the embedding's rows
     "rms_norm_eps": 1e-6,  # moe_decoder RMSNorm epsilon
     # moe_decoder: the chips that share each expert layer, and which of

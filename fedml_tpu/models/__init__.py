@@ -227,12 +227,24 @@ def create(args, output_dim: int) -> FedModel:
         )
     if name == "moe_decoder":
         from ..parallel.expert import experts_held
-        from .decoder import FULL, MoEDecoderLM, rope_parameters_from_args
+        from flax.core import freeze
+
+        from .decoder import FULL, SSM, MoEDecoderLM, rope_parameters_from_args
 
         vocab, seq_len = _lm_geometry(args, output_dim)
         num_experts = int(getattr(args, "num_experts", 8))
         layer_types = getattr(args, "layer_types", None) or (
             [FULL] * int(getattr(args, "num_layers", 2)))
+        ssm = None
+        if SSM in layer_types:  # the state-space mixer's sizes (Mamba2Mixer's fields)
+            ssm = freeze(dict(
+                num_heads=int(getattr(args, "ssm_num_heads", 4)),
+                head_dim=int(getattr(args, "ssm_head_dim", 64)),
+                groups=int(getattr(args, "ssm_groups", 1)),
+                state_size=int(getattr(args, "ssm_state_size", 128)),
+                conv_taps=int(getattr(args, "ssm_conv_kernel", 4)),
+                chunk_size=int(getattr(args, "ssm_chunk_size", 128)),
+            ))
         return FedModel(
             name="moe_decoder_lm",
             module=MoEDecoderLM(
@@ -263,6 +275,12 @@ def create(args, output_dim: int) -> FedModel:
                 use_expert_bias=bool(getattr(args, "use_expert_bias", False)),
                 norm_topk_eps=float(getattr(args, "norm_topk_eps", 0.0)),
                 tie_word_embeddings=bool(getattr(args, "tie_word_embeddings", False)),
+                sublayers=bool(getattr(args, "sublayers", False)),
+                qk_norm=bool(getattr(args, "qk_norm", True)),
+                expert_activation=getattr(args, "expert_activation", "gated_silu"),
+                shared_expert_dim=int(getattr(args, "shared_expert_dim", 0) or 0),
+                routed_scaling_factor=float(getattr(args, "routed_scaling_factor", 1.0)),
+                ssm=ssm,
             ),
             task="nwp",
             example_shape=(seq_len,),

@@ -1,77 +1,77 @@
 """A config-driven decoder LM: the block today's open models share.
 
-``models/transformer.py`` is a GPT-2 block (LayerNorm, learned
-positions, fused qkv, GELU) and cannot express this one: RMSNorm,
-separate q/k/v/o projections whose ``head_dim`` is not
-``hidden / heads``, grouped KV heads, q and k RMS-normalised per head,
-a rotary embedding whose parameters depend on the layer's *type*
-(``sliding_attention`` layers attend a causal window with the default
-rotary table, ``full_attention`` layers attend everything before them
-and may carry YaRN), and a routed gated-SiLU expert layer in place of
-the MLP. A layer's *operator* may also be a gated short convolution
-(``conv``, below) and its feed-forward a dense gated-SiLU MLP on the
-first ``num_dense_layers`` layers; the head may be tied to the
-embedding. All sizes are arguments; nothing is a model's name.
+``models/transformer.py`` is a GPT-2 block and cannot express this one:
+RMSNorm, separate q/k/v/o projections whose ``head_dim`` is not
+``hidden / heads``, grouped KV heads, q and k RMS-normalised per head
+(``qk_norm``), a rotary embedding by the layer's *type*
+(``sliding_attention``: a causal window, the default table;
+``full_attention``: every key before, maybe YaRN; a type with no entry
+in ``rope_parameters`` is not rotated) and routed experts for the MLP.
+A layer is an *operator* (attention, or the gated short convolution,
+``conv``) then a feed-forward (the experts; a dense gated-SiLU MLP on
+the first ``num_dense_layers`` layers) -- or, with ``sublayers``, ONE
+sublayer ``x + mixer(norm(x))``: attention, the experts (``moe``) or a
+state-space mixer (``mamba``). The head may be tied to the embedding.
+All sizes are arguments; nothing is a model's name.
 
-**The gated short convolution** (``layer_types`` entry ``conv``): ``B,
-C, u = split3(in_proj(h))``; ``z_t = sum_j w_j * (B * u)_{t - (L - 1)
-+ j}`` over ``L = conv_L_cache`` taps, one filter a channel, causal
-(zeros before the sequence), no activation; ``out_proj(C * z)``. The
-taps are ``L`` shifted multiply-adds in the compute type; it carries no
-state past the sequence and has no rotary table.
+**The gated short convolution** (``conv``): ``B, C, u =
+split3(in_proj(h))``; ``z_t = sum_j w_j * (B * u)_{t - (L - 1) + j}``
+over ``L = conv_L_cache`` taps, one filter a channel, causal (zeros
+before the sequence), no activation; ``out_proj(C * z)``; no state.
+
+**The state-space mixer** (``mamba``; Mamba-2): ``z, xBC, dt =
+split(in_proj(u))``; ``xBC = silu(conv(xBC) + bias)``, causal and
+depthwise; ``x, B, C = split(xBC)``, ``x`` as heads, ``B`` / ``C`` as
+groups of ``state_size`` (head ``h`` reads group ``h // (H / G)``);
+``dt = softplus(dt + dt_bias)``, ``A = -exp(A_log)`` a head, float32.
+Per head ``S_t = exp(dt_t A) S_{t-1} + dt_t x_t B_t^T``, ``y_t = S_t
+C_t + D x_t``: ``ops/ssd.py`` runs it in chunks (masked products in a
+chunk, a carried float32 state between chunks). Then ``RMSNorm(y *
+silu(z)) * w`` over each group's channels, and ``out_proj``.
 
 **The router** scores all experts in float32 with ``softmax`` or
-``sigmoid``. With ``use_expert_bias`` a float32 leaf ``expert_bias``
-[num_experts] is added to the scores *for the choice only*: the weights
-are the chosen experts' unbiased scores, so the bias's gradient is
-exactly zero and SGD leaves it as it was drawn. The top-k weights are
-divided by their sum plus ``norm_topk_eps`` (``norm_topk_prob``). (A
-published ``routed_scaling_factor`` other than 1 has no argument here
-yet: no configuration run so far sets one.)
+``sigmoid``. With ``use_expert_bias`` a float32 leaf ``expert_bias`` is
+added to the scores *for the choice only*: the weights are the chosen
+experts' unbiased scores, so the bias's gradient is exactly zero. The
+top-k weights are divided by their sum plus ``norm_topk_eps``
+(``norm_topk_prob``) and multiplied by ``routed_scaling_factor``. An
+expert is a gated-SiLU MLP (three stacks) or ``down(relu(up x) ** 2)``
+(``relu2``, two); ``shared_dim`` adds a shared expert of that form on
+every token, whole on every chip (a share's part counts it once).
 
 **The expert layer holds a share.** ``experts_held = (first, count)``
 (``parallel/expert.py``) says which of the ``num_experts`` experts live
-on this chip. The router keeps its published width: it scores all of
-them in float32, takes the top ``experts_per_token`` and renormalises;
-the layer then keeps the token-choices that landed on held experts,
-sorts them by expert, runs one grouped gated-SiLU product over the held
-stacks (``jax.lax.ragged_dot``: each row meets only its own expert's
-matrices) and adds each row, times its routing weight, back onto its
-token. What the absent experts would add is left out and the partial
-sum goes on — on one chip the layer runs without its exchange, and no
-code stands in for absent chips. Nothing is dropped at any imbalance:
-the sorted list has room for every choice a token can place here, and
-is worked through in chunks of an even load's rows and a quarter, as
-many as the choices that did land fill: one at a load near even (the
-chunks it never reaches cost nothing), more at an imbalance.
+on this chip. The router keeps its published width; the layer keeps
+the token-choices that landed on held experts, sorts them by expert,
+runs one grouped product over the held stacks (``jax.lax.ragged_dot``)
+and adds each row, times its routing weight, back onto its token. What
+the absent experts would add is left out and the partial sum goes on:
+no code stands in for absent chips. Nothing is dropped at any
+imbalance: the sorted list has room for every choice a token can place
+here, and is worked through in chunks of an even load's rows and a
+quarter, as many as the choices that did land fill.
 
 The round engine vmaps local training over the cohort's lanes, and the
 chip's ragged product takes no batch dimension, so the grouped part is
 a ``custom_vjp`` whose forward and backward each run one lane after
-another (``jax.custom_batching.sequential_vmap``): every lane brings its
-own expert weights, as a vmapped cohort must, and one lane's rows at a
-time is what the chip has room for (batched over two lanes the round
-executable needs 15.8 GB, lane after lane 13.5; compiled for a described
-v5e, PR 28). Inside that lane loop JAX's name stack starts anew: its
-operations carry ``moe.route`` / ``moe.experts`` / ``moe.combine`` and
-not the scopes around the model (``fed.local_train``).
-
-Where the cohort's lanes run one after another instead
-(``build_round_fn``'s ``ragged``) nothing is vmapped, the loop is not
-entered and the expert layer's operations keep the scopes around them.
+another (``jax.custom_batching.sequential_vmap``; batched over two lanes
+the round executable needs 15.8 GB, lane after lane 13.5: PR 28).
+Inside that loop JAX's name stack starts anew: its operations carry
+``moe.*`` and not ``fed.local_train``. Where the cohort's lanes run one
+after another instead (``build_round_fn``'s ``ragged``) nothing is
+vmapped and the operations keep the scopes around them.
 
 Scopes (HLO op metadata; ``benchmark/layer_metrics`` reads them from
 device traces): ``lm.embed``, ``blk.attn.window``, ``blk.attn.full``,
-``blk.conv`` (norm, ``in_proj``, gates, taps, ``out_proj``),
-``blk.mlp.dense``, ``moe.route``, ``moe.experts``, ``moe.combine``,
-``lm.head_loss`` (the head here -- a tied head's product with the
-embedding's transpose too -- and the loss in ``core/losses.py``).
-Counters (collection ``counters``, summed over layers;
-``FedModel.apply_counted``): ``moe_local_hits``,
-``moe_expert_tokens_max``, ``moe_expert_tokens_mean``, ``moe_dropped``
-and, on a layer with a selection bias, ``moe_bias_moved``: the
-token-choices the bias changed against the unbiased top-k (a layer
-without a bias sows none, and its program is what it was).
+``blk.conv``, ``blk.mlp.dense``, ``blk.ssm`` (a ``mamba`` sublayer) and
+inside it ``blk.ssm.scan``, ``moe.route``, ``moe.experts``,
+``moe.combine``, ``moe.shared``, ``lm.head_loss`` (the head, tied or
+not, and the loss in ``core/losses.py``). Counters (``counters``, summed
+over layers; ``FedModel.apply_counted``): ``moe_local_hits``,
+``moe_expert_tokens_max``, ``moe_expert_tokens_mean``, ``moe_dropped``,
+``moe_bias_moved`` on a layer with a selection bias (the choices it
+changed against the unbiased top-k) and ``ssm_chunks`` on a ``mamba``
+sublayer (sequences x chunks the scan ran); a layer sows only its own.
 """
 
 from __future__ import annotations
@@ -196,6 +196,12 @@ def _proj(features: int, name: str) -> nn.Dense:
     return nn.Dense(features, use_bias=False, name=name)
 
 
+# the sublayer kinds beside attention (``MoEDecoderLM.sublayers``); named
+# down here because the flash kernels' lowered text holds the line on
+# which ``attend`` calls them
+SSM, EXPERTS = "mamba", "moe"
+
+
 class Attention(nn.Module):
     num_heads: int
     num_kv_heads: int
@@ -203,6 +209,7 @@ class Attention(nn.Module):
     window: Optional[int]  # None: a full (causal) layer
     impl: str
     eps: float
+    qk_norm: bool = True
 
     @nn.compact
     def __call__(self, x, cos, sin):
@@ -211,9 +218,15 @@ class Attention(nn.Module):
         q = _proj(H * D, "q_proj")(x).reshape(B, T, H, D)
         k = _proj(KV * D, "k_proj")(x).reshape(B, T, KV, D)
         v = _proj(KV * D, "v_proj")(x).reshape(B, T, KV, D)
-        # q and k are RMS-normalised per head before the rotation
-        q = apply_rope(RMSNorm(self.eps, name="q_norm")(q), cos, sin)
-        k = apply_rope(RMSNorm(self.eps, name="k_norm")(k), cos, sin)
+
+        def prepared(h, norm_name):
+            """RMS-normalised per head (``qk_norm``), then rotated
+            (a layer type that has rotary parameters)."""
+            if self.qk_norm:
+                h = RMSNorm(self.eps, name=norm_name)(h)
+            return h if cos is None else apply_rope(h, cos, sin)
+
+        q, k = prepared(q, "q_norm"), prepared(k, "k_norm")
         o = attend(q, k, v, self.window, self.impl)
         return _proj(x.shape[-1], "o_proj")(o.reshape(B, T, H * D))
 
@@ -248,15 +261,99 @@ class GatedMLP(nn.Module):
         return _proj(x.shape[-1], "down_proj")(h)
 
 
+class SquaredReluMLP(nn.Module):
+    """``down(relu(up x) ** 2)``: two matrices, no gate."""
+
+    width: int
+
+    @nn.compact
+    def __call__(self, x):
+        h = jnp.square(jax.nn.relu(_proj(self.width, "up_proj")(x)))
+        return _proj(x.shape[-1], "down_proj")(h)
+
+
+# what ``dt_bias`` is drawn from where the program draws its own weights
+# (Mamba-2's defaults): softplus(dt_bias) log-uniform in [min, max], floored
+_DT_BIAS_DRAW = (1e-3, 1e-1, 1e-4)
+
+
+class Mamba2Mixer(nn.Module):
+    """The mixer of a ``mamba`` sublayer (module docstring), [B, T, C]
+    -> [B, T, C]. ``conv_kernel`` [taps, channels] holds one causal
+    filter a channel of ``x | B | C``, tap ``taps - 1`` on the token
+    itself; ``A_log``, ``D``, ``dt_bias`` one scalar a head."""
+
+    num_heads: int
+    head_dim: int
+    groups: int
+    state_size: int
+    conv_taps: int
+    chunk_size: int
+    eps: float
+
+    @nn.compact
+    def __call__(self, u):
+        from ..ops.ssd import num_chunks, ssd_scan
+
+        B, T, _ = u.shape
+        H, P, G, N = self.num_heads, self.head_dim, self.groups, self.state_size
+        inner, bc = H * P, G * N
+        if inner % G:
+            raise ValueError(f"{inner} inner channels do not split into {G} norm groups")
+        z, xbc, dt = jnp.split(_proj(2 * inner + 2 * bc + H, "in_proj")(u), [inner, 2 * inner + 2 * bc], axis=-1)
+        w = self.param("conv_kernel", nn.initializers.lecun_normal(), (self.conv_taps, inner + 2 * bc))
+        bias = self.param("conv_bias", nn.initializers.zeros, (inner + 2 * bc,))
+        # zeros before the sequence; tap j reads token t - (taps - 1) + j
+        padded = jnp.pad(xbc, ((0, 0), (self.conv_taps - 1, 0), (0, 0)))
+        w = w.astype(u.dtype)
+        xbc = jax.nn.silu(sum(w[j] * padded[:, j:j + T] for j in range(self.conv_taps)) + bias.astype(u.dtype))
+        x, b, c = jnp.split(xbc, [inner, inner + bc], axis=-1)
+
+        def dt_bias_init(key, shape):
+            lo, hi, floor = _DT_BIAS_DRAW
+            dt0 = jnp.exp(jax.random.uniform(key, shape, minval=math.log(lo), maxval=math.log(hi)))
+            dt0 = jnp.maximum(dt0, floor)
+            return dt0 + jnp.log(-jnp.expm1(-dt0))  # softplus of it is dt0
+
+        def a_log_init(key, shape):
+            return jnp.log(jax.random.uniform(key, shape, minval=1.0, maxval=16.0))
+
+        a_log = self.param("A_log", a_log_init, (H,))
+        d_skip = self.param("D", nn.initializers.ones, (H,))
+        dt_bias = self.param("dt_bias", dt_bias_init, (H,))
+        f32 = jnp.float32
+        with jax.named_scope("blk.ssm.scan"):
+            # the step, the decay and the skip in float32 whatever the compute type
+            step = jax.nn.softplus(dt.astype(f32) + dt_bias.astype(f32))
+            y = ssd_scan(
+                x.reshape(B, T, H, P), step, -jnp.exp(a_log.astype(f32)), b.reshape(B, T, G, N),
+                c.reshape(B, T, G, N), d_skip.astype(f32), self.chunk_size)
+        self.sow("counters", "ssm_chunks", f32(B * num_chunks(T, self.chunk_size)),
+                 reduce_fn=jnp.add, init_fn=lambda: f32(0))
+        # gated RMSNorm: the gate first, the statistics over each group's channels
+        scale = self.param("norm_scale", nn.initializers.ones, (inner,))
+        gated = (y.reshape(B, T, inner).astype(f32) * jax.nn.silu(z.astype(f32))).reshape(B, T, G, inner // G)
+        gated = gated * jax.lax.rsqrt(jnp.mean(gated * gated, axis=-1, keepdims=True) + self.eps)
+        y = (gated.reshape(B, T, inner) * scale.astype(f32)).astype(u.dtype)
+        return _proj(u.shape[-1], "out_proj")(y)
+
+
 # -- the expert layer -----------------------------------------------------
 
-def _gated_silu(xs, wg, wu, wd, sizes):
-    """Rows sorted by expert through their own expert's gated-SiLU MLP:
-    the grouped product. Rows past ``sizes.sum()`` belong to no expert;
-    what they read is masked by the caller."""
+def _expert_mlp(xs, stacks, sizes):
+    """Rows sorted by expert through their own expert's MLP: the grouped
+    product. Three stacks (gate, up, down) are a gated-SiLU expert,
+    ``down(silu(gate x) * up x)``; two (up, down) a squared-ReLU one,
+    ``down(relu(up x) ** 2)``. Rows past ``sizes.sum()`` belong to no
+    expert; what they read is masked by the caller."""
     dot = functools.partial(jax.lax.ragged_dot, group_sizes=sizes, preferred_element_type=jnp.float32)
-    gate, up = dot(xs, wg), dot(xs, wu)
-    h = (jax.nn.silu(gate) * up).astype(xs.dtype)
+    if len(stacks) == 3:
+        wg, wu, wd = stacks
+        gate, up = dot(xs, wg), dot(xs, wu)
+        h = (jax.nn.silu(gate) * up).astype(xs.dtype)
+    else:
+        wu, wd = stacks
+        h = jnp.square(jax.nn.relu(dot(xs, wu))).astype(xs.dtype)
     return dot(h, wd).astype(xs.dtype)
 
 
@@ -283,7 +380,7 @@ def _chunks(tok, weight, sizes, rows: int):
     return count, slice_of
 
 
-def _experts_fwd_one(rows, x, tok, weight, sizes, wg, wu, wd):
+def _experts_fwd_one(rows, x, tok, weight, sizes, *stacks):
     """One lane: ``y[t] = sum over the held choices (t, e) of
     weight * MLP_e(x[t])``, float32 [N, C], and the rows the grouped
     product was given over the chunks that ran (what ``moe_dropped``
@@ -296,7 +393,7 @@ def _experts_fwd_one(rows, x, tok, weight, sizes, wg, wu, wd):
         with jax.named_scope("moe.route"):
             xs = jnp.take(x, t, axis=0)
         with jax.named_scope("moe.experts"):
-            out = _gated_silu(xs, wg, wu, wd, inside)
+            out = _expert_mlp(xs, stacks, inside)
         with jax.named_scope("moe.combine"):
             add = jnp.where(valid[:, None], out.astype(jnp.float32) * w[:, None], 0.0)
             return y.at[t].add(add), done + jnp.sum(inside).astype(jnp.float32)
@@ -304,42 +401,44 @@ def _experts_fwd_one(rows, x, tok, weight, sizes, wg, wu, wd):
     return jax.lax.fori_loop(0, count, body, (jnp.zeros(x.shape, jnp.float32), jnp.float32(0)))
 
 
-def _experts_bwd_one(rows, x, tok, weight, sizes, wg, wu, wd, dy):
-    """One lane's cotangents of ``x``, ``weight`` and the three stacks,
-    each chunk's forward recomputed (nothing of a chunk outlives it)."""
+def _experts_bwd_one(rows, x, tok, weight, sizes, *stacks_dy):
+    """One lane's cotangents of ``x``, ``weight`` and the stacks, each
+    chunk's forward recomputed (nothing of a chunk outlives it)."""
+    *stacks, dy = stacks_dy
     count, slice_of = _chunks(tok, weight, sizes, rows)
 
     def body(c, acc):
-        dx, dweight, dwg, dwu, dwd = acc
+        dx, dweight, *dstacks = acc
         t, w, inside, valid = slice_of(c)
         with jax.named_scope("moe.route"):
             xs = jnp.take(x, t, axis=0)
         with jax.named_scope("moe.combine"):
             dys = jnp.where(valid[:, None], jnp.take(dy, t, axis=0), 0.0)
         with jax.named_scope("moe.experts"):
-            out, vjp = jax.vjp(lambda xs, a, b, c_: _gated_silu(xs, a, b, c_, inside), xs, wg, wu, wd)
-            dxs, g_wg, g_wu, g_wd = vjp((dys * w[:, None]).astype(out.dtype))
+            out, vjp = jax.vjp(lambda xs, *ws: _expert_mlp(xs, ws, inside), xs, *stacks)
+            dxs, *g_stacks = vjp((dys * w[:, None]).astype(out.dtype))
         with jax.named_scope("moe.combine"):
             dw = jnp.where(valid, jnp.sum(dys * out.astype(jnp.float32), axis=-1), 0.0)
             dweight = jax.lax.dynamic_update_slice_in_dim(dweight, dw, c * rows, axis=0)
         with jax.named_scope("moe.route"):
             dx = dx.at[t].add(jnp.where(valid[:, None], dxs.astype(jnp.float32), 0.0))
-        return dx, dweight, dwg + g_wg, dwu + g_wu, dwd + g_wd
+        return (dx, dweight, *(acc_w + g for acc_w, g in zip(dstacks, g_stacks)))
 
     zeros32 = lambda a: jnp.zeros(a.shape, jnp.float32)
     whole_chunks = jnp.zeros(-(-weight.shape[0] // rows) * rows, jnp.float32)
-    dx, dweight, dwg, dwu, dwd = jax.lax.fori_loop(
-        0, count, body, (zeros32(x), whole_chunks, zeros32(wg), zeros32(wu), zeros32(wd)))
-    return (dx.astype(x.dtype), dweight[:weight.shape[0]], dwg.astype(wg.dtype), dwu.astype(wu.dtype),
-            dwd.astype(wd.dtype))
+    dx, dweight, *dstacks = jax.lax.fori_loop(
+        0, count, body, (zeros32(x), whole_chunks, *map(zeros32, stacks)))
+    return (dx.astype(x.dtype), dweight[:weight.shape[0]],
+            *(dw.astype(w.dtype) for dw, w in zip(dstacks, stacks)))
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
-def held_experts(rows, x, tok, weight, sizes, wg, wu, wd):
+def held_experts(rows, x, tok, weight, sizes, *stacks):
     """``x`` [N, C] tokens; ``tok`` / ``weight`` [M] the token id and
     routing weight of each choice that landed on a held expert, sorted
-    by expert, ``sizes`` [held] the rows of each; ``wg`` / ``wu``
-    [held, C, I], ``wd`` [held, I, C]; ``rows`` the chunk the list is
+    by expert, ``sizes`` [held] the rows of each; ``stacks`` the held
+    experts' matrices (``_expert_mlp``: gate and up [held, C, I] and
+    down [held, I, C], or up and down alone); ``rows`` the chunk the list is
     worked through in. Returns the held experts' part
     of the layer's output, float32 [N, C], and the number of rows the
     grouped product ran on (float32; no gradient). A ``custom_vjp`` because the
@@ -348,25 +447,28 @@ def held_experts(rows, x, tok, weight, sizes, wg, wu, wd):
     Under ``vmap`` the lanes run one after another, forward and
     backward (the chip's ragged product has no batch dimension, and one
     lane's rows at a time is all the chip has room for)."""
-    return sequential_vmap(functools.partial(_experts_fwd_one, rows))(x, tok, weight, sizes, wg, wu, wd)
+    return sequential_vmap(functools.partial(_experts_fwd_one, rows))(x, tok, weight, sizes, *stacks)
 
 
-def _held_experts_fwd(rows, x, tok, weight, sizes, wg, wu, wd):
-    return held_experts(rows, x, tok, weight, sizes, wg, wu, wd), (x, tok, weight, sizes, wg, wu, wd)
+def _held_experts_fwd(rows, x, tok, weight, sizes, *stacks):
+    return held_experts(rows, x, tok, weight, sizes, *stacks), (x, tok, weight, sizes, *stacks)
 
 
 def _held_experts_bwd(rows, res, cotangents):
     dy, _ = cotangents  # the row count carries no gradient
-    dx, dweight, dwg, dwu, dwd = sequential_vmap(functools.partial(_experts_bwd_one, rows))(*res, dy)
-    return dx, None, dweight, None, dwg, dwu, dwd
+    dx, dweight, *dstacks = sequential_vmap(functools.partial(_experts_bwd_one, rows))(*res, dy)
+    return (dx, None, dweight, None, *dstacks)
 
 
 held_experts.defvjp(_held_experts_fwd, _held_experts_bwd)
 
 
 class HeldExperts(nn.Module):
-    """Routed gated-SiLU experts, [B, T, C] -> [B, T, C]: the router's
-    full width, this chip's share of the stacks (module docstring)."""
+    """Routed experts, [B, T, C] -> [B, T, C]: the router's full width,
+    this chip's share of the stacks (module docstring). ``activation``
+    ``gated_silu`` (three stacks) or ``relu2`` (``up_proj`` and
+    ``down_proj`` alone); ``shared_dim`` > 0 adds a shared expert of
+    that width and the same form, on every token, whole."""
 
     num_experts: int
     experts_per_token: int
@@ -376,6 +478,9 @@ class HeldExperts(nn.Module):
     scoring: str = "softmax"  # | "sigmoid"
     use_expert_bias: bool = False
     norm_topk_eps: float = 0.0
+    activation: str = "gated_silu"  # | "relu2"
+    shared_dim: int = 0
+    routed_scaling_factor: float = 1.0
 
     @nn.compact
     def __call__(self, x):
@@ -386,10 +491,14 @@ class HeldExperts(nn.Module):
             raise ValueError(f"experts_held {self.experts_held} is no share of {E} experts")
         if self.scoring not in ("softmax", "sigmoid"):
             raise ValueError(f"router scoring {self.scoring!r}: 'softmax' or 'sigmoid'")
+        if self.activation not in ("gated_silu", "relu2"):
+            raise ValueError(f"expert activation {self.activation!r}: 'gated_silu' or 'relu2'")
         init = nn.initializers.lecun_normal(batch_axis=(0,))
-        wg = self.param("gate_proj", init, (held, C, self.expert_dim))
-        wu = self.param("up_proj", init, (held, C, self.expert_dim))
-        wd = self.param("down_proj", init, (held, self.expert_dim, C))
+        wide, narrow = (held, C, self.expert_dim), (held, self.expert_dim, C)
+        shapes = {"gate_proj": wide, "up_proj": wide, "down_proj": narrow}
+        if self.activation == "relu2":
+            del shapes["gate_proj"]
+        stacks = tuple(self.param(name, init, shape) for name, shape in shapes.items())
         xf = x.reshape(N, C)
         with jax.named_scope("moe.route"):
             # float32 whatever the compute type (the router's parameters
@@ -413,6 +522,8 @@ class HeldExperts(nn.Module):
             if self.norm_topk_prob:
                 total = jnp.sum(weight, axis=-1, keepdims=True)
                 weight = weight / (total + self.norm_topk_eps if self.norm_topk_eps else total)
+            if self.routed_scaling_factor != 1.0:
+                weight = weight * self.routed_scaling_factor
             here = (expert >= first) & (expert < first + held)
             # absent experts sort last, as one group past the held ones
             local = jnp.where(here, expert - first, held).reshape(N * K)
@@ -430,7 +541,11 @@ class HeldExperts(nn.Module):
         # even load, layers a per cent over it ran a second, near-empty
         # chunk: a call of 10 rounds read 62.5 to 63.9 s by seed on the v5e)
         rows = min(room, -(-5 * N * K * held // (4 * E)))
-        y, done = held_experts(rows, xf, tok, sorted_weight, sizes, wg, wu, wd)
+        y, done = held_experts(rows, xf, tok, sorted_weight, sizes, *stacks)
+        if self.shared_dim:
+            with jax.named_scope("moe.shared"):
+                shared = GatedMLP if self.activation == "gated_silu" else SquaredReluMLP
+                y = y + shared(self.shared_dim, name="shared")(xf).astype(jnp.float32)
         f32 = lambda v: jnp.asarray(v, jnp.float32)
         counted = {
             "moe_local_hits": hits, "moe_expert_tokens_max": jnp.max(sizes),
@@ -485,6 +600,33 @@ class DecoderBlock(nn.Module):
             return x + GatedMLP(self.intermediate_size, name="mlp")(h)
 
 
+class SublayerBlock(nn.Module):
+    """``x + mixer(norm(x))``, one sublayer a layer: the mixer is the
+    state-space one (``kind`` ``mamba``; ``ssm``: ``Mamba2Mixer``'s
+    fields but eps), attention (sliding or full; ``attn``: ``Attention``'s
+    fields but the window and eps) or the routed experts (``moe``; ``experts``:
+    ``HeldExperts``' fields); ``eps`` is every norm's."""
+
+    kind: str
+    window: Optional[int]
+    eps: float
+    attn: Any
+    ssm: Any
+    experts: Any
+
+    @nn.compact
+    def __call__(self, x, cos, sin):
+        if self.kind == SSM:
+            with jax.named_scope("blk.ssm"):
+                return x + Mamba2Mixer(**self.ssm, eps=self.eps, name="ssm")(
+                    RMSNorm(self.eps, name="ssm_norm")(x))
+        if self.kind == EXPERTS:
+            return x + HeldExperts(**self.experts, name="moe")(RMSNorm(self.eps, name="ffn_norm")(x))
+        with jax.named_scope("blk.attn.window" if self.window is not None else "blk.attn.full"):
+            return x + Attention(window=self.window, eps=self.eps, **self.attn, name="attn")(
+                RMSNorm(self.eps, name="attn_norm")(x), cos, sin)
+
+
 class MoEDecoderLM(nn.Module):
     """Causal LM over ``vocab_size`` rows (a slice of a larger
     vocabulary is a smaller vocabulary: ids, logits and loss are over
@@ -492,7 +634,11 @@ class MoEDecoderLM(nn.Module):
     ``num_dense_layers`` layers carry a dense MLP of
     ``intermediate_size``, every other one the routed experts; with
     ``tie_word_embeddings`` the head is the embedding's rows (one leaf,
-    the gradients of both uses summed)."""
+    the gradients of both uses summed). With ``sublayers`` every entry
+    of ``layer_types`` is one sublayer (``SublayerBlock``: ``mamba``,
+    an attention kind or ``moe``) instead of an operator and a
+    feed-forward. An attention kind with no entry in
+    ``rope_parameters`` is not rotated."""
 
     vocab_size: int
     hidden_size: int
@@ -517,6 +663,12 @@ class MoEDecoderLM(nn.Module):
     use_expert_bias: bool = False
     norm_topk_eps: float = 0.0
     tie_word_embeddings: bool = False
+    sublayers: bool = False
+    qk_norm: bool = True
+    expert_activation: str = "gated_silu"
+    shared_expert_dim: int = 0
+    routed_scaling_factor: float = 1.0
+    ssm: Any = None  # the ``mamba`` sublayers': Mamba2Mixer's fields but eps (a frozen dict)
 
     @nn.compact
     def __call__(self, tokens, train: bool = False):
@@ -524,28 +676,43 @@ class MoEDecoderLM(nn.Module):
         embed = nn.Embed(self.vocab_size, self.hidden_size, name="embed")
         with jax.named_scope("lm.embed"):
             x = embed(tokens.astype(jnp.int32))
+        kinds = (SLIDING, FULL, SSM, EXPERTS) if self.sublayers else (SLIDING, FULL, CONV)
         for kind in self.layer_types:
-            if kind not in (SLIDING, FULL, CONV):
-                raise ValueError(f"layer type {kind!r}: {SLIDING!r}, {FULL!r} or {CONV!r}")
-        # one table per attention layer type, made once
+            if kind not in kinds:
+                raise ValueError(f"layer type {kind!r}: one of {kinds}")
+        # one table per rotated attention layer type, made once
         tables = {
             kind: rope_tables(T, self.head_dim, dict(self.rope_parameters[kind]))
-            for kind in dict.fromkeys(self.layer_types) if kind != CONV
+            for kind in dict.fromkeys(self.layer_types)
+            if kind in (SLIDING, FULL) and kind in self.rope_parameters
         }
         experts = freeze(dict(
             num_experts=self.num_experts, experts_per_token=self.experts_per_token,
             expert_dim=self.expert_dim, experts_held=tuple(self.experts_held),
             norm_topk_prob=self.norm_topk_prob, scoring=self.router_scoring,
             use_expert_bias=self.use_expert_bias, norm_topk_eps=self.norm_topk_eps,
+            activation=self.expert_activation, shared_dim=self.shared_expert_dim,
+            routed_scaling_factor=self.routed_scaling_factor,
         ))
-        block = nn.remat(DecoderBlock) if self.remat else DecoderBlock
-        for i, kind in enumerate(self.layer_types):
-            x = block(
+        if self.sublayers:
+            if SSM in self.layer_types and self.ssm is None:
+                raise ValueError(f"a {SSM!r} sublayer needs the state-space mixer's sizes (ssm)")
+            attn = freeze(dict(
+                num_heads=self.num_heads, num_kv_heads=self.num_kv_heads, head_dim=self.head_dim,
+                impl=self.attention, qk_norm=self.qk_norm))
+            block = nn.remat(SublayerBlock) if self.remat else SublayerBlock
+            layer = lambda i, kind: block(
+                kind, self.sliding_window if kind == SLIDING else None, self.rms_norm_eps,
+                attn, self.ssm, experts, name=f"layer_{i}")
+        else:
+            block = nn.remat(DecoderBlock) if self.remat else DecoderBlock
+            layer = lambda i, kind: block(
                 kind, self.num_heads, self.num_kv_heads, self.head_dim,
                 self.sliding_window if kind == SLIDING else None, self.attention,
                 self.rms_norm_eps, self.conv_L_cache, self.intermediate_size,
-                experts if i >= self.num_dense_layers else None, name=f"layer_{i}",
-            )(x, *tables.get(kind, (None, None)))
+                experts if i >= self.num_dense_layers else None, name=f"layer_{i}")
+        for i, kind in enumerate(self.layer_types):
+            x = layer(i, kind)(x, *tables.get(kind, (None, None)))
         with jax.named_scope("lm.head_loss"):
             x = RMSNorm(self.rms_norm_eps, name="final_norm")(x)
             if self.tie_word_embeddings:
@@ -556,7 +723,9 @@ class MoEDecoderLM(nn.Module):
 def rope_parameters_from_args(args):
     """``args.rope_parameters`` ({layer type: {...}}, as a published
     ``config.json`` has it) or, without one, the default table at a
-    base of 10,000 for both layer types."""
-    given = getattr(args, "rope_parameters", None) or {
-        kind: {"rope_type": "default", "rope_theta": 10000.0} for kind in (SLIDING, FULL)}
+    base of 10,000 for both layer types. An attention type the given
+    table leaves out is not rotated (``{}``: no layer is)."""
+    given = getattr(args, "rope_parameters", None)
+    if given is None:
+        given = {kind: {"rope_type": "default", "rope_theta": 10000.0} for kind in (SLIDING, FULL)}
     return freeze({k: dict(v) for k, v in dict(given).items()})

@@ -118,7 +118,7 @@ def test_kernel_is_named_in_the_tpu_lowering(kernel):
     text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).trace(s, s, s).lower(
         lowering_platforms=("tpu",)).as_text()
     assert kernel in set(re.findall(r"flash_attention\w*", text))
-    for traffic in ("c2_t4096_b4_eval10.json", "c2_t4096_b2_eval10.json"):
+    for traffic in ("c2_t4096_b4_eval10.json", "c2_t4096_b2_eval10.json", "c2_t8192_b1_eval10.json"):
         read = json.loads(_read("benchmark", "workloads", traffic))["kernel_names"]
         assert set(read) <= set(KERNELS)
 
@@ -131,14 +131,16 @@ def test_every_cell_is_described_where_readers_look(cell):
     assert f"`{cell}`" in cells_section
 
 
-def test_the_lane_after_lane_familys_tiny_cell_runs_on_the_cpu():
+@pytest.mark.parametrize("rehearsal", ["test_fedavg_lfm2.py", "test_fedavg_twotower.py"])
+def test_the_lane_after_lane_familys_tiny_cell_runs_on_the_cpu(rehearsal):
     """``benchmark/tests/`` is outside this suite's command (PERF.md
     section 7, ask 10), so the rehearsal of the ``fedavg_lm_lanes``
-    family's whole run -- set-up, window, release, the comparison with
-    its plain reference -- is started from here, in a process of its
-    own (the benchmark's tests bring their own ``conftest.py``)."""
+    family's whole run (and of ``fedavg_lm_lanes_ssm``'s, its child) --
+    set-up, window, release, the comparison with its plain reference --
+    is started from here, in a process of its own (the benchmark's
+    tests bring their own ``conftest.py``)."""
     r = subprocess.run(
-        [sys.executable, "-m", "pytest", "benchmark/tests/test_fedavg_lfm2.py::test_end_to_end_line",
+        [sys.executable, "-m", "pytest", f"benchmark/tests/{rehearsal}::test_end_to_end_line",
          "-q", "-p", "no:cacheprovider", "-p", "no:xdist", "-p", "no:randomly"],
         cwd=REPO, capture_output=True, text=True, timeout=900,
         env={**os.environ, "JAX_PLATFORMS": "cpu"},

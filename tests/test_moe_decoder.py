@@ -32,7 +32,8 @@ import fedml_tpu
 from fedml_tpu import data, models
 from fedml_tpu.arguments import Arguments
 from fedml_tpu.models.decoder import (
-    CONV, FULL, SLIDING, DecoderBlock, GatedShortConv, HeldExperts, dense_attention, rope_inv_freq,
+    CONV, EXPERTS, FULL, SLIDING, SSM, DecoderBlock, GatedShortConv, HeldExperts, dense_attention,
+    rope_inv_freq,
 )
 from fedml_tpu.ops.flash_attention import flash_attention
 from fedml_tpu.parallel.expert import ep_specs, experts_held
@@ -49,6 +50,7 @@ def _load(path, name):
 
 ref = _load("benchmark/reference/fedavg_mellum2.py", "ref_fedavg_mellum2")
 ref_conv = _load("benchmark/reference/fedavg_lfm2.py", "ref_fedavg_lfm2")
+ref_ssm = _load("benchmark/reference/fedavg_twotower.py", "ref_fedavg_twotower")
 
 ROPE = {
     FULL: {"rope_type": "yarn", "rope_theta": 500000, "factor": 16,
@@ -103,13 +105,44 @@ def _args_conv(**over):
     return argparse.Namespace(**flat)
 
 
+# the third configuration: one sublayer a layer -- state-space mixers,
+# unrotated attention without q/k norm, squared-ReLU experts beside a
+# shared expert behind a biased sigmoid router with a scaling factor
+MODEL_SSM = {
+    "vocab_size": 64, "hidden_size": 64, "head_dim": 16, "num_attention_heads": 4,
+    "num_key_value_heads": 2, "layer_types": [SSM, EXPERTS, SSM, EXPERTS, SSM, FULL, EXPERTS],
+    "num_dense_layers": 4,  # the sublayers without routed experts
+    "mamba_num_heads": 8, "mamba_head_dim": 8, "n_groups": 2, "ssm_state_size": 16, "conv_kernel": 4,
+    "chunk_size": 8, "time_step_min": 1e-3, "time_step_max": 0.1, "time_step_floor": 1e-4,
+    "n_routed_experts": 8, "num_experts_per_tok": 2, "moe_intermediate_size": 32,
+    "moe_shared_expert_intermediate_size": 48, "routed_scaling_factor": 2.5, "norm_topk_prob": True,
+    "norm_topk_eps": 1e-20, "norm_eps": 1e-5, "experts_held": [4, 4],
+}
+
+
+def _args_ssm(**over):
+    flat = dict(
+        model="moe_decoder", dataset="token_stream", vocab_size=64, seq_len=T, hidden_size=64,
+        num_heads=4, num_kv_heads=2, head_dim=16, layer_types=list(MODEL_SSM["layer_types"]),
+        sublayers=True, qk_norm=False, rope_parameters={}, ssm_num_heads=8, ssm_head_dim=8,
+        ssm_groups=2, ssm_state_size=16, ssm_conv_kernel=4, ssm_chunk_size=8, num_experts=8,
+        experts_per_token=2, expert_dim=32, router_scoring="sigmoid", use_expert_bias=True,
+        norm_topk_eps=1e-20, expert_activation="relu2", shared_expert_dim=48,
+        routed_scaling_factor=2.5, rms_norm_eps=1e-5, expert_parallel=2, expert_rank=1,
+        attention_impl="full",
+    )
+    flat.update(over)
+    return argparse.Namespace(**flat)
+
+
 class Case:
     """One configuration of the block with its plain reference; the
-    seeded weights are drawn on first use."""
+    seeded weights are drawn on first use. ``ragged``: its federated
+    round runs lane after lane (``one_round``)."""
 
-    def __init__(self, ref, model, args, faults, scopes, counters):
+    def __init__(self, ref, model, args, faults, scopes, counters, ragged=False):
         self.ref, self.model, self.args = ref, model, args
-        self.faults, self.scopes, self.counters = faults, scopes, counters
+        self.faults, self.scopes, self.counters, self.ragged = faults, scopes, counters, ragged
 
     @functools.cached_property
     def weights(self):
@@ -125,7 +158,12 @@ CASES = {
     "conv_sigmoid": Case(
         ref_conv, MODEL_CONV, _args_conv,
         ("no_bias", "acausal_conv", "no_c_gate", "no_renorm", "dense_width"),
-        LM_SCOPES + ("blk.attn.full", "blk.conv", "blk.mlp.dense"), MOE_COUNTERS + ("moe_bias_moved",)),
+        LM_SCOPES + ("blk.attn.full", "blk.conv", "blk.mlp.dense"), MOE_COUNTERS + ("moe_bias_moved",),
+        ragged=True),
+    "ssm_relu2": Case(
+        ref_ssm, MODEL_SSM, _args_ssm, tuple(f for f in ref_ssm.FAULTS if f),
+        LM_SCOPES + ("blk.attn.full", "blk.ssm", "blk.ssm.scan", "moe.shared"),
+        MOE_COUNTERS + ("moe_bias_moved", "ssm_chunks"), ragged=True),
 }
 
 
@@ -677,13 +715,13 @@ def test_sliced_vocabulary_draws_scores_and_loses_over_the_slice(weights):
 @pytest.fixture(scope="module")
 def one_round(case):
     """``window_softmax``: 4 silos of 4 sequences, the vmapped static
-    scan. ``conv_sigmoid``: 10 sequences over 4 silos (3, 3, 2, 2) at
+    scan. The ``ragged`` cases: 10 sequences over 4 silos (3, 3, 2, 2) at
     batch 2, so two silos leave the second batch empty and -- with the
     engine's floor on a lane step's work lowered for this tiny model --
     the cohort runs lane after lane (``lax.map``)."""
     from fedml_tpu.simulation import fedavg_api
 
-    ragged = case is CASES["conv_sigmoid"]
+    ragged = case.ragged
     args = _fed_args(case.args, **({"synthetic_train_size": 10} if ragged else {}))
     ds = data.load(args)
     heavy = fedavg_api._HEAVY_LANE_STEP
@@ -733,7 +771,11 @@ def test_the_rounds_record_carries_the_counters(case, one_round):
     assert rec["moe_expert_tokens_mean"] == pytest.approx(rec["moe_local_hits"] / 4)
     assert rec["moe_expert_tokens_mean"] <= rec["moe_expert_tokens_max"] <= calls * n
     assert rec["steps_packed"] == 2 * ds.packed_train.mask.shape[1]
-    if case is CASES["conv_sigmoid"]:
+    if "ssm_chunks" in case.counters:
+        # every mixer's scan, a step: 2 sequences of T / chunk_size chunks
+        assert rec["ssm_chunks"] == case.model["layer_types"].count(SSM) * rec["steps_run"] * 2 * (
+            T // case.model["chunk_size"])
+    if case.ragged:
         # silos 0 and 1 hold 3 sequences (2 steps), 2 and 3 hold 2 (1 step of their 2)
         cohort = case.ref.sample_cohort(0, 4, 2)
         assert rec["steps_run"] == sum(2 if c < 2 else 1 for c in cohort) < rec["steps_packed"] + (
@@ -758,7 +800,7 @@ def test_scopes_name_the_round_executables_parts(case, one_round):
     text = lowered.as_text(debug_info=True)
     for scope in case.scopes:
         assert f"fed.local_train/" in text and f"/{scope}/" in text, scope
-    if case is CASES["conv_sigmoid"]:
+    if case.ragged:
         import re
 
         # composed names are the compiled executable's (the lowered text nests its locations)
@@ -768,3 +810,115 @@ def test_scopes_name_the_round_executables_parts(case, one_round):
     ev = api._eval_all.lower(api.global_params, packed).as_text(debug_info=True)
     for scope in case.scopes:
         assert f"/{scope}/" in ev, scope
+
+
+# -- the sublayer form: shared expert, scaling factor, three rounds ------
+def _relu2_layer(first, held, experts=16, **kw):
+    return HeldExperts(
+        num_experts=experts, experts_per_token=3, expert_dim=32, experts_held=(first, held),
+        scoring="sigmoid", use_expert_bias=True, norm_topk_eps=1e-20, activation="relu2",
+        routed_scaling_factor=2.5, **kw)
+
+
+@pytest.mark.parametrize("ep", [1, 4, 16])
+def test_the_shares_of_a_relu2_layer_and_the_shared_expert_once_add_up(ep):
+    """The held parts of all ``ep`` shares, with what every chip
+    computes alike -- the shared expert -- counted once, are the uncut
+    reference's expert layer."""
+    ks = jax.random.split(jax.random.PRNGKey(13), 6)
+    bias = 0.05 * jax.random.normal(ks[0], (16,))
+    p = {
+        "router": {"kernel": jax.random.normal(ks[1], (64, 16)) / 8}, "expert_bias": bias - bias.mean(),
+        "up_proj": jax.random.normal(ks[2], (16, 64, 32)) / 8,
+        "down_proj": jax.random.normal(ks[3], (16, 32, 64)) / 6,
+        "shared": {"up_proj": {"kernel": jax.random.normal(ks[4], (64, 48)) / 8},
+                   "down_proj": {"kernel": jax.random.normal(ks[5], (48, 64)) / 7}},
+    }
+    whole = dict(MODEL_SSM, n_routed_experts=16, num_experts_per_tok=3, experts_held=[0, 16])
+    x = jax.random.normal(jax.random.PRNGKey(5), (2, T, 64))
+
+    def share(first, held, shared):
+        cut = {k: (v[first:first + held] if k in ("up_proj", "down_proj") else v)
+               for k, v in p.items() if shared or k != "shared"}
+        layer = _relu2_layer(first, held, shared_dim=48 if shared else 0)
+        return layer.apply({"params": cut}, x)
+
+    with jax.default_matmul_precision("highest"):
+        want = jnp.stack([ref_ssm._experts(s, p, whole, None, None) for s in x])
+        # rank 0 brings the shared expert, the other shares their held experts' part alone
+        got = sum(share(*experts_held(16, ep, r), shared=(r == 0)) for r in range(ep))
+        # and one share with the shared expert is the reference's share
+        one = ref_ssm._experts(x[0], {**p, "up_proj": p["up_proj"][4:8], "down_proj": p["down_proj"][4:8]},
+                               dict(whole, experts_held=[4, 4]), None, None)
+    assert _close(got, want, 2e-5)
+    assert _close(share(4, 4, shared=True)[0], one, 2e-5)
+
+
+@pytest.mark.parametrize("key,value,over", [
+    ("routed_scaling_factor", 1.0, {}), ("norm_topk_eps", 0.5, {}),
+    ("routed_scaling_factor", 2.5, {"routed_scaling_factor": 1.0}),
+    ("norm_topk_eps", 1e-20, {"norm_topk_eps": 0.5})])
+def test_the_reference_reads_the_scaling_factor_and_the_renormalisers_epsilon(tokens, key, value, over):
+    """The reference takes both from the configuration: told another
+    value it disagrees with the program, and a program told another
+    (one that ignored the configuration's) disagrees with the
+    reference."""
+    c = CASES["ssm_relu2"]
+    with jax.default_matmul_precision("highest"):
+        got = models.create(c.args(**over), 64).apply(c.weights, tokens[:1, :-1])[0]
+        want = c.ref.forward(c.weights, tokens[0, :-1], dict(c.model, **{key: value}))
+    assert not _close(got, want, 1e-3)
+
+
+def test_attention_without_rotation_or_qk_norm_is_a_matter_of_the_arguments(tokens):
+    """``rope_parameters`` {} and ``qk_norm`` false: no leaf, no
+    rotation; an entry for the layer's type brings the rotation back."""
+    c = CASES["ssm_relu2"]
+    plain = models.create(c.args(), 64)
+    assert set(plain.init(jax.random.PRNGKey(0))["layer_5"]["attn"]) == {"q_proj", "k_proj", "v_proj", "o_proj"}
+    rotated = models.create(c.args(rope_parameters={FULL: {"rope_type": "default", "rope_theta": 1e4}}), 64)
+    normed = models.create(c.args(qk_norm=True), 64)
+    assert set(normed.init(jax.random.PRNGKey(0))["layer_5"]["attn"]) >= {"q_norm", "k_norm"}
+    with jax.default_matmul_precision("highest"):
+        a, b = plain.apply(c.weights, tokens[:, :-1]), rotated.apply(c.weights, tokens[:, :-1])
+    assert not _close(a, b, 1e-3)
+    with pytest.raises(ValueError, match="layer type"):  # a sublayer kind outside the sublayer form
+        models.create(_args(layer_types=[FULL, SSM]), 64).init(jax.random.PRNGKey(0))
+    with pytest.raises(ValueError, match="activation"):
+        models.create(c.args(expert_activation="gelu"), 64).init(jax.random.PRNGKey(0))
+
+
+def test_three_rounds_lane_after_lane_match_the_reference():
+    """The check's drive at a tiny size: three FedAvg rounds (cohorts of
+    rounds 0, 1, 2) through the lane-after-lane engine against the
+    reference's, losses and final weights; ``ssm_chunks`` reaches every
+    round's record."""
+    from fedml_tpu.simulation import fedavg_api
+
+    c = CASES["ssm_relu2"]
+    args = _fed_args(c.args, synthetic_train_size=10, comm_round=3)
+    ds = data.load(args)
+    heavy, fedavg_api._HEAVY_LANE_STEP = fedavg_api._HEAVY_LANE_STEP, 0
+    try:
+        api = fedavg_api.FedAvgAPI(args, None, ds, models.create(args, ds.class_num))
+    finally:
+        fedavg_api._HEAVY_LANE_STEP = heavy
+    assert api._round_exec_name() == "simulation.round_fn_ragged"
+    api.global_params = jax.tree.map(jnp.copy, c.weights)
+    api.train()
+    packed = (ds.packed_train.x, ds.packed_train.y, ds.packed_train.mask)
+    want, losses = c.weights, []
+    with jax.default_matmul_precision("highest"):
+        for r in range(3):
+            want, loss = c.ref.fedavg_round(
+                want, packed, ds.packed_num_samples, c.ref.sample_cohort(r, 4, 2), c.model,
+                {"lr": 0.05, "epochs": 1})
+            losses.append(loss)
+    assert len(api.history) == 3
+    for rec, loss in zip(api.history, losses):
+        assert abs(rec["train_loss_cohort"] - loss) <= 2e-5 * loss
+        assert rec["ssm_chunks"] == 3 * rec["steps_run"] * 2 * (T // 8) and rec["moe_dropped"] == 0.0
+    moved = [float(jnp.linalg.norm(a - b)) for a, b in zip(jax.tree.leaves(want), jax.tree.leaves(c.weights))]
+    gap = [float(jnp.linalg.norm(a - b)) for a, b in zip(
+        jax.tree.leaves(api.global_params), jax.tree.leaves(want))]
+    assert max(g / m if m else g for g, m in zip(gap, moved)) < 5e-3
