@@ -70,8 +70,8 @@ not, and the loss in ``core/losses.py``). Counters (``counters``, summed
 over layers; ``FedModel.apply_counted``): ``moe_local_hits``,
 ``moe_expert_tokens_max``, ``moe_expert_tokens_mean``, ``moe_dropped``,
 ``moe_bias_moved`` on a layer with a selection bias (the choices it
-changed against the unbiased top-k) and ``ssm_chunks`` on a ``mamba``
-sublayer (sequences x chunks the scan ran); a layer sows only its own.
+changed against the unbiased top-k) and ``ssm_chunks`` / ``ssm_kernel_chunks``
+on a ``mamba`` sublayer (``Mamba2Mixer``); a layer sows only its own.
 """
 
 from __future__ import annotations
@@ -293,7 +293,7 @@ class Mamba2Mixer(nn.Module):
 
     @nn.compact
     def __call__(self, u):
-        from ..ops.ssd import num_chunks, ssd_scan
+        from ..ops.ssd import chunk_counts, ssd_scan
 
         B, T, _ = u.shape
         H, P, G, N = self.num_heads, self.head_dim, self.groups, self.state_size
@@ -328,8 +328,8 @@ class Mamba2Mixer(nn.Module):
             y = ssd_scan(
                 x.reshape(B, T, H, P), step, -jnp.exp(a_log.astype(f32)), b.reshape(B, T, G, N),
                 c.reshape(B, T, G, N), d_skip.astype(f32), self.chunk_size)
-        self.sow("counters", "ssm_chunks", f32(B * num_chunks(T, self.chunk_size)),
-                 reduce_fn=jnp.add, init_fn=lambda: f32(0))
+        for name, chunks in chunk_counts(T, H, P, G, N, self.chunk_size).items():
+            self.sow("counters", name, f32(B * chunks), reduce_fn=jnp.add, init_fn=lambda: f32(0))
         # gated RMSNorm: the gate first, the statistics over each group's channels
         scale = self.param("norm_scale", nn.initializers.ones, (inner,))
         gated = (y.reshape(B, T, inner).astype(f32) * jax.nn.silu(z.astype(f32))).reshape(B, T, G, inner // G)

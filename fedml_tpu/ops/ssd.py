@@ -1,5 +1,6 @@
 """The chunked state-space scan (the "state-space dual" form of Dao &
-Gu 2024, arXiv:2405.21060), as ``jnp`` products.
+Gu 2024, arXiv:2405.21060): as ``jnp`` products (``ssd_scan_jnp``) and,
+for the shapes ``ops/ssd_kernel.py`` takes, as Pallas kernels.
 
 Per head, with a scalar decay a step, the recurrence is
 
@@ -26,8 +27,16 @@ The four products (``C B^T``, the masked one, the states, ``C S``) take
 their operands in ``x``'s type with float32 accumulation (bfloat16 on
 the MXU where the model computes in bfloat16); ``dt``, the decays, the
 running sums, the mask and the carried state are float32 throughout.
-Reverse mode is autodiff of this form (the caller's ``remat`` decides
-what is kept). No Pallas kernel: a later one has this as its reference.
+Reverse mode of the ``jnp`` form is autodiff of it (the caller's
+``remat`` decides what is kept).
+
+``ssd_scan`` chooses by what it sees in its arguments, nothing else
+(``kernel_chunks``): where the chunk and the state are whole 128-lane
+tiles, a group's heads fill whole lane tiles and the sequence holds a
+chunk, the scan runs as ``ops/ssd_kernel.py``'s two Pallas kernels --
+the same products, casts and float32 state, the mask and the carried
+state in VMEM --; every other shape (test-sized models) runs the
+``jnp`` form, which is also the kernels' reference.
 
 A ``T`` that ``chunk`` does not divide is padded at its end with steps
 of ``dt = 0`` -- they decay nothing and add nothing -- and the padded
@@ -39,25 +48,75 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from . import ssd_kernel
+
 
 def num_chunks(seq_len: int, chunk: int) -> int:
     """Chunks the scan runs for one sequence of ``seq_len`` tokens."""
     return -(-seq_len // chunk)
 
 
+def kernel_chunks(seq_len: int, heads: int, head_dim: int, groups: int, state: int, chunk: int) -> int:
+    """Of ``num_chunks``, those that ``ssd_scan`` hands the Pallas
+    kernels at these shapes: all of them or none."""
+    geom = ssd_kernel.Geometry(heads, head_dim, groups, state, chunk)
+    return num_chunks(seq_len, chunk) if ssd_kernel.takes(seq_len, geom) else 0
+
+
+def chunk_counts(seq_len: int, heads: int, head_dim: int, groups: int, state: int, chunk: int) -> dict:
+    """What a mixer sows into ``counters`` for one sequence: the chunks
+    its scan runs (``ssm_chunks``) and those of them that run as the
+    Pallas kernels (``ssm_kernel_chunks``: all at kernel-sized widths,
+    none at a test's)."""
+    return {
+        "ssm_chunks": num_chunks(seq_len, chunk),
+        "ssm_kernel_chunks": kernel_chunks(seq_len, heads, head_dim, groups, state, chunk),
+    }
+
+
+def _whole_chunks(x, dt, b, c, chunk):
+    """The operands padded at their end to whole chunks with steps of
+    ``dt = 0`` (module docstring)."""
+    pad = -x.shape[1] % chunk
+    if pad:
+        x, dt, b, c = (jnp.pad(v, ((0, 0), (0, pad)) + ((0, 0),) * (v.ndim - 2)) for v in (x, dt, b, c))
+    return x, dt, b, c
+
+
+def _check(h, g, chunk):
+    if chunk <= 0 or h % g:
+        raise ValueError(f"{h} heads over {g} groups in chunks of {chunk}: no such scan")
+
+
 def ssd_scan(x, dt, a_head, b, c, d_head, chunk: int = 128) -> jax.Array:
     """``x`` [Bt, T, H, P]; ``dt`` [Bt, T, H] float32, positive (after
     its softplus); ``a_head`` [H] float32, negative; ``b``, ``c``
     [Bt, T, G, N] with ``H % G == 0``; ``d_head`` [H] float32. Returns
-    ``y`` [Bt, T, H, P] in ``x``'s type (module docstring)."""
+    ``y`` [Bt, T, H, P] in ``x``'s type (module docstring): through the
+    Pallas kernels where ``ssd_kernel.takes`` the shape, else ``ssd_scan_jnp``."""
     bt, t, h, p = x.shape
     g, n = b.shape[2:]
-    if h % g or chunk <= 0:
-        raise ValueError(f"{h} heads over {g} groups in chunks of {chunk}: no such scan")
+    _check(h, g, chunk)
+    geom = ssd_kernel.Geometry(h, p, g, n, chunk)
+    if not ssd_kernel.takes(t, geom):
+        return ssd_scan_jnp(x, dt, a_head, b, c, d_head, chunk)
+    f32 = jnp.float32
+    x, dt, b, c = _whole_chunks(x, dt, b, c, chunk)
+    padded = x.shape[1]
+    y = ssd_kernel.scan(
+        x.reshape(bt, padded, h * p), dt.astype(f32), a_head.astype(f32), b.reshape(bt, padded, g * n),
+        c.reshape(bt, padded, g * n), d_head.astype(f32), geom)
+    return y.reshape(bt, padded, h, p)[:, :t]
+
+
+def ssd_scan_jnp(x, dt, a_head, b, c, d_head, chunk: int = 128) -> jax.Array:
+    """``ssd_scan``'s operands and result, every shape, as ``jnp``
+    products and a ``lax.scan`` over the chunks."""
+    bt, t, h, p = x.shape
+    g, n = b.shape[2:]
+    _check(h, g, chunk)
     nc = num_chunks(t, chunk)
-    pad = nc * chunk - t
-    if pad:
-        x, dt, b, c = (jnp.pad(v, ((0, 0), (0, pad)) + ((0, 0),) * (v.ndim - 2)) for v in (x, dt, b, c))
+    x, dt, b, c = _whole_chunks(x, dt, b, c, chunk)
     per = h // g
     f32, dtype = jnp.float32, x.dtype
     dot = lambda spec, *ops: jnp.einsum(spec, *ops, preferred_element_type=f32)
