@@ -43,7 +43,7 @@ every token, whole on every chip (a share's part counts it once).
 (``parallel/expert.py``) says which of the ``num_experts`` experts live
 on this chip. The router keeps its published width; the layer keeps
 the token-choices that landed on held experts, sorts them by expert,
-runs one grouped product over the held stacks (``jax.lax.ragged_dot``)
+runs one grouped product over the held stacks (``ops/grouped_matmul``)
 and adds each row, times its routing weight, back onto its token. What
 the absent experts would add is left out and the partial sum goes on:
 no code stands in for absent chips. Nothing is dropped at any
@@ -52,7 +52,7 @@ here, and is worked through in chunks of an even load's rows and a
 quarter, as many as the choices that did land fill.
 
 The round engine vmaps local training over the cohort's lanes, and the
-chip's ragged product takes no batch dimension, so the grouped part is
+chip's grouped kernels take no batch dimension, so the grouped part is
 a ``custom_vjp`` whose forward and backward each run one lane after
 another (``jax.custom_batching.sequential_vmap``; batched over two lanes
 the round executable needs 15.8 GB, lane after lane 13.5: PR 28).
@@ -67,10 +67,10 @@ readers in ``benchmark/layer_metrics``): ``lm.embed``, ``blk.*``
 and its scan), ``moe.route`` / ``experts`` / ``combine`` inside the
 container ``moe.loop`` (the loops' own moves), ``moe.grad_sum``,
 ``moe.chunk`` (the chunk loop's bookkeeping), ``moe.out``,
-``moe.shared``, and
-``lm.head_loss`` (head, loss: ``core/losses.py``). Counters (``counters``, summed
-over layers; ``FedModel.apply_counted``): ``moe_local_hits``,
-``moe_expert_tokens_max``, ``moe_expert_tokens_mean``, ``moe_dropped``,
+``moe.shared``, and ``lm.head_loss`` (head, loss: ``core/losses.py``).
+Counters (``counters``, summed over layers; ``FedModel.apply_counted``):
+``moe_local_hits``, ``moe_expert_tokens_max``, ``moe_expert_tokens_mean``,
+``moe_dropped``, ``moe_kernel_rows`` (the rows the Pallas kernels ran),
 ``moe_bias_moved`` on a layer with a selection bias (the choices it
 changed against the unbiased top-k) and ``ssm_chunks`` / ``ssm_kernel_chunks``
 on a ``mamba`` sublayer (``Mamba2Mixer``); a layer sows only its own.
@@ -88,6 +88,8 @@ import jax.numpy as jnp
 import numpy as np
 from flax.core import freeze
 from jax.custom_batching import sequential_vmap
+
+from ..ops.grouped_matmul import kernel_rows, mlp_backward, mlp_forward
 
 SLIDING, FULL, CONV = "sliding_attention", "full_attention", "conv"
 _NEG_INF = -1e30
@@ -342,21 +344,10 @@ class Mamba2Mixer(nn.Module):
 
 # -- the expert layer -----------------------------------------------------
 
-def _expert_mlp(xs, stacks, sizes):
-    """Rows sorted by expert through their own expert's MLP: the grouped
-    product. Three stacks (gate, up, down) are a gated-SiLU expert,
-    ``down(silu(gate x) * up x)``; two (up, down) a squared-ReLU one,
-    ``down(relu(up x) ** 2)``. Rows past ``sizes.sum()`` belong to no
-    expert; what they read is masked by the caller."""
-    dot = functools.partial(jax.lax.ragged_dot, group_sizes=sizes, preferred_element_type=jnp.float32)
-    if len(stacks) == 3:
-        wg, wu, wd = stacks
-        gate, up = dot(xs, wg), dot(xs, wu)
-        h = (jax.nn.silu(gate) * up).astype(xs.dtype)
-    else:
-        wu, wd = stacks
-        h = jnp.square(jax.nn.relu(dot(xs, wu))).astype(xs.dtype)
-    return dot(h, wd).astype(xs.dtype)
+def expert_chunk_rows(tokens: int, k: int, held: int, num_experts: int) -> int:
+    """The chunk ``held_experts`` works the sorted list through in: an
+    even load's rows and a quarter, at most the list's room."""
+    return min(tokens * min(k, held), -(-5 * tokens * k * held // (4 * num_experts)))
 
 
 def _chunks(tok, weight, sizes, rows: int):
@@ -398,7 +389,8 @@ def _experts_fwd_one(rows, x, tok, weight, sizes, *stacks):
         with jax.named_scope("moe.route"):
             xs = jnp.take(x, t, axis=0)
         with jax.named_scope("moe.experts"):
-            out = _expert_mlp(xs, stacks, inside)
+            # the rows through their own expert's MLP; those past the sum are masked below
+            out = mlp_forward(xs, stacks, inside)
         with jax.named_scope("moe.combine"):
             add = jnp.where(valid[:, None], out.astype(jnp.float32) * w[:, None], 0.0)
             return y.at[t].add(add), done + jnp.sum(inside).astype(jnp.float32)
@@ -422,8 +414,8 @@ def _experts_bwd_one(rows, x, tok, weight, sizes, *stacks_dy):
         with jax.named_scope("moe.combine"):
             dys = jnp.where(valid[:, None], jnp.take(dy, t, axis=0), 0.0)
         with jax.named_scope("moe.experts"):
-            out, vjp = jax.vjp(lambda xs, *ws: _expert_mlp(xs, ws, inside), xs, *stacks)
-            dxs, *g_stacks = vjp((dys * w[:, None]).astype(out.dtype))
+            # the chunk's forward again, and its cotangents: the stacks' in float32
+            out, dxs, g_stacks = mlp_backward(xs, stacks, inside, (dys * w[:, None]).astype(xs.dtype))
         with jax.named_scope("moe.combine"):
             dw = jnp.where(valid, jnp.sum(dys * out.astype(jnp.float32), axis=-1), 0.0)
             dweight = jax.lax.dynamic_update_slice_in_dim(dweight, dw, c * rows, axis=0)
@@ -448,7 +440,7 @@ def held_experts(rows, x, tok, weight, sizes, *stacks):
     """``x`` [N, C] tokens; ``tok`` / ``weight`` [M] the token id and
     routing weight of each choice that landed on a held expert, sorted
     by expert, ``sizes`` [held] the rows of each; ``stacks`` the held
-    experts' matrices (``_expert_mlp``: gate and up [held, C, I] and
+    experts' matrices (``mlp_forward``: gate and up [held, C, I] and
     down [held, I, C], or up and down alone); ``rows`` the chunk the list is
     worked through in. Returns the held experts' part
     of the layer's output, float32 [N, C], and the number of rows the
@@ -456,7 +448,7 @@ def held_experts(rows, x, tok, weight, sizes, *stacks):
     chunk loop's length is a value (reverse mode cannot unroll it): the
     backward walks the same chunks, each chunk's forward recomputed.
     Under ``vmap`` the lanes run one after another, forward and
-    backward (the chip's ragged product has no batch dimension, and one
+    backward (the chip's grouped kernels have no batch dimension, and one
     lane's rows at a time is all the chip has room for)."""
     # a container (core/scopes.py): the lanes' and the chunks' loops,
     # with the slices, carries and stacked outputs XLA emits for them
@@ -555,7 +547,7 @@ class HeldExperts(nn.Module):
         # not jump with the seed's routing (at a chunk of exactly the
         # even load, layers a per cent over it ran a second, near-empty
         # chunk: a call of 10 rounds read 62.5 to 63.9 s by seed on the v5e)
-        rows = min(room, -(-5 * N * K * held // (4 * E)))
+        rows = expert_chunk_rows(N, K, held, E)
         y, done = held_experts(rows, xf, tok, sorted_weight, sizes, *stacks)
         if self.shared_dim:
             with jax.named_scope("moe.shared"):
@@ -568,6 +560,8 @@ class HeldExperts(nn.Module):
             # choices on held experts less the rows the chunk loop gave
             # the grouped product: 0 while the loop walks the whole list
             "moe_dropped": f32(hits) - jax.lax.stop_gradient(done),
+            # of those rows, the ones the Pallas kernels ran on (0 where ragged_dot did)
+            "moe_kernel_rows": kernel_rows(jax.lax.stop_gradient(done), (rows, C), stacks),
         }
         if moved is not None:
             counted["moe_bias_moved"] = moved

@@ -376,3 +376,34 @@ def test_staged_round_compiled_for_a_v5e_is_client_major(one_chip, no_compile_ca
     # a six-dimensional value whose minor-most (first-listed) dimension
     # is its leading axis has the clients or the cohort for its lanes
     assert not re.findall(r"bf16\[\d+,15,64,32,32,3\]\{0,", text)
+
+
+def _sweep_script():
+    """``scripts/grouped_matmul_sweep.py``, which reads each LM cell's
+    chunk (rows, hidden, expert width, stacks) from its configuration."""
+    import importlib.util
+    import os
+
+    path = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "scripts", "grouped_matmul_sweep.py")
+    spec = importlib.util.spec_from_file_location("grouped_matmul_sweep", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+@pytest.mark.parametrize("cell", ["mellum2", "lfm2", "twotower"])
+def test_grouped_matmul_kernels_compile_for_a_v5e_at_the_cells_widths(one_chip, no_compile_cache, cell):
+    """``ops/grouped_matmul.py``'s forward and backward at each LM cell's
+    chunk, compiled for a described v5e, Mosaic included: the tiling
+    rule's blocks fit the scoped VMEM (a tiling that does not is refused
+    here, not on the chip) and every product is a Mosaic call."""
+    from fedml_tpu.ops import grouped_matmul as gm
+
+    m, c, i, n_stacks = _sweep_script().cell_chunk(cell)
+    assert gm.takes(m, c, i)
+    spec = lambda shape, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    stacks = [spec((8, c, i))] * (n_stacks - 1) + [spec((8, i, c))]
+    sizes, xs = spec((8,), jnp.int32), spec((m, c))
+    fwd = jax.jit(gm._kernel_forward).trace(xs, sizes, *stacks).lower(lowering_platforms=("tpu",)).compile()
+    bwd = jax.jit(gm._kernel_backward).trace(xs, sizes, xs, *stacks).lower(lowering_platforms=("tpu",)).compile()
+    assert "tpu_custom_call" in fwd.as_text() and "ragged" not in bwd.as_text()

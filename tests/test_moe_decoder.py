@@ -151,7 +151,8 @@ class Case:
 
 
 LM_SCOPES = ("lm.embed", "moe.route", "moe.experts", "moe.combine", "lm.head_loss")
-MOE_COUNTERS = ("moe_local_hits", "moe_expert_tokens_max", "moe_expert_tokens_mean", "moe_dropped")
+MOE_COUNTERS = ("moe_local_hits", "moe_expert_tokens_max", "moe_expert_tokens_mean", "moe_dropped",
+                "moe_kernel_rows")
 CASES = {
     "window_softmax": Case(
         ref, MODEL, _args, ("no_window", "no_renorm", "no_yarn"),
@@ -203,8 +204,9 @@ def test_parameter_tree_is_the_references(case):
 
 def test_the_softmax_configuration_grew_no_leaf_and_no_counter(weights, tokens):
     """What the second configuration added is off by default: no
-    ``expert_bias`` leaf, no ``mlp``, an untied head, and the four
-    counters there were."""
+    ``expert_bias`` leaf, no ``mlp``, an untied head, and the five
+    counters there were (the fifth, ``moe_kernel_rows``, every expert
+    layer sows)."""
     m = models.create(_args(), 64)
     tree = m.init(jax.random.PRNGKey(0))
     assert set(tree) == {"embed", "final_norm", "lm_head"} | {f"layer_{i}" for i in range(4)}
