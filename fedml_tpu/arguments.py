@@ -458,6 +458,14 @@ _DEFAULTS: Dict[str, Any] = {
     # instead of an operator followed by a feed-forward
     "sublayers": False,
     "qk_norm": True,  # moe_decoder: RMS-normalise q and k per head before the rotation
+    # moe_decoder: gate attention's output by sigmoid(gate_proj(normed input)) before
+    # o_proj (not in the sublayer form)
+    "output_gate": False,
+    # moe_decoder: RMS-normalise the attention's and the feed-forward's outputs
+    # before the residual takes them (the sandwich norm; refused in the sublayer
+    # form and beside a conv layer)
+    "post_norms": False,
+    "embedding_multiplier": 1.0,  # moe_decoder: multiply the embedded rows (muP's sqrt(hidden))
     # moe_decoder "mamba" sublayers (ops/ssd.py): heads and their width
     # (the inner width is their product), B / C groups, state size, the
     # causal depthwise convolution's taps, the scan's chunk
@@ -493,6 +501,9 @@ _DEFAULTS: Dict[str, Any] = {
     # token beside the routed ones (0 = none)
     "shared_expert_dim": 0,
     "routed_scaling_factor": 1.0,  # moe_decoder: multiplies the (renormalised) top-k weights
+    # moe_decoder: the chunk the held experts' sorted choices are worked
+    # through in, in even loads (a step whose share passes it runs another)
+    "expert_chunk_loads": 1.25,
     "tie_word_embeddings": False,  # moe_decoder: the head is the embedding's rows
     "rms_norm_eps": 1e-6,  # moe_decoder RMSNorm epsilon
     # moe_decoder: the chips that share each expert layer, and which of

@@ -18,7 +18,9 @@ name is of one of two kinds:
   gather and aggregation, the update, a step's casts and loss, the
   decoder's parts (``lm.*``, ``blk.*``, ``moe.*``) and the evaluation's
   own passes (``eval.*``). Layers may nest (``blk.ssm.scan`` inside
-  ``blk.ssm``); a reader takes the union of their intervals.
+  ``blk.ssm``, ``blk.attn.gate`` inside an attention scope,
+  ``blk.post_norm`` inside its sublayer's); a reader takes the union of
+  their intervals.
 
 ``tests/test_scopes.py`` fails when a ``named_scope`` literal
 in ``fedml_tpu/`` is missing here; the benchmark's coverage reader
@@ -48,9 +50,11 @@ SCOPES: Tuple[Tuple[str, str], ...] = (
     ("lm.head_loss", LAYER),
     ("blk.attn.window", LAYER),
     ("blk.attn.full", LAYER),
+    ("blk.attn.gate", LAYER),
     ("blk.conv", LAYER),
     ("blk.mlp.dense", LAYER),
     ("blk.ffn_norm", LAYER),
+    ("blk.post_norm", LAYER),
     ("blk.ssm", LAYER),
     ("blk.ssm.scan", LAYER),
     ("moe.route", LAYER),

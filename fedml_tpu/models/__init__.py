@@ -280,7 +280,11 @@ def create(args, output_dim: int) -> FedModel:
                 expert_activation=getattr(args, "expert_activation", "gated_silu"),
                 shared_expert_dim=int(getattr(args, "shared_expert_dim", 0) or 0),
                 routed_scaling_factor=float(getattr(args, "routed_scaling_factor", 1.0)),
+                expert_chunk_loads=float(getattr(args, "expert_chunk_loads", 1.25)),
                 ssm=ssm,
+                output_gate=bool(getattr(args, "output_gate", False)),
+                post_norms=bool(getattr(args, "post_norms", False)),
+                embedding_multiplier=float(getattr(args, "embedding_multiplier", 1.0)),
             ),
             task="nwp",
             example_shape=(seq_len,),

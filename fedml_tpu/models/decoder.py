@@ -48,8 +48,8 @@ and adds each row, times its routing weight, back onto its token. What
 the absent experts would add is left out and the partial sum goes on:
 no code stands in for absent chips. Nothing is dropped at any
 imbalance: the sorted list has room for every choice a token can place
-here, and is worked through in chunks of an even load's rows and a
-quarter, as many as the choices that did land fill.
+here, and is worked through in chunks of ``chunk_loads`` even loads'
+rows (1.25 by default), as many as the choices that did land fill.
 
 The round engine vmaps local training over the cohort's lanes, and the
 chip's grouped kernels take no batch dimension, so the grouped part is
@@ -74,6 +74,21 @@ Counters (``counters``, summed over layers; ``FedModel.apply_counted``):
 ``moe_bias_moved`` on a layer with a selection bias (the choices it
 changed against the unbiased top-k) and ``ssm_chunks`` / ``ssm_kernel_chunks``
 on a ``mamba`` sublayer (``Mamba2Mixer``); a layer sows only its own.
+
+**The gated attention output** (``output_gate``): ``o_proj(o *
+sigmoid(gate_proj(a)))``, ``a`` the normed input the q/k/v projections
+read, ``gate_proj`` one output a query head's channel (scope
+``blk.attn.gate``, inside the layer's attention scope).
+
+**The sandwich norms** (``post_norms``; not beside a convolution): the
+attention's and the feed-forward's outputs RMS-normalised before the
+residual takes them, ``x + RMSNorm(op(RMSNorm(x)))`` (``attn_post_norm``,
+``ffn_post_norm``, dense or routed; scope ``blk.post_norm``, inside the
+sublayer's own scope where it has one).
+
+**The embedding multiplier** (``embedding_multiplier``; muP's
+``sqrt(hidden)``): the looked-up rows times a constant before the first
+layer (inside ``lm.embed``); the head is not scaled.
 """
 
 from __future__ import annotations
@@ -214,6 +229,7 @@ class Attention(nn.Module):
     impl: str
     eps: float
     qk_norm: bool = True
+    output_gate: bool = False
 
     @nn.compact
     def __call__(self, x, cos, sin):
@@ -231,8 +247,11 @@ class Attention(nn.Module):
             return h if cos is None else apply_rope(h, cos, sin)
 
         q, k = prepared(q, "q_norm"), prepared(k, "k_norm")
-        o = attend(q, k, v, self.window, self.impl)
-        return _proj(x.shape[-1], "o_proj")(o.reshape(B, T, H * D))
+        o = attend(q, k, v, self.window, self.impl).reshape(B, T, H * D)
+        if self.output_gate:
+            with jax.named_scope("blk.attn.gate"):
+                o = o * jax.nn.sigmoid(_proj(H * D, "gate_proj")(x))
+        return _proj(x.shape[-1], "o_proj")(o)
 
 
 class GatedShortConv(nn.Module):
@@ -344,10 +363,12 @@ class Mamba2Mixer(nn.Module):
 
 # -- the expert layer -----------------------------------------------------
 
-def expert_chunk_rows(tokens: int, k: int, held: int, num_experts: int) -> int:
-    """The chunk ``held_experts`` works the sorted list through in: an
-    even load's rows and a quarter, at most the list's room."""
-    return min(tokens * min(k, held), -(-5 * tokens * k * held // (4 * num_experts)))
+def expert_chunk_rows(tokens: int, k: int, held: int, num_experts: int, loads: float = 1.25) -> int:
+    """The chunk ``held_experts`` works the sorted list through in:
+    ``loads`` even loads' rows (an even load's and a quarter by default),
+    at most the list's room; in whole numbers, from ``loads``' exact value."""
+    num, den = float(loads).as_integer_ratio()
+    return min(tokens * min(k, held), -(-num * tokens * k * held // (den * num_experts)))
 
 
 def _chunks(tok, weight, sizes, rows: int):
@@ -475,7 +496,9 @@ class HeldExperts(nn.Module):
     this chip's share of the stacks (module docstring). ``activation``
     ``gated_silu`` (three stacks) or ``relu2`` (``up_proj`` and
     ``down_proj`` alone); ``shared_dim`` > 0 adds a shared expert of
-    that width and the same form, on every token, whole."""
+    that width and the same form, on every token, whole. ``chunk_loads``:
+    the chunk the sorted choices are worked through in, in even loads
+    (``expert_chunk_rows``)."""
 
     num_experts: int
     experts_per_token: int
@@ -488,6 +511,7 @@ class HeldExperts(nn.Module):
     activation: str = "gated_silu"  # | "relu2"
     shared_dim: int = 0
     routed_scaling_factor: float = 1.0
+    chunk_loads: float = 1.25
 
     @nn.compact
     def __call__(self, x):
@@ -542,12 +566,14 @@ class HeldExperts(nn.Module):
             sorted_weight = jnp.take(weight.reshape(N * K), order)
             sizes = jnp.bincount(local, length=held + 1)[:held].astype(jnp.int32)
             hits = jnp.sum(here)
-        # a chunk is an even load's rows and a quarter: a share a little
-        # fuller than even still takes one chunk, so a step's time does
-        # not jump with the seed's routing (at a chunk of exactly the
+        # a chunk is an even load's rows and a quarter by default: a share
+        # a little fuller than even still takes one chunk, so a step's time
+        # does not jump with the seed's routing (at a chunk of exactly the
         # even load, layers a per cent over it ran a second, near-empty
-        # chunk: a call of 10 rounds read 62.5 to 63.9 s by seed on the v5e)
-        rows = expert_chunk_rows(N, K, held, E)
+        # chunk: a call of 10 rounds read 62.5 to 63.9 s by seed on the v5e);
+        # a model whose share swings further from step to step takes a
+        # wider one (chunk_loads)
+        rows = expert_chunk_rows(N, K, held, E, self.chunk_loads)
         y, done = held_experts(rows, xf, tok, sorted_weight, sizes, *stacks)
         if self.shared_dim:
             with jax.named_scope("moe.shared"):
@@ -576,10 +602,12 @@ class HeldExperts(nn.Module):
 
 class DecoderBlock(nn.Module):
     """``x + operator(norm(x))`` then ``x + ffn(norm(x))``. The operator
-    is attention (``kind`` sliding or full) or the gated short
-    convolution (``conv``); the feed-forward the routed experts
-    (``experts``: ``HeldExperts``' fields) or, with ``experts`` None, a
-    dense gated MLP of ``intermediate_size``."""
+    is attention (``kind`` sliding or full; ``output_gate``: its output
+    gated) or the gated short convolution (``conv``); the feed-forward
+    the routed experts (``experts``: ``HeldExperts``' fields) or, with
+    ``experts`` None, a dense gated MLP of ``intermediate_size``. With
+    ``post_norms`` the attention's and the feed-forward's outputs are
+    normed before the residual takes them (the sandwich norm)."""
 
     kind: str
     num_heads: int
@@ -591,25 +619,35 @@ class DecoderBlock(nn.Module):
     conv_taps: int
     intermediate_size: int
     experts: Optional[Any]  # HeldExperts' fields (a frozen dict), or None: a dense MLP
+    output_gate: bool = False
+    post_norms: bool = False
 
     @nn.compact
     def __call__(self, x, cos, sin):
+        def residual(x, h, post_norm: str):
+            """``x + h``, or with ``post_norms`` ``x + RMSNorm(h)`` (a
+            local function: a method would add its name to the ops')."""
+            if self.post_norms:
+                with jax.named_scope("blk.post_norm"):
+                    h = RMSNorm(self.eps, name=post_norm)(h)
+            return x + h
+
         if self.kind == CONV:
             with jax.named_scope("blk.conv"):
                 x = x + GatedShortConv(self.conv_taps, name="conv")(
                     RMSNorm(self.eps, name="conv_norm")(x))
         else:
             with jax.named_scope("blk.attn.window" if self.window is not None else "blk.attn.full"):
-                x = x + Attention(
+                x = residual(x, Attention(
                     self.num_heads, self.num_kv_heads, self.head_dim, self.window,
-                    self.attention, self.eps, name="attn",
-                )(RMSNorm(self.eps, name="attn_norm")(x), cos, sin)
+                    self.attention, self.eps, output_gate=self.output_gate, name="attn",
+                )(RMSNorm(self.eps, name="attn_norm")(x), cos, sin), "attn_post_norm")
         with jax.named_scope("blk.ffn_norm"):
             h = RMSNorm(self.eps, name="ffn_norm")(x)
         if self.experts is not None:
-            return x + HeldExperts(**self.experts, name="moe")(h)
+            return residual(x, HeldExperts(**self.experts, name="moe")(h), "ffn_post_norm")
         with jax.named_scope("blk.mlp.dense"):
-            return x + GatedMLP(self.intermediate_size, name="mlp")(h)
+            return residual(x, GatedMLP(self.intermediate_size, name="mlp")(h), "ffn_post_norm")
 
 
 class SublayerBlock(nn.Module):
@@ -652,7 +690,10 @@ class MoEDecoderLM(nn.Module):
     of ``layer_types`` is one sublayer (``SublayerBlock``: ``mamba``,
     an attention kind or ``moe``) instead of an operator and a
     feed-forward. An attention kind with no entry in
-    ``rope_parameters`` is not rotated."""
+    ``rope_parameters`` is not rotated. ``output_gate``, ``post_norms``
+    and ``embedding_multiplier`` are the module docstring's; the
+    sublayer form takes neither of the first two, a convolution layer
+    no sandwich norms."""
 
     vocab_size: int
     hidden_size: int
@@ -682,7 +723,11 @@ class MoEDecoderLM(nn.Module):
     expert_activation: str = "gated_silu"
     shared_expert_dim: int = 0
     routed_scaling_factor: float = 1.0
+    expert_chunk_loads: float = 1.25
     ssm: Any = None  # the ``mamba`` sublayers': Mamba2Mixer's fields but eps (a frozen dict)
+    output_gate: bool = False
+    post_norms: bool = False
+    embedding_multiplier: float = 1.0
 
     @nn.compact
     def __call__(self, tokens, train: bool = False):
@@ -690,6 +735,9 @@ class MoEDecoderLM(nn.Module):
         embed = nn.Embed(self.vocab_size, self.hidden_size, name="embed")
         with jax.named_scope("lm.embed"):
             x = embed(tokens.astype(jnp.int32))
+            if self.embedding_multiplier != 1.0:
+                # in float32, rounded once to the compute type
+                x = (x.astype(jnp.float32) * self.embedding_multiplier).astype(x.dtype)
         kinds = (SLIDING, FULL, SSM, EXPERTS) if self.sublayers else (SLIDING, FULL, CONV)
         for kind in self.layer_types:
             if kind not in kinds:
@@ -706,11 +754,13 @@ class MoEDecoderLM(nn.Module):
             norm_topk_prob=self.norm_topk_prob, scoring=self.router_scoring,
             use_expert_bias=self.use_expert_bias, norm_topk_eps=self.norm_topk_eps,
             activation=self.expert_activation, shared_dim=self.shared_expert_dim,
-            routed_scaling_factor=self.routed_scaling_factor,
+            routed_scaling_factor=self.routed_scaling_factor, chunk_loads=self.expert_chunk_loads,
         ))
         if self.sublayers:
             if SSM in self.layer_types and self.ssm is None:
                 raise ValueError(f"a {SSM!r} sublayer needs the state-space mixer's sizes (ssm)")
+            if self.output_gate or self.post_norms:
+                raise ValueError("the sublayer form takes no output gate and no sandwich norms")
             attn = freeze(dict(
                 num_heads=self.num_heads, num_kv_heads=self.num_kv_heads, head_dim=self.head_dim,
                 impl=self.attention, qk_norm=self.qk_norm))
@@ -719,12 +769,15 @@ class MoEDecoderLM(nn.Module):
                 kind, self.sliding_window if kind == SLIDING else None, self.rms_norm_eps,
                 attn, self.ssm, experts, name=f"layer_{i}")
         else:
+            if self.post_norms and CONV in self.layer_types:
+                raise ValueError("a convolution layer takes no sandwich norms")
             block = nn.remat(DecoderBlock) if self.remat else DecoderBlock
             layer = lambda i, kind: block(
                 kind, self.num_heads, self.num_kv_heads, self.head_dim,
                 self.sliding_window if kind == SLIDING else None, self.attention,
                 self.rms_norm_eps, self.conv_L_cache, self.intermediate_size,
-                experts if i >= self.num_dense_layers else None, name=f"layer_{i}")
+                experts if i >= self.num_dense_layers else None, self.output_gate, self.post_norms,
+                name=f"layer_{i}")
         for i, kind in enumerate(self.layer_types):
             x = layer(i, kind)(x, *tables.get(kind, (None, None)))
         with jax.named_scope("lm.head_loss"):

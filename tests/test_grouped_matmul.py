@@ -206,6 +206,31 @@ def test_the_layer_counts_the_rows_the_kernels_ran(forced, monkeypatch, activati
     assert _gap(y, want) < 1e-5
 
 
+@pytest.mark.parametrize("loads, rows", [(None, 640), (0.5, 256), (2.0, 1024), (4.0, 1024)])
+def test_chunk_loads_sets_the_chunk_and_leaves_the_layer_as_it_was(monkeypatch, loads, rows):
+    """``chunk_loads`` even loads' rows a chunk (512 tokens, top 2, 4 of
+    8 held: an even load is 512 rows, the list's room 1,024), an even
+    load and a quarter by default; the layer's output and gradients are
+    the default's whatever the chunk, and nothing is dropped."""
+    from fedml_tpu.models import decoder
+
+    x = jax.random.normal(jax.random.PRNGKey(4), (1, 512, 128))
+    base = _layer()
+    layer = base if loads is None else _layer(chunk_loads=loads)
+    params = {"params": base.init(jax.random.PRNGKey(0), x)["params"]}
+    seen = []
+    monkeypatch.setattr(decoder, "held_experts", lambda r, *a: (seen.append(r), held_experts(r, *a))[1])
+    run = lambda m, p: jax.value_and_grad(lambda p: jnp.sum(m.apply(p, x, mutable=["counters"])[0] ** 2))(p)
+    with jax.default_matmul_precision("highest"):
+        (y, grads), (want, want_grads) = run(layer, params), run(base, params)
+        _, state = layer.apply(params, x, mutable=["counters"])
+    assert seen[0] == rows == decoder.expert_chunk_rows(512, 2, 4, 8, 1.25 if loads is None else loads)
+    assert float(state["counters"]["moe_dropped"]) == 0.0
+    assert _gap(y, want) < 1e-6
+    for got, ref in zip(jax.tree.leaves(grads), jax.tree.leaves(want_grads)):
+        assert _gap(got, ref) < 1e-5
+
+
 def test_a_test_sized_layer_stays_on_ragged_dot(forced):
     """Rows that are not whole 128-row tiles never reach the kernels,
     wherever they run: the counter reads 0."""

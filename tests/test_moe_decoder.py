@@ -13,7 +13,10 @@ assertion is the same (``case``): gated short-convolution layers beside
 one full-attention layer (C F C C C), a dense leading layer of 96, a
 sigmoid router with a selection bias, a tied head, a federation that
 leaves whole batches empty -- against its own plain reference
-(``benchmark/reference/fedavg_lfm2.py``).
+(``benchmark/reference/fedavg_lfm2.py``). A third, one sublayer a
+layer (state-space mixers, squared-ReLU and shared experts), against
+``fedavg_twotower.py``. ``test_gated_decoder.py`` puts a fourth through
+the same cases.
 """
 
 import argparse
@@ -767,10 +770,13 @@ def test_the_rounds_record_carries_the_counters(case, one_round):
     rec = api.history[-1]
     assert set(case.counters) | {"steps_run", "steps_packed"} <= set(rec)
     sparse = len(case.model["layer_types"]) - case.model.get("num_dense_layers", 0)
-    # 2 sequences of 32 tokens a step, 2 choices a token, over the steps run and the sparse layers
+    # 2 sequences of 32 tokens a step, k choices a token (at most min(k, held) of them
+    # here), over the steps run and the sparse layers
     calls, n = rec["steps_run"] * sparse, 2 * T
+    k = case.model["num_experts_per_tok"]
+    here = min(k, case.model["experts_held"][1])
     assert rec["moe_dropped"] == 0.0
-    assert 0 < rec["moe_local_hits"] <= calls * n * 2
+    assert 0 < rec["moe_local_hits"] <= calls * n * here
     assert rec["moe_expert_tokens_mean"] == pytest.approx(rec["moe_local_hits"] / 4)
     assert rec["moe_expert_tokens_mean"] <= rec["moe_expert_tokens_max"] <= calls * n
     assert rec["steps_packed"] == 2 * ds.packed_train.mask.shape[1]
@@ -783,7 +789,7 @@ def test_the_rounds_record_carries_the_counters(case, one_round):
         cohort = case.ref.sample_cohort(0, 4, 2)
         assert rec["steps_run"] == sum(2 if c < 2 else 1 for c in cohort) < rec["steps_packed"] + (
             1 if all(c < 2 for c in cohort) else 0)
-        assert 0 < rec["moe_bias_moved"] < rec["steps_run"] * sparse * n * 2
+        assert 0 < rec["moe_bias_moved"] < rec["steps_run"] * sparse * n * k
     else:
         assert rec["steps_run"] == rec["steps_packed"] and "moe_bias_moved" not in rec
 
