@@ -155,12 +155,67 @@ def rope_tables(seq_len: int, head_dim: int, rope: Dict[str, Any]):
     return jnp.cos(angles) * scale, jnp.sin(angles) * scale
 
 
+# the TPU's vector lanes: XLA keeps a head a multiple of this wide in
+# the lanes, and lays a narrower one out with the sequence there
+_LANES = 128
+
+
 def apply_rope(x, cos, sin):
-    """``x`` [B, T, heads, D]; rotated in float32, returned in its dtype."""
+    """``x`` [B, T, heads, D] rotated: ``x * cos + rotate_half(x) * sin``
+    in float32, returned in its dtype. A bfloat16 operand whose heads
+    fill whole lane rows (``D % 128 == 0``) takes its rotate-half as a
+    product with a signed permutation (``_rope_bf16``): exact, and it
+    writes no float32 copy of ``x``. Any other operand is upcast and its
+    halves concatenated: a float32 one would be rounded by a one-pass
+    product, and a narrower head's product lowers to a windowed
+    convolution over the sequence-minor layout, slower on the chip than
+    the copies it spares."""
+    if x.dtype == jnp.bfloat16 and x.shape[-1] % _LANES == 0:
+        return _rope_bf16(x, cos, sin)
     x32 = x.astype(jnp.float32)
     half = x.shape[-1] // 2
     rotated = jnp.concatenate([-x32[..., half:], x32[..., :half]], axis=-1)
     return (x32 * cos[None, :, None] + rotated * sin[None, :, None]).astype(x.dtype)
+
+
+def _rotate_half(x, transpose: bool = False):
+    """``rotate_half(x) = [-x2, x1]`` (or its transpose ``[x2, -x1]``)
+    in float32, as one product of the bfloat16 ``x`` with a ±1 / 0
+    matrix: one non-zero term an output, so exact in a single pass."""
+    d = x.shape[-1]
+    p = np.zeros((d, d), np.float32)  # rotate_half(x) = x @ p
+    p[d // 2:, :d // 2] = -np.eye(d // 2)
+    p[:d // 2, d // 2:] = np.eye(d // 2)
+    return jax.lax.dot_general(
+        x, jnp.asarray(p.T if transpose else p, x.dtype), (((x.ndim - 1,), (0,)), ((), ())),
+        precision=jax.lax.Precision.DEFAULT, preferred_element_type=jnp.float32)
+
+
+@jax.custom_vjp
+def _rope_bf16(x, cos, sin):
+    """``apply_rope``'s product form, with its own backward: autodiff of
+    it would round the rotated term's cotangent to bfloat16 (the
+    product's operand) before the sum."""
+    return (x.astype(jnp.float32) * cos[None, :, None]
+            + _rotate_half(x) * sin[None, :, None]).astype(x.dtype)
+
+
+def _rope_bf16_fwd(x, cos, sin):
+    return _rope_bf16(x, cos, sin), (cos, sin)
+
+
+def _rope_bf16_bwd(res, g):
+    """``dx = g cos + rotate_halfᵀ(g sin)``, summed in float32 and rounded
+    once, as autodiff of the concatenated form does; ``sin``'s halves,
+    swapped, carry each term to its column. The tables take no gradient."""
+    cos, sin = res
+    half = sin.shape[-1] // 2
+    sin_swapped = jnp.concatenate([sin[:, half:], sin[:, :half]], axis=-1)
+    dx = g.astype(jnp.float32) * cos[None, :, None] + _rotate_half(g, True) * sin_swapped[None, :, None]
+    return dx.astype(g.dtype), None, None
+
+
+_rope_bf16.defvjp(_rope_bf16_fwd, _rope_bf16_bwd)
 
 
 # -- attention ------------------------------------------------------------
